@@ -71,10 +71,7 @@ def exchange_changes(mesh, axis, all_outboxes, all_lens):
     (inboxes [n_shards, n_shards, L], in_lens) where row j column i is the
     payload shard j received from shard i — one all_to_all on ICI plus the
     matching length exchange."""
-    try:
-        from jax import shard_map
-    except ImportError:           # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = mesh.shape[axis]
     spec_data = P(axis, None, None)

@@ -102,10 +102,7 @@ class OpBatch:
 def register_pytrees(*classes):
     """Register container classes (with tree_flatten/tree_unflatten) as JAX
     pytree nodes; idempotent."""
-    try:
-        from jax import tree_util
-    except ImportError:
-        return
+    from jax import tree_util
     for klass in classes:
         try:
             tree_util.register_pytree_node(

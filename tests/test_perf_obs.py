@@ -415,6 +415,22 @@ class TestBenchLedger:
         assert artifacts <= sources, artifacts - sources
         assert report['corrupt'] == 0
 
+    def test_box_platform_comes_from_the_caller_never_the_env(
+            self, monkeypatch):
+        monkeypatch.setenv('JAX_PLATFORMS', 'tpu')   # must not be read
+        blind = bench_ledger.box_fingerprint()
+        assert (blind['platform'], blind['device_kind'],
+                blind['n_devices']) == (None, None, None)
+        chip = bench_ledger.box_fingerprint(
+            {'platform': 'tpu', 'device_kind': 'TPU v5 lite',
+             'n_devices': 1})
+        assert (chip['platform'], chip['device_kind'],
+                chip['n_devices']) == ('tpu', 'TPU v5 lite', 1)
+        # a chip row and a CPU row of one host never share a baseline
+        cpu = bench_ledger.box_fingerprint(
+            {'platform': 'cpu', 'device_kind': 'cpu', 'n_devices': 1})
+        assert len({blind['box_id'], chip['box_id'], cpu['box_id']}) == 3
+
     def test_trajectory_renders(self, tmp_path, capsys):
         path = str(tmp_path / 'ledger.jsonl')
         bench_ledger.backfill(path)

@@ -7,7 +7,8 @@ disables, ``BENCH_LEDGER_PATH`` redirects). A row is self-describing:
     {"schema": 1, "ts": ..., "date": "YYYY-MM-DD",
      "source": "bench" | "backfill:BENCH_r07.json",
      "round": 7 | null, "git_sha": "...",
-     "box": {"box_id", "cpus", "machine", "python", "platform"},
+     "box": {"box_id", "cpus", "machine", "python", "platform",
+             "device_kind", "n_devices"},   # the last three from JAX
      "metrics": {"seam_rate": 708847.0, ...},       # flat floats only
      "reps": {"seam_rate": [...]}}                  # per-rep samples,
                                                     # when recorded
@@ -77,15 +78,22 @@ def git_sha(root=_ROOT):
         return None
 
 
-def box_fingerprint():
+def box_fingerprint(device=None):
     """The box identity rows are grouped by: a same-box baseline means
     a same-fingerprint baseline (an 8-core replacement box must never
-    be judged against this 2-core one's numbers)."""
+    be judged against this 2-core one's numbers, nor a chip run against
+    a CPU run). `device` is the {'platform', 'device_kind', 'n_devices'}
+    stamp the caller read from JAX (automerge_tpu.jaxenv.device_stamp);
+    this module never guesses it — a caller that holds no backend gets
+    platform None."""
+    device = device or {}
     info = {
         'cpus': os.cpu_count(),
         'machine': _platform.machine(),
         'python': _platform.python_version(),
-        'platform': os.environ.get('JAX_PLATFORMS') or 'device',
+        'platform': device.get('platform'),
+        'device_kind': device.get('device_kind'),
+        'n_devices': device.get('n_devices'),
     }
     digest = hashlib.sha256(
         json.dumps(info, sort_keys=True).encode()).hexdigest()[:12]

@@ -47,7 +47,10 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+# storage recovery never needs the device; forced (not setdefault) so a
+# machine that exports JAX_PLATFORMS=tpu cannot send this process after
+# a chip some other process holds
+os.environ['JAX_PLATFORMS'] = 'cpu'
 
 from automerge_tpu.columnar import encode_change                 # noqa: E402
 from automerge_tpu.errors import AutomergeError                  # noqa: E402

@@ -1182,7 +1182,7 @@ def run_tier_kill_leg(name='tier_kill', *, docs=32, seed=0, path=None):
     script = f'''
 import os, sys
 sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ['JAX_PLATFORMS'] = 'cpu'   # the parent may hold the chip
 from automerge_tpu.columnar import encode_change
 from automerge_tpu.fleet import backend as fb
 from automerge_tpu.fleet.backend import DocFleet, init_docs
@@ -1244,6 +1244,8 @@ eng.vacuum_now()       # never returns
 
 
 def main():
+    from automerge_tpu import jaxenv
+    jaxenv.configure_compile_cache()
     sessions = int(os.environ.get('LOADGEN_SESSIONS', 1000))
     tenants = int(os.environ.get('LOADGEN_TENANTS', 64))
     requests = int(os.environ.get('LOADGEN_REQUESTS', 10_000))
