@@ -19,12 +19,14 @@ the host OpSet oracle (``automerge_tpu.backend``) outside any timing:
           256 x 256 x 256 batch against apply_op_batch
 
 It refuses to start unless JAX comes up on a TPU and the native codec
-is loaded, and exits non-zero when any leg fails. The last stdout line
-is one JSON object: {"ok": ..., "device": {...}, "legs": {...}, ...}.
-The per-leg seconds, dispatch counts, compilation counts and peak bytes
-in it are OBSERVATIONS for planning, not metrics: nothing is warmed or
-repeated the way a benchmark would, so they are not to be quoted as
-rates.
+is loaded, and exits non-zero when any leg fails. It prints two stdout
+lines, each one JSON object. The first is the report: {"report":
+"chip_smoke", "legs": {...}, ...}. The per-leg seconds, dispatch counts,
+compilation counts and peak bytes in it are OBSERVATIONS for planning,
+not metrics: nothing is warmed or repeated the way a benchmark would, so
+they are not to be quoted as rates. The last is the verdict, these keys
+and no others, the device as JAX reports it:
+{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}.
 
 ``--cpu-rehearsal`` runs the same legs at a tiny size on the CPU (the
 Pallas kernels in interpret mode) to debug this script before chip
@@ -620,11 +622,14 @@ def main(argv=None):
     records = {name: run_leg(name, fns[name], counter) for name in legs}
     skipped = [name for name in LEGS if name not in legs]
     ok = not skipped and all(r['ok'] for r in records.values())
-    result = {
+    verdict = {
         'ok': ok,
         'device': {'platform': stamp['platform'],
                    'kind': stamp['device_kind'],
                    'count': stamp['n_devices']},
+    }
+    report = {
+        'report': 'chip_smoke',
         'observations_not_metrics': True,
         'rehearsal': args.cpu_rehearsal,
         'seed': args.seed,
@@ -638,10 +643,12 @@ def main(argv=None):
     }
     total = counter.snapshot()
     total['compile_s'] = round(total['compile_s'], 3)
-    result.update(total)
-    result['peak_bytes_in_use'] = max(
+    report.update(total)
+    report['peak_bytes_in_use'] = max(
         (r['peak_bytes_in_use'] or 0 for r in records.values()), default=0)
-    print(json.dumps(result), flush=True)
+    print(json.dumps(report))
+    # the last stdout line: exactly these keys, nothing after it
+    print(json.dumps(verdict), flush=True)
     return 0 if ok else 1
 
 

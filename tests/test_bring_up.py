@@ -41,15 +41,26 @@ def _last_json(proc):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
+def _smoke_lines(proc):
+    """chip_smoke.py's two stdout lines: (report, verdict). The verdict
+    is the LAST line and carries exactly the keys the driver reads."""
+    report, verdict = map(json.loads, proc.stdout.strip().splitlines())
+    assert list(verdict) == ['ok', 'device']
+    assert list(verdict['device']) == ['platform', 'kind', 'count']
+    assert isinstance(verdict['ok'], bool)
+    assert isinstance(verdict['device']['count'], int)
+    return report, verdict
+
+
 # ---- chip_smoke.py ---------------------------------------------------------
 
 def test_smoke_rehearsal_runs_every_leg(tmp_path):
     proc = _run([SMOKE, '--cpu-rehearsal'], _env(tmp_path))
     assert proc.returncode == 0, proc.stderr[-2000:]
-    result = _last_json(proc)
-    assert result['ok'] is True and result['rehearsal'] is True
-    assert result['device'] == {'platform': 'cpu', 'kind': 'cpu',
-                                'count': 1}
+    result, verdict = _smoke_lines(proc)
+    assert verdict == {'ok': True, 'device': {'platform': 'cpu',
+                                              'kind': 'cpu', 'count': 1}}
+    assert result['rehearsal'] is True
     assert set(result['legs']) == {'seam', 'text', 'sync', 'served',
                                    'pallas'}
     assert all(leg['ok'] for leg in result['legs'].values())
@@ -101,8 +112,8 @@ def test_smoke_fails_when_a_leg_disagrees_with_the_oracle(tmp_path):
         'chip_smoke.host_oracle = lambda changes: real(list(changes)[:-1])',
         ['--cpu-rehearsal', '--legs', 'seam'], _env(tmp_path))
     assert proc.returncode != 0
-    result = _last_json(proc)
-    assert result['ok'] is False
+    result, verdict = _smoke_lines(proc)
+    assert verdict['ok'] is False
     seam = result['legs']['seam']
     assert seam['ok'] is False and 'host oracle' in seam['error']
 
@@ -110,9 +121,9 @@ def test_smoke_fails_when_a_leg_disagrees_with_the_oracle(tmp_path):
 def test_smoke_subset_never_reports_ok(tmp_path):
     proc = _run([SMOKE, '--cpu-rehearsal', '--legs', 'seam'],
                 _env(tmp_path))
-    result = _last_json(proc)
+    result, verdict = _smoke_lines(proc)
     assert result['legs']['seam']['ok'] is True
-    assert result['ok'] is False and proc.returncode != 0
+    assert verdict['ok'] is False and proc.returncode != 0
     assert result['legs_skipped'] == ['text', 'sync', 'served', 'pallas']
 
 
