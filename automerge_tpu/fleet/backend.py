@@ -3952,17 +3952,28 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     `turbo_commit` / `turbo_stage` / `turbo_dispatch` spans (no
     unattributed gap between marks — the coverage contract bench.py's
     observability section checks), with the native parse / device
-    dispatch sub-spans nested inside."""
+    dispatch sub-spans nested inside. `turbo_gate` and `turbo_commit`
+    are tiled in turn by a second sequence: `gate.chain` / `gate.shape`
+    / `gate.decode` / `gate.general` (per off-chain document a
+    `gate.meta` and a `gate.drain` span) / `gate.validate`, and
+    `commit.columnar` / `commit.staged` / `commit.handles` — named
+    without the `turbo_` prefix, so readers that sum `turbo_*` count
+    each millisecond once. `turbo_gate` carries why documents left the
+    chain path (`offchain_native` / `offchain_heads` / `offchain_seq`,
+    also in `fleet.metrics`)."""
     ps = _span_seq()
+    sub = _span_seq()   # the sub-phases of turbo_gate, then turbo_commit
     ps.mark('turbo_setup', docs=len(handles))
     try:
-        return _apply_changes_turbo_inner(handles, per_doc_changes, ps,
+        return _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
                                           parsed)
     finally:
+        sub.done()
         ps.done()
 
 
-def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
+def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
+                               parsed=None):
     from .. import native
     from .tensor_doc import OpBatch, MAX_ACTORS as _MA
 
@@ -4025,6 +4036,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
     rows, nat_keys, nat_actors, nmeta = out
     batch_meta = _TurboMetaBatch(nmeta, nat_actors, flat_buffers)
     ps.mark('turbo_gate')
+    sub.mark('gate.chain')
 
     # ---- Batched linear-chain validation: ONE native call ----
     # A doc takes the fast path iff every change deps on exactly the
@@ -4053,6 +4065,10 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
     if gate is None:
         return None
     doc_ok, hostcheck, g_doc, g_actor, g_first, g_last = gate
+    # Why a document leaves the chain path, by the first check that
+    # refuses it (documents without changes never leave it)
+    has_changes = doc_counts > 0
+    offchain_native = int((~doc_ok & has_changes).sum())
     # Docs whose head frontier is not columnar-representable (multi-head)
     # get the host hex compare for JUST their first change — rare.
     for d in np.flatnonzero(hostcheck).tolist():
@@ -4062,6 +4078,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
             if int(nmeta['deps_off'][i + 1] - nmeta['deps_off'][i]) != \
                     len(heads) or batch_meta.deps_hex(i) != heads:
                 doc_ok[d] = False
+    offchain_heads = int((~doc_ok & has_changes).sum()) - offchain_native
     # Seq bases: each (doc, actor) run's first seq must extend the doc's
     # clock. Lane-mode rows check vectorized against the clock columns;
     # dict-mode rows (actor populations past the lane width) probe their
@@ -4089,6 +4106,11 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
         if bad.any():
             doc_ok[g_doc[bad]] = False
     fast_mask = doc_ok
+    offchain_seq = int((~fast_mask & has_changes).sum()) - \
+        offchain_native - offchain_heads
+    ps.note(offchain_native=offchain_native, offchain_heads=offchain_heads,
+            offchain_seq=offchain_seq)
+    sub.mark('gate.shape')
 
     flags_all = rows['flags']
     seq_sel = (flags_all >= 3) & (flags_all <= 6)
@@ -4145,6 +4167,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
             if oid not in made_map[d] and \
                     oid not in engines[d].map_objects:
                 return None
+    sub.mark('gate.decode')
     # Decode every arena-boxed payload BEFORE the commit point: a payload
     # decode_value rejects (out-of-range leb, invalid UTF-8, bad float
     # width) must fall back to the exact path, not corrupt state after
@@ -4199,6 +4222,9 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
 
     # From here on the batch is committed to turbo (counted as such)
     fleet.metrics.turbo_calls += 1
+    fleet.metrics.offchain_native += offchain_native
+    fleet.metrics.offchain_heads += offchain_heads
+    fleet.metrics.offchain_seq += offchain_seq
 
     # Phase 1 — fallible: general causal gate for docs off the chain shape.
     # _drain_queue mutates clock/heads, so engines carry backups and any
@@ -4212,15 +4238,19 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
         for engine, clock, heads, queue in backups:
             engine.clock, engine.heads, engine.queue = clock, heads, queue
 
-    for d in np.flatnonzero(~fast_mask & (doc_counts > 0)).tolist():
+    offchain = np.flatnonzero(~fast_mask & has_changes).tolist()
+    sub.mark('gate.general', docs=len(offchain))
+    for d in offchain:
         engine = engines[d]
         start, stop = per_doc_idx[d]
         backups.append((engine, dict(engine.clock), list(engine.heads),
                         list(engine.queue)))
         try:
-            applied, queue = engine._drain_queue(
-                [batch_meta.meta(i) for i in range(start, stop)],
-                lambda change: None)
+            with _span('gate.meta', doc=d, changes=stop - start):
+                metas = [batch_meta.meta(i) for i in range(start, stop)]
+            with _span('gate.drain', doc=d, changes=stop - start):
+                applied, queue = engine._drain_queue(
+                    metas, lambda change: None)
         except Exception as exc:
             restore_all()
             # Gate errors are doc-scoped by construction (the drain loop
@@ -4237,6 +4267,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
         for change in applied:
             ready[change['_change_index']] = True
 
+    sub.mark('gate.validate')
     keep = ready[rows['doc']]
     # Validation from the native rows: duplicate opIds *within* the
     # applied batch are detectable per doc without decoding op objects.
@@ -4273,14 +4304,17 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
     # the exact path drains and flushes them later. Byte counts come from
     # the parser's buf_len meta column — no Python len() pass.
     buf_len = nmeta['buf_len']
-    fleet.metrics.changes_ingested += int(ready.sum())
-    if ready.all():
+    n_ready = int(ready.sum())
+    fleet.metrics.changes_ingested += n_ready
+    if n_ready == len(ready):
         fleet.metrics.bytes_ingested += int(buf_len.sum())
     else:
         fleet.metrics.bytes_ingested += int(buf_len[ready].sum())
 
     # Phase 2 — infallible: record logs, queues, staleness
-    ps.mark('turbo_commit', ready=int(ready.sum()))
+    sub.done()
+    ps.mark('turbo_commit', ready=n_ready)
+    sub.mark('commit.columnar')
     start_op = nmeta['startOp']
     nops = nmeta['nops']
     last_op = start_op + nops - 1
@@ -4412,6 +4446,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
             for gi in np.flatnonzero(gd == d).tolist():
                 clock[nat_actors[int(ga[gi])]] = int(g_last[gi])
             engine.clock = clock
+    sub.mark('commit.staged', docs=len(staged))
     for engine, applied, queue in staged:
         # Slow/staged docs: the exact per-doc tail loop (counted — this
         # is the fallback path the columnar commit replaces for fast
@@ -4430,6 +4465,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
             # mirror so the exact path re-decodes them before draining
             engine.stale = True
 
+    sub.mark('commit.handles')
     for handle in handles:
         handle['frozen'] = True
     # Fast docs' handles capture their post-commit head32 ROW and hex it
@@ -4449,13 +4485,15 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, parsed=None):
             lazy._head32 = head_rows[k]
             out_handles.append(lazy)
     result = out_handles, [None] * len(handles)
-    if not keep.any():
+    kept = int(keep.sum())
+    sub.done()
+    if not kept:
         return result            # everything queued: no device work
 
     # Land any lazily-enqueued earlier changes first: the register engine
     # is order-sensitive (pred kills), and even the LWW grid's counter
     # reset bases on the pre-batch winner
-    ps.mark('turbo_stage', kept=int(keep.sum()))
+    ps.mark('turbo_stage', kept=kept)
     fleet.flush()
 
     # Device batch: remap the native parser's key/actor numbering into the

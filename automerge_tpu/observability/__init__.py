@@ -7,17 +7,26 @@ see an XLA dispatch from a patchCallback, and when one document in a
 10k-doc fused batch is quarantined you need to know which one, in what
 phase, and what happened around it. Four layers, one package:
 
-- **Counters & roll-ups** (metrics.py): per-fleet monotonic `Metrics`,
-  `timed` phase seconds, `register_dispatch_source`/`dispatch_counts`
-  and `register_health_source`/`health_counts` system-wide roll-ups,
-  and the `trace` wrapper around `jax.profiler.trace`.
+- **Counters & roll-ups** (metrics.py): per-fleet monotonic `Metrics`
+  (among them why a document left the turbo chain path:
+  `offchain_native` / `offchain_heads` / `offchain_seq`),
+  `register_dispatch_source`/`dispatch_counts` and
+  `register_health_source`/`health_counts` system-wide roll-ups, and
+  `trace`, the operator's one entry to a profiler capture: it turns the
+  spans on for the block, so the capture holds device ops and host
+  phases on one clock.
 - **Host-phase spans** (spans.py): `span(name, **attrs)` — near-zero
   overhead while disabled, a bounded ring while enabled — instrumented
-  at every hot seam (native parse, SHA, turbo gate/stage/commit, device
-  dispatch, mirror rebuild, actor remap, journal append/commit/fsync,
-  checkpoint, compaction, recovery replay, Bloom build/probe, sync
-  encode/decode). `export_chrome_trace` writes Perfetto-loadable JSON
-  that lines up beside a `trace()` device capture.
+  at every hot seam (native parse, SHA, turbo gate/stage/commit and
+  their `gate.*` / `commit.*` sub-phases, device dispatch, mirror
+  rebuild, actor remap, journal append/commit/fsync, checkpoint,
+  compaction, recovery replay, Bloom build/probe, sync encode/decode).
+  Spans are a tree (`id`, `parent`, `root`; `self_times` gives each
+  span's time outside its children), every collection is a `gc` span
+  under the phase it interrupted, and while enabled each span is also a
+  `jax.profiler.TraceAnnotation`, so a profiler capture shows it in
+  `/host:CPU` beside the device planes. `export_chrome_trace` writes the
+  ring as Perfetto-loadable JSON on `perf_counter_ns` (its own clock).
 - **Latency histograms** (hist.py): fixed log2-bucket `Histogram`s with
   p50/p95/p99 summaries and bucketwise `snapshot()`/`delta()` — batch
   apply latency, fsync latency, sync round-trip, per-doc change bytes,
@@ -62,7 +71,7 @@ from .hist import (Histogram, histogram, histogram_delta,
 from .metrics import (Counters, Metrics, counts_delta, dispatch_counts,
                       dispatch_delta, health_counts, health_delta,
                       register_dispatch_source, register_health_source,
-                      timed, trace)
+                      trace)
 from .perf import (PerfBaselines, baselines, disable_observatory,
                    dump_ledger, enable_observatory, instrument_kernel,
                    kernel_report, kernel_snapshot, perf_stats,
@@ -73,17 +82,18 @@ from .recorder import (configure as configure_flight_recorder, clear_events,
                        recent_events, record_event)
 from .slo import SloPolicy, SloRegistry, outcome_class, slo_stats
 from .spans import (clear as clear_spans, export_chrome_trace, iter_spans,
-                    record_span, span, span_count, span_seq, spanned,
-                    spans_dropped)
+                    record_span, self_times, span, span_count, span_seq,
+                    spanned, spans_dropped)
 from .tracecontext import TraceContext
 
 __all__ = [
-    'Metrics', 'timed', 'trace',
+    'Metrics', 'trace',
     'register_dispatch_source', 'dispatch_counts',
     'register_health_source', 'health_counts',
     'counts_delta', 'health_delta', 'dispatch_delta',
     'span', 'span_seq', 'spanned', 'iter_spans', 'clear_spans',
     'span_count', 'export_chrome_trace', 'record_span', 'spans_dropped',
+    'self_times',
     'Histogram', 'histogram', 'record_value', 'histogram_snapshot',
     'histogram_delta',
     'record_event', 'recent_events', 'clear_events', 'dump_flight_record',

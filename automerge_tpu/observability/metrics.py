@@ -6,12 +6,10 @@
   capacity growths. `snapshot()` returns a plain dict; `delta(prev)`
   diffs two snapshots — subtract around a workload to get per-phase
   counts.
-- `trace(path)`: context manager around `jax.profiler.trace` — writes a
-  TensorBoard-loadable XLA trace of everything dispatched inside the
-  block (merge the host-span Chrome trace from spans.py next to it in
-  Perfetto; see BASELINE.md "Observability contract").
-- `timed(metrics, key)`: context manager accumulating wall-clock seconds
-  into a counter, for host-side phases (decode, gate, patch build).
+- `trace(path)`: the operator's one entry to a capture — a context
+  manager around `jax.profiler.trace` that turns the host-phase spans on
+  for the block, so the written trace holds the device's ops and, in
+  its `/host:CPU` plane, every program span on the same clock.
 - `register_dispatch_source(name, fn)` / `dispatch_counts(fleets)`: one
   roll-up of every device-dispatch counter in the system. DocFleet counts
   its dispatches in `fleet.metrics.dispatches`, but some batched paths run
@@ -36,9 +34,8 @@ synthetic key). Both register functions reject them with ValueError.
 import contextlib
 import re
 import threading
-import time
 
-__all__ = ['Counters', 'Metrics', 'timed', 'trace',
+__all__ = ['Counters', 'Metrics', 'trace',
            'register_dispatch_source', 'dispatch_counts',
            'register_health_source', 'health_counts',
            'counts_delta', 'health_delta', 'dispatch_delta']
@@ -99,6 +96,13 @@ class Metrics:
                                  # (staged/slow docs only; the columnar
                                  # fast path contributes ZERO — pinned
                                  # by the commit-phase regression guard)
+        # why a document left the turbo chain path for the general gate
+        # (and so the staged commit): the first check that refused it
+        'offchain_native',       # native.turbo_gate: chain links, deps
+                                 # counts, heads against the columnar rows
+        'offchain_heads',        # the host compare of a multi-head frontier
+        'offchain_seq',          # an actor's first seq does not extend
+                                 # the document's clock
     )
 
     def __init__(self):
@@ -123,17 +127,6 @@ class Metrics:
         parts = [f'{k}={getattr(self, k)}' for k in self._FIELDS
                  if getattr(self, k)]
         return f'Metrics({", ".join(parts)})'
-
-
-@contextlib.contextmanager
-def timed(metrics, key):
-    """Accumulate the block's wall-clock seconds into metrics.seconds[key]."""
-    start = time.perf_counter()
-    try:
-        yield
-    finally:
-        metrics.seconds[key] = metrics.seconds.get(key, 0.0) + \
-            (time.perf_counter() - start)
 
 
 # ---- device-dispatch roll-up ----------------------------------------------
@@ -235,8 +228,20 @@ def dispatch_delta(prev, fleets=()):
 
 @contextlib.contextmanager
 def trace(log_dir):
-    """JAX profiler trace of every dispatch inside the block; view the
-    written trace with TensorBoard's profile plugin or Perfetto."""
+    """JAX profiler trace of every dispatch inside the block, with the
+    program's host-phase spans in it: spans are turned on for the block
+    (and left as they were found), and while on each span is a
+    `TraceAnnotation` in the capture's `/host:CPU` plane, on the device
+    planes' clock. View the written trace with TensorBoard's profile
+    plugin or Perfetto."""
     import jax
-    with jax.profiler.trace(str(log_dir)):
-        yield
+    from . import spans
+    was_on = spans.on()
+    if not was_on:
+        spans.enable()
+    try:
+        with jax.profiler.trace(str(log_dir)):
+            yield
+    finally:
+        if not was_on:
+            spans.disable()

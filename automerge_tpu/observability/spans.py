@@ -6,8 +6,19 @@ of an instrumented seam is one module-flag check and two empty method
 calls, which is why the hot paths (turbo apply, journal commit, Bloom
 build) can stay instrumented permanently instead of behind copy-pasted
 ``if`` guards. When ON (``enable()``), every span close records
-``(name, t0_ns, t1_ns, thread, attrs, error)`` into a bounded ring — old
-spans fall off the end, so a long-running fleet never grows memory.
+``(name, t0_ns, t1_ns, thread, attrs, error, id, parent, root)`` into a
+bounded ring — old spans fall off the end, so a long-running fleet never
+grows memory.
+
+Spans form a TREE: ``id`` is a process-wide counter, ``parent`` the id of
+the innermost span open on the same thread when this one opened (``None``
+for a root), ``root`` the id of the outermost ancestor — what every span of
+one ``apply_changes_docs`` call, one service tick, one recovery shares.
+The open spans of a thread are a thread-local stack that exists only while
+recording is on. ``self_times(spans)`` is a span's duration less what its
+children cover. While recording is on a ``gc.callbacks`` hook records every
+collection as a ``gc`` span, a child of whatever it interrupted, so a
+phase's self time can be read without the collector.
 
 ``span_seq()`` is the shape the multi-phase seams use (turbo apply,
 recovery): ``mark(name)`` closes the previous phase and opens the next at
@@ -22,12 +33,23 @@ close into its own event ring — a traced run would otherwise flood the
 small fault-event ring with span closes and evict exactly the
 quarantine/rot events a forensic dump exists to preserve.
 
+While recording is on every ``Span`` / ``SpanSeq`` phase is also a
+``jax.profiler.TraceAnnotation`` of the same name for its lifetime: outside
+a profiler session that is a flag check inside the profiler, inside one the
+span lands in the capture's ``/host:CPU`` plane on the clock the
+``/device:TPU:*`` planes use, so a device gap can be put down to the phase
+the host was in (``observability.trace`` is the operator's entry). Where
+JAX is absent the ring works alone.
+
 ``export_chrome_trace(path)`` writes the ring as Chrome trace-event JSON
 ("X" complete events, microsecond timestamps), the format Perfetto and
-chrome://tracing load directly — drop it next to a ``jax.profiler.trace``
-capture and the host phases line up beside the device timeline.
+chrome://tracing load directly. Its clock is ``time.perf_counter_ns``, NOT
+the profiler's: to see host phases beside the device timeline read them
+from the profiler capture itself.
 """
 
+import gc
+import itertools
 import json
 import threading
 import time
@@ -36,7 +58,7 @@ from .metrics import register_health_source
 
 __all__ = ['enable', 'disable', 'on', 'span', 'span_seq', 'spanned',
            'clear', 'iter_spans', 'export_chrome_trace', 'Span',
-           'record_span', 'spans_dropped']
+           'record_span', 'spans_dropped', 'self_times']
 
 _on = False                 # the master switch; module-global for one-load checks
 _ring = []                  # preallocated record slots (None until written)
@@ -45,6 +67,10 @@ _idx = 0                    # next write position
 _total = 0                  # lifetime spans recorded (wraparound-aware)
 _dropped_lifetime = 0       # spans evicted by wraparound, never reset
 _lock = threading.Lock()    # guards ring writes only; reads copy under it
+_ids = itertools.count(1)   # span ids (next() is one GIL-atomic call)
+_open_spans = threading.local()   # .stack: this thread's open spans, outermost first
+_annotation = None          # jax.profiler.TraceAnnotation while on, where JAX is
+_gc_span = None             # the collection in flight (collections do not nest)
 
 # a wrapped ring silently truncating a trace is the no-silent-caps rule's
 # textbook violation: the health counter makes the loss countable, and
@@ -58,15 +84,28 @@ def on():
     return _on
 
 
+def _import_annotation():
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
 def enable(capacity=4096):
-    """Turn span recording on with a bounded ring of `capacity` spans."""
-    global _on, _ring, _cap, _idx, _total
+    """Turn span recording on with a bounded ring of `capacity` spans,
+    bridge the spans into the JAX profiler's host plane (where JAX can be
+    imported) and start recording collections as ``gc`` spans."""
+    global _on, _ring, _cap, _idx, _total, _annotation
+    try:
+        _annotation = _import_annotation()
+    except ImportError:
+        _annotation = None
     with _lock:
         _ring = [None] * int(capacity)
         _cap = int(capacity)
         _idx = 0
         _total = 0
         _on = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def disable():
@@ -74,6 +113,8 @@ def disable():
     so a forensic dump can still read the tail of a disabled trace."""
     global _on
     _on = False
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def clear():
@@ -86,10 +127,10 @@ def clear():
         _total = 0
 
 
-def _record(name, t0, t1, attrs, error, tid=None):
+def _record(name, t0, t1, attrs, error, ids, tid=None):
     global _idx, _total, _dropped_lifetime
     rec = (name, t0, t1,
-           threading.get_ident() if tid is None else tid, attrs, error)
+           threading.get_ident() if tid is None else tid, attrs, error) + ids
     with _lock:
         if not _cap:
             return
@@ -100,31 +141,93 @@ def _record(name, t0, t1, attrs, error, tid=None):
         _total += 1
 
 
-def record_span(name, t0_ns, t1_ns, tid=None, **attrs):
+def _stack():
+    try:
+        return _open_spans.stack
+    except AttributeError:
+        _open_spans.stack = []
+        return _open_spans.stack
+
+
+def _new_ids(stack):
+    """(id, parent, root) of a span opening under `stack`'s innermost."""
+    sid = next(_ids)
+    return (sid, stack[-1], stack[0]) if stack else (sid, None, sid)
+
+
+def _open(name):
+    """Open a span on this thread: ((id, parent, root), annotation)."""
+    stack = _stack()
+    ids = _new_ids(stack)
+    stack.append(ids[0])
+    annotation = None
+    if _annotation is not None:
+        annotation = _annotation(name)
+        annotation.__enter__()
+    return ids, annotation
+
+
+def _close(sid, annotation):
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    stack = _stack()
+    if stack and stack[-1] == sid:
+        stack.pop()
+    elif sid in stack:
+        # a phase sequence left open above this span (never done()) must
+        # not become the parent of what this thread opens next
+        del stack[stack.index(sid):]
+
+
+def record_span(name, t0_ns, t1_ns, tid=None, parent=None, **attrs):
     """Inject an externally-timed span into the ring. For phases measured
     outside Python — the native codec's pool workers time their parse
     slices against CLOCK_MONOTONIC, the same epoch ``perf_counter_ns``
     reads on Linux, so injected slices line up with host-phase spans in
     one Perfetto timeline. ``tid`` (default: calling thread) lets each
-    worker render as its own track."""
+    worker render as its own track. ``parent`` is the live ``Span`` or
+    ``SpanSeq`` the slice worked for; by default the innermost span open
+    on the calling thread."""
     if not _on:
         return
-    _record(name, t0_ns, t1_ns, attrs or None, None, tid=tid)
+    if parent is not None:
+        ids = (next(_ids), parent.id, parent.root)
+    else:
+        ids = _new_ids(_stack())
+    _record(name, t0_ns, t1_ns, attrs or None, None, ids, tid=tid)
 
 
-class Span:
+class _Node:
+    """What Span and SpanSeq share: the place in the tree of the span (or
+    running phase), and its profiler annotation."""
+
+    __slots__ = ('_ids', '_annotation')
+
+    def __init__(self):
+        self._ids = (None, None, None)
+        self._annotation = None
+
+    id = property(lambda self: self._ids[0])
+    parent = property(lambda self: self._ids[1])
+    root = property(lambda self: self._ids[2])
+
+
+class Span(_Node):
     """A live span: records on close (including exceptional close, with
     the exception type attached as the ``error`` field — every begin has
-    an end even when the guarded block raises)."""
+    an end even when the guarded block raises). ``id``, ``parent`` and
+    ``root`` are set once it is entered."""
 
     __slots__ = ('_name', '_t0', '_attrs')
 
     def __init__(self, name, attrs):
+        super().__init__()
         self._name = name
         self._attrs = attrs or None
         self._t0 = 0
 
     def __enter__(self):
+        self._ids, self._annotation = _open(self._name)
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -136,8 +239,11 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        _record(self._name, self._t0, time.perf_counter_ns(), self._attrs,
-                exc_type.__name__ if exc_type is not None else None)
+        t1 = time.perf_counter_ns()
+        _close(self._ids[0], self._annotation)
+        _record(self._name, self._t0, t1, self._attrs,
+                exc_type.__name__ if exc_type is not None else None,
+                self._ids)
         return False
 
 
@@ -156,42 +262,72 @@ class _NullSpan:
         return self
 
 
-class SpanSeq:
+def _on_gc(phase, info):
+    """gc.callbacks hook, installed while recording is on: one ``gc`` span
+    per collection, on the thread it ran on, under the span it stopped."""
+    global _gc_span
+    if phase == 'start':
+        if _on:
+            _gc_span = Span('gc', {'generation': info['generation']})
+            _gc_span.__enter__()
+    elif _gc_span is not None:
+        gc_span, _gc_span = _gc_span, None
+        gc_span.set(collected=info['collected'])
+        gc_span.__exit__(None, None, None)
+
+
+class SpanSeq(_Node):
     """Sequential phase spans: each mark() closes the running phase and
-    opens the next at the same instant, so the phases tile the interval."""
+    opens the next at the same instant, so the phases tile the interval.
+    The running phase is on the thread's stack of open spans like any
+    other, so a sequence started inside a phase records that phase's
+    children. ``id``, ``parent`` and ``root`` are the running phase's."""
 
     __slots__ = ('_name', '_t0', '_attrs')
 
     def __init__(self):
+        super().__init__()
         self._name = None
         self._t0 = 0
         self._attrs = None
 
+    def _finish(self, t, error):
+        _close(self._ids[0], self._annotation)
+        _record(self._name, self._t0, t, self._attrs, error, self._ids)
+        self._name = None
+
     def mark(self, name, **attrs):
         t = time.perf_counter_ns()
         if self._name is not None:
-            _record(self._name, self._t0, t, self._attrs, None)
+            self._finish(t, None)
+        self._ids, self._annotation = _open(name)
         self._name = name
         self._t0 = t
         self._attrs = attrs or None
 
+    def note(self, **attrs):
+        """Attach attributes to the running phase (recorded when the next
+        mark() or done() closes it)."""
+        if self._name is None:
+            return
+        if self._attrs is None:
+            self._attrs = {}
+        self._attrs.update(attrs)
+
     def done(self, error=None, **attrs):
         if self._name is None:
             return
-        if attrs:
-            if self._attrs is None:
-                self._attrs = {}
-            self._attrs.update(attrs)
-        _record(self._name, self._t0, time.perf_counter_ns(), self._attrs,
-                error)
-        self._name = None
-        self._attrs = None
+        self.note(**attrs)
+        self._finish(time.perf_counter_ns(), error)
 
 
 class _NullSeq:
     __slots__ = ()
 
     def mark(self, name, **attrs):
+        pass
+
+    def note(self, **attrs):
         pass
 
     def done(self, error=None, **attrs):
@@ -244,14 +380,38 @@ def iter_spans():
     for rec in raw:
         if rec is None:
             continue
-        name, t0, t1, tid, attrs, error = rec
+        name, t0, t1, tid, attrs, error, sid, parent, root = rec
         d = {'name': name, 't0_ns': t0, 't1_ns': t1,
-             'dur_ns': t1 - t0, 'tid': tid}
+             'dur_ns': t1 - t0, 'tid': tid,
+             'id': sid, 'parent': parent, 'root': root}
         if attrs:
             d['attrs'] = dict(attrs)
         if error:
             d['error'] = error
         out.append(d)
+    return out
+
+
+def self_times(spans):
+    """{id: ns} for iter_spans()-shaped dicts: each span's duration less
+    the union of its children's intervals (clipped to its own), i.e. the
+    time it spent in no narrower span. Children on other threads (the
+    parse pool's slices) count: what they cover the parent waited for."""
+    children = {}
+    for s in spans:
+        if s.get('parent') is not None:
+            children.setdefault(s['parent'], []).append(
+                (s['t0_ns'], s['t1_ns']))
+    out = {}
+    for s in spans:
+        lo, hi = s['t0_ns'], s['t1_ns']
+        covered, edge = 0, lo
+        for c0, c1 in sorted(children.get(s['id'], ())):
+            c0, c1 = max(c0, edge), min(c1, hi)
+            if c1 > c0:
+                covered += c1 - c0
+                edge = c1
+        out[s['id']] = (hi - lo) - covered
     return out
 
 
@@ -271,9 +431,11 @@ def spans_dropped():
 def export_chrome_trace(path=None, pid=1):
     """The recorded spans as Chrome trace-event 'X' (complete) events —
     the JSON Perfetto / chrome://tracing load. Timestamps are the raw
-    perf_counter microseconds; host spans from one process share a clock,
-    so phases nest correctly. Returns the event list; writes
-    ``{"traceEvents": [...]}`` to `path` when given."""
+    ``time.perf_counter_ns`` microseconds (not the JAX profiler's clock);
+    host spans from one process share that clock, so phases nest
+    correctly. ``args`` carries each span's ``id``, ``parent`` and
+    ``root``. Returns the event list; writes ``{"traceEvents": [...]}``
+    to `path` when given."""
     events = []
     for rec in iter_spans():
         ev = {'ph': 'X', 'name': rec['name'], 'pid': pid,
@@ -283,8 +445,8 @@ def export_chrome_trace(path=None, pid=1):
         args = dict(rec.get('attrs') or {})
         if rec.get('error'):
             args['error'] = rec['error']
-        if args:
-            ev['args'] = args
+        args.update(id=rec['id'], parent=rec['parent'], root=rec['root'])
+        ev['args'] = args
         events.append(ev)
     dropped = spans_dropped()
     if dropped and events:

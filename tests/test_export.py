@@ -32,6 +32,10 @@ def _clean():
     # watermark sampling is sticky by design (once sampled, the mem
     # gauges render); reset so the not-enabled assertions mean something
     obs_perf.reset_watermarks()
+    # and so are the seam histograms an earlier file of this worker may
+    # have filled: the baselines' first tick would drain them into the
+    # windows the tests below fill by hand
+    obs_hist.reset()
     yield
     obs_perf.disable_observatory()
     obs_hist.disable()

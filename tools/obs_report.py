@@ -34,13 +34,17 @@ serving cadence) and pump seconds — plus any non-zero health counters.
 Trace mode reads the Chrome trace-event JSON that
 ``observability.export_chrome_trace`` writes (a bare event list or a
 ``{"traceEvents": [...]}`` wrapper — the same shapes Perfetto accepts)
-and renders, per span name: call count, total/mean/max milliseconds, and
-share of the trace's wall-clock — the per-phase merge-cost breakdown the
-ROADMAP's parse/merge-overlap work needs (cf. the differential-merge
-phase analysis in PAPERS.md "Fast Updates on Read-Optimized Databases").
-Spans nest (native_parse inside turbo_parse, dispatch_grid inside
-turbo_dispatch), so percentages legitimately sum past 100; the
-``turbo_*`` phase rows tile each batch and sum to ~the batch wall.
+and renders, per span name: call count, total/mean/max milliseconds,
+share of the trace's wall-clock, and SELF time — the span's duration
+less what its child spans cover (``spans.self_times`` over the ``id`` /
+``parent`` the export carries in ``args``) — the per-phase merge-cost
+breakdown the ROADMAP's parse/merge-overlap work needs (cf. the
+differential-merge phase analysis in PAPERS.md "Fast Updates on
+Read-Optimized Databases"). Spans nest (native_parse inside turbo_parse,
+gate.drain inside gate.general inside turbo_gate), so the wall column
+counts a millisecond once per level; the self column counts it once, in
+the narrowest span that held it, and sums to the traced wall of each
+thread. A collection is a ``gc`` span under the phase it interrupted.
 
 Flight mode pretty-prints a forensic dump: trigger, per-doc errors
 (slot, durable id, stage, typed error), then the surrounding event ring.
@@ -71,27 +75,29 @@ partial window) has its truncation DISCLOSED in the report — trace ids
 stay continuous across the gap, so a failover still stitches.
 
 stdlib only — usable on a box with nothing else installed (the counter
-delta helper is loaded straight from
-automerge_tpu/observability/metrics.py by file path, which keeps one
-implementation without importing the package).
+delta and the self-time helpers are loaded straight from
+automerge_tpu/observability/metrics.py and spans.py by file path, which
+keeps one implementation without importing the package).
 """
 
-import importlib.util
+import importlib
 import json
 import os
 import sys
+import types
 
 
-def _metrics_mod():
-    """observability/metrics.py loaded by path (stdlib importlib only):
-    the shared counts_delta without pulling the package import chain."""
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), 'automerge_tpu', 'observability',
-        'metrics.py')
-    spec = importlib.util.spec_from_file_location('_obs_metrics', path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _obs_mod(name):
+    """observability/<name>.py (metrics, spans) loaded from its directory
+    under a stand-in package, so that the module's relative imports
+    resolve without running the package's __init__ and its import chain
+    (stdlib importlib only): one counts_delta, one self_times."""
+    if '_obs' not in sys.modules:
+        package = types.ModuleType('_obs')
+        package.__path__ = [os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), 'automerge_tpu', 'observability')]
+        sys.modules['_obs'] = package
+    return importlib.import_module('_obs.' + name)
 
 
 def load_events(path, phases=('X',)):
@@ -106,7 +112,8 @@ def load_events(path, phases=('X',)):
                      'ts': s['t0_ns'] / 1000.0,
                      'dur': s['dur_ns'] / 1000.0,
                      'tid': s.get('tid', 0) % 1_000_000,
-                     'args': s.get('attrs') or {}}
+                     'args': dict(s.get('attrs') or {}, id=s.get('id'),
+                                  parent=s.get('parent'))}
                     for s in data['recent_spans']]
         else:
             data = []
@@ -133,20 +140,31 @@ def attribution(events):
     """Per-name rollup: count, cpu (summed durations), wall (union of the
     name's intervals — with the multi-core parse, spans of one name run
     CONCURRENTLY on pool workers, so cpu > wall measures parallelism),
-    mean/max duration (µs), wall share. Returns (rows sorted by cpu desc,
-    wall_us)."""
+    mean/max duration (µs), wall share, self (summed durations less what
+    each span's children cover; an export without span ids has no tree,
+    and every span is then its own leaf). Returns (rows sorted by cpu
+    desc, wall_us)."""
     stats = {}
     ivs = {}
     lo, hi = None, None
-    for e in events:
+    tree = []
+    for k, e in enumerate(events, 1):
+        args = e.get('args') or {}
+        ts, dur = float(e.get('ts', 0.0)), float(e.get('dur', 0.0))
+        # an event without a span id (an older export) gets one of its own
+        tree.append({'id': args.get('id') or -k, 'parent': args.get('parent'),
+                     't0_ns': ts * 1000.0, 't1_ns': (ts + dur) * 1000.0})
+    self_ns = _obs_mod('spans').self_times(tree)
+    for e, node in zip(events, tree):
         name = e.get('name', '?')
         dur = float(e.get('dur', 0.0))
         ts = float(e.get('ts', 0.0))
-        ent = stats.setdefault(name, [0, 0.0, 0.0])
+        ent = stats.setdefault(name, [0, 0.0, 0.0, 0.0])
         ent[0] += 1
         ent[1] += dur
         if dur > ent[2]:
             ent[2] = dur
+        ent[3] += self_ns[node['id']] / 1000.0
         ivs.setdefault(name, []).append((ts, ts + dur))
         lo = ts if lo is None else min(lo, ts)
         hi = ts + dur if hi is None else max(hi, ts + dur)
@@ -154,8 +172,8 @@ def attribution(events):
     # % wall from the UNION, not the cpu sum: concurrent same-name spans
     # (pool workers) would otherwise print shares past 100%
     rows = [(name, n, tot, _union(ivs[name]), tot / n, mx,
-             (100.0 * _union(ivs[name]) / wall) if wall else 0.0)
-            for name, (n, tot, mx) in stats.items()]
+             (100.0 * _union(ivs[name]) / wall) if wall else 0.0, own)
+            for name, (n, tot, mx, own) in stats.items()]
     rows.sort(key=lambda r: -r[2])
     return rows, wall
 
@@ -166,13 +184,15 @@ def render_trace(path, out=None):
     print(f'# {path}: {len(events)} spans, wall {wall / 1000.0:.2f} ms',
           file=out)
     print(f'{"phase":<24}{"calls":>7}{"cpu ms":>10}{"wall ms":>10}'
-          f'{"par":>6}{"mean ms":>10}{"max ms":>10}{"% wall":>8}', file=out)
-    for name, n, tot, wall_n, mean, mx, pct in rows:
+          f'{"par":>6}{"mean ms":>10}{"max ms":>10}{"% wall":>8}'
+          f'{"self ms":>10}{"% self":>8}', file=out)
+    for name, n, tot, wall_n, mean, mx, pct, own in rows:
         par = tot / wall_n if wall_n else 1.0
         print(f'{name:<24}{n:>7}{tot / 1000.0:>10.3f}'
               f'{wall_n / 1000.0:>10.3f}{par:>6.2f}'
-              f'{mean / 1000.0:>10.3f}{mx / 1000.0:>10.3f}{pct:>8.1f}',
-              file=out)
+              f'{mean / 1000.0:>10.3f}{mx / 1000.0:>10.3f}{pct:>8.1f}'
+              f'{own / 1000.0:>10.3f}'
+              f'{100.0 * own / wall if wall else 0.0:>8.1f}', file=out)
     # Pool view: per-slice parse spans carry worker/chunk attrs; cpu/wall
     # over them is the measured pool parallelism, and occupancy relates
     # that to the configured lane count when the spans recorded it.
@@ -332,7 +352,7 @@ def render_flight(path, baseline=None, out=None):
     if baseline is not None:
         with open(baseline) as f:
             base_health = json.load(f).get('health') or {}
-        moved = {k: v for k, v in _metrics_mod().counts_delta(
+        moved = {k: v for k, v in _obs_mod('metrics').counts_delta(
             health, base_health).items() if v}
         if moved:
             print(f'# health counters moved since {baseline}: {moved}',
@@ -595,9 +615,10 @@ def render_floor(ledger_path, trace_path=None, out=None):
         print(f'# host phases beside them ({trace_path}):', file=out)
         events = load_events(trace_path)
         rows, wall = attribution(events)
-        for name, n, tot, wall_n, mean, mx, pct in rows[:12]:
+        for name, n, tot, wall_n, mean, mx, pct, own in rows[:12]:
             print(f'  {name:<30}{n:>6}{tot / 1000.0:>10.2f} ms cpu '
-                  f'({pct:>5.1f}% of wall)', file=out)
+                  f'({pct:>5.1f}% of wall), {own / 1000.0:.2f} ms self',
+                  file=out)
     mem = dump.get('watermarks')
     if mem:
         print('# memory watermarks (bytes, current / high):', file=out)
