@@ -1901,7 +1901,10 @@ class _DocCols:
     columnar-representable (0 = empty, 1 = ``head32`` holds the raw
     hash, ``head_hex``/``head_obj`` memoize the hex string / list) and
     -1 when the authoritative list lives in ``head_obj`` (multi-head
-    docs — the gate falls back to the host hex compare for those).
+    docs: the chain gate compares a first change's deps with it on the
+    host, the DAG gate takes it as a ragged blob of hashes, and the
+    columnar commit writes it for a DAG-ordered document left with
+    several heads).
 
     Clock: up to ``CLOCK_LANES`` (actor, seq) lanes per doc
     (``ck_actor`` holds ids into the fleet's clock-actor registry,
@@ -3880,8 +3883,8 @@ class _LazyHandle(dict):
 class _TurboMetaBatch:
     """Raw per-change metadata from the native parser, with lazy hex/dict
     materialization: the fast path touches only numpy arrays; full dicts are
-    built per change only for general-path gating and deferred hash-graph
-    resolution."""
+    built per change only for the general gate (documents that are neither
+    one chain nor DAG-ordered) and for deferred hash-graph resolution."""
 
     __slots__ = ('m', 'actors', 'buffers')
 
@@ -3943,9 +3946,14 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
 
     Control flow: one native parse for every change; chain validation
     (deps == current head, contiguous seqs) vectorized over the whole batch;
-    docs that fit the linear-chain shape commit through the deferred hash
-    graph with no per-change dict work, the rest go through the general
-    causal gate. The call is atomic: any gate error rolls back every doc.
+    the docs it refuses go to the native DAG gate, which takes those whose
+    batch is causally ordered (every dependency an earlier change of the
+    batch or a current head: concurrent branches in one buffer, merge
+    changes); docs of either shape commit columnar through the deferred
+    hash graph with no per-change Python object, the rest go through the
+    general causal gate and the staged per-change commit. Which way a doc
+    goes depends only on the shape of its own input. The call is atomic:
+    any gate error rolls back every doc (the native gates mutate nothing).
 
     Phase attribution: when spans are enabled the call tiles into
     contiguous `turbo_setup` / `turbo_parse` / `turbo_gate` /
@@ -3954,13 +3962,15 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     observability section checks), with the native parse / device
     dispatch sub-spans nested inside. `turbo_gate` and `turbo_commit`
     are tiled in turn by a second sequence: `gate.chain` / `gate.shape`
-    / `gate.decode` / `gate.general` (per off-chain document a
-    `gate.meta` and a `gate.drain` span) / `gate.validate`, and
-    `commit.columnar` / `commit.staged` / `commit.handles` — named
+    / `gate.dag` / `gate.decode` / `gate.general` (per document that
+    reaches it a `gate.meta` and a `gate.drain` span) / `gate.validate`,
+    and `commit.columnar` / `commit.staged` / `commit.handles` — named
     without the `turbo_` prefix, so readers that sum `turbo_*` count
     each millisecond once. `turbo_gate` carries why documents left the
-    chain path (`offchain_native` / `offchain_heads` / `offchain_seq`,
-    also in `fleet.metrics`)."""
+    chain path (`offchain_native` / `offchain_heads` / `offchain_seq`)
+    and how many of them the DAG gate took back (`offchain_dag`), all
+    also in `fleet.metrics`; the three reasons less `offchain_dag` is
+    what reached `gate.general`."""
     ps = _span_seq()
     sub = _span_seq()   # the sub-phases of turbo_gate, then turbo_commit
     ps.mark('turbo_setup', docs=len(handles))
@@ -4039,9 +4049,10 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     sub.mark('gate.chain')
 
     # ---- Batched linear-chain validation: ONE native call ----
-    # A doc takes the fast path iff every change deps on exactly the
+    # A doc is on the chain iff every change deps on exactly the
     # previous change (or the doc's current head for the first) and seqs
-    # are contiguous per actor. Everything else gets the general gate.
+    # are contiguous per actor. Everything else is the DAG gate's to
+    # look at (gate.dag below), and what that refuses the general gate's.
     # The chain-link memcmps, deps-count checks, heads compare against
     # the columnar head32 rows, and per-(doc, actor) seq-run grouping
     # all run in codec.cpp's am_turbo_gate with the GIL released —
@@ -4059,12 +4070,13 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     starts_all = np.cumsum(doc_counts) - doc_counts
     doc_off = np.concatenate([starts_all, [n_changes]])
     head_n_d = cols.head_n[erows]
+    head32_d = cols.head32[erows]
     gate = native.turbo_gate(doc_off, nmeta['actor'], seqs, hash32,
                              nmeta['deps_off'], nmeta['deps_blob'],
-                             cols.head32[erows], head_n_d)
+                             head32_d, head_n_d)
     if gate is None:
         return None
-    doc_ok, hostcheck, g_doc, g_actor, g_first, g_last = gate
+    doc_ok, hostcheck, seq_ok, g_doc, g_actor, g_first, g_last = gate
     # Why a document leaves the chain path, by the first check that
     # refuses it (documents without changes never leave it)
     has_changes = doc_counts > 0
@@ -4105,6 +4117,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         bad = g_first != base + 1
         if bad.any():
             doc_ok[g_doc[bad]] = False
+            seq_ok[g_doc[bad]] = False
     fast_mask = doc_ok
     offchain_seq = int((~fast_mask & has_changes).sum()) - \
         offchain_native - offchain_heads
@@ -4167,6 +4180,38 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
             if oid not in made_map[d] and \
                     oid not in engines[d].map_objects:
                 return None
+    sub.mark('gate.dag')
+    # ---- DAG gate: the documents the chain check refused whose batch is
+    # still causally ORDERED (concurrent branches in one buffer, a merge
+    # change naming two heads) — every dependency an earlier change of
+    # the batch or a current head, no hash twice, seq runs extending the
+    # clock (seq_ok, from the groups above). _causal_gate would apply all
+    # of such a document in buffer order and leave no queue, so it joins
+    # the columnar commit with the frontier the kernel computed; what the
+    # kernel refuses goes to the general gate untouched.
+    chain_mask = fast_mask
+    offchain_dag = 0
+    cand = ~chain_mask & has_changes & seq_ok
+    if cand.any():
+        multi_heads = {}
+        for d in np.flatnonzero(cand & (head_n_d == -1)).tolist():
+            heads = engines[d].heads
+            try:
+                blob = bytes.fromhex(''.join(heads))
+            except (TypeError, ValueError):
+                blob = b''
+            if len(blob) == 32 * len(heads):
+                multi_heads[d] = blob
+            else:
+                cand[d] = False   # heads that are no hashes: general gate
+        dag = native.dag_gate(doc_off, hash32, nmeta['deps_off'],
+                              nmeta['deps_blob'], head32_d, head_n_d,
+                              multi_heads, cand)
+        if dag is not None:
+            dag_ok, dag_nh_off, dag_nh = dag
+            offchain_dag = int(dag_ok.sum())
+            fast_mask = chain_mask | dag_ok
+    ps.note(offchain_dag=offchain_dag)
     sub.mark('gate.decode')
     # Decode every arena-boxed payload BEFORE the commit point: a payload
     # decode_value rejects (out-of-range leb, invalid UTF-8, bad float
@@ -4225,8 +4270,10 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     fleet.metrics.offchain_native += offchain_native
     fleet.metrics.offchain_heads += offchain_heads
     fleet.metrics.offchain_seq += offchain_seq
+    fleet.metrics.offchain_dag += offchain_dag
 
-    # Phase 1 — fallible: general causal gate for docs off the chain shape.
+    # Phase 1 — fallible: general causal gate for docs that are neither
+    # one chain nor DAG-ordered.
     # _drain_queue mutates clock/heads, so engines carry backups and any
     # failure restores all of them: the whole turbo call is atomic (the
     # exact path gets per-doc atomicity from fleet.pending instead).
@@ -4344,10 +4391,31 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     frows = erows[fast_ne]
     last_idx = (starts_all + doc_counts - 1)[fast_ne]
     head_rows = hash32[last_idx]
+    # positions in fast_ne of the documents left with several heads
+    multi_pos = np.zeros(0, dtype=np.int64)
+    if offchain_dag:
+        # DAG-ordered documents: the frontier the gate kernel computed.
+        # One head is a head32 row like a chain document's; k > 1 heads
+        # are the sorted hex list in head_obj (head_n -1) — hexed per
+        # head, never per change.
+        dag_pos = np.flatnonzero(~chain_mask[fast_ne])
+        nh_lo = dag_nh_off[fast_ne[dag_pos]]
+        nh_hi = dag_nh_off[fast_ne[dag_pos] + 1]
+        head_rows[dag_pos] = dag_nh[nh_lo]
+        several = nh_hi - nh_lo > 1
+        multi_pos = dag_pos[several]
     cols.head32[frows] = head_rows
     cols.head_n[frows] = 1
     cols.head_hex[frows] = None
     cols.head_obj[frows] = None
+    if len(multi_pos):
+        nh_hex = dag_nh.tobytes().hex()
+        mrows = frows[multi_pos]
+        cols.head_n[mrows] = -1
+        for r, lo, hi in zip(mrows.tolist(), nh_lo[several].tolist(),
+                             nh_hi[several].tolist()):
+            cols.head_obj[r] = [nh_hex[64 * j:64 * (j + 1)]
+                                for j in range(lo, hi)]
     cols.maxop[frows] = np.maximum(cols.maxop[frows], doc_max[fast_ne])
     cols.stale[frows] = True
     cols.bindoc[frows] = None
@@ -4474,6 +4542,8 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # materializations; slow/empty docs consult their engines eagerly
     # (few, and their memos are already warm).
     fast_pos = {int(d): k for k, d in enumerate(fast_ne.tolist())}
+    for d in fast_ne[multi_pos].tolist():
+        del fast_pos[d]          # several heads: the engine's own list
     out_handles = []
     for d, handle in enumerate(handles):
         k = fast_pos.get(d)

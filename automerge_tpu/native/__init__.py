@@ -41,7 +41,7 @@ from ..observability.spans import span as _span
 # Bumped in lockstep with codec.cpp's am_abi_version whenever the C
 # surface changes shape. A mismatch means the cached .so predates this
 # wrapper (or vice versa) and MUST NOT be used.
-_ABI_VERSION = 3
+_ABI_VERSION = 4
 
 
 class NativeAbiMismatch(RuntimeError):
@@ -717,6 +717,15 @@ def _fetch_ingest_meta(lib, n_changes):
     }
 
 
+def _deps_array(deps_blob):
+    """The parser's deps blob as a uint8 array C can take a pointer to
+    (one zero byte where there are no deps at all)."""
+    arr = np.frombuffer(deps_blob, dtype=np.uint8) \
+        if isinstance(deps_blob, (bytes, bytearray)) else \
+        np.ascontiguousarray(deps_blob, dtype=np.uint8)
+    return arr if arr.size else np.zeros(1, dtype=np.uint8)
+
+
 def turbo_gate(doc_off, actor, seq, hash32, deps_off, deps_blob,
                head32, head_n):
     """Batched linear-chain causal gate (codec.cpp am_turbo_gate): the
@@ -727,11 +736,14 @@ def turbo_gate(doc_off, actor, seq, hash32, deps_off, deps_blob,
     columnar per-doc head state (head32 rows gathered for this batch's
     docs; head_n outside {0, 1} routes that doc's first-change deps
     check back to the host). Returns None when the codec is
-    unavailable, else ``(doc_ok, doc_hostcheck, g_doc, g_actor,
-    g_first, g_last)`` — per-doc verdict bools plus the per-(doc,
-    actor) seq-run group records whose ``g_first`` the caller checks
-    against its clock columns (and whose ``g_last`` it scatters back
-    as the clock advance)."""
+    unavailable, else ``(doc_ok, doc_hostcheck, doc_seq_ok, g_doc,
+    g_actor, g_first, g_last)`` — per-doc verdict bools (``doc_ok``:
+    on the chain AND seq runs contiguous; ``doc_seq_ok``: the runs
+    alone, which is what a document `dag_gate` may still take has to
+    show) plus the per-(doc, actor) seq-run group records of EVERY
+    document, on the chain or off it, whose ``g_first`` the caller
+    checks against its clock columns (and whose ``g_last`` it scatters
+    back as the clock advance)."""
     lib = _load()
     if lib is None:
         return None
@@ -743,7 +755,7 @@ def turbo_gate(doc_off, actor, seq, hash32, deps_off, deps_blob,
         lib.am_turbo_gate.argtypes = [
             i64p, i32p, i64p, u8p, i64p, u8p, u8p, i32p,
             i64, i64, i64,
-            u8p, u8p, i32p, i32p, i64p, i64p]
+            u8p, u8p, u8p, i32p, i32p, i64p, i64p]
         lib.am_turbo_gate.restype = i64
         lib._turbo_gate_ready = True
     n_docs = len(doc_off) - 1
@@ -753,11 +765,7 @@ def turbo_gate(doc_off, actor, seq, hash32, deps_off, deps_blob,
     seq = np.ascontiguousarray(seq, dtype=np.int64)
     hash32 = np.ascontiguousarray(hash32, dtype=np.uint8)
     deps_off = np.ascontiguousarray(deps_off, dtype=np.int64)
-    deps_arr = np.frombuffer(deps_blob, dtype=np.uint8) \
-        if isinstance(deps_blob, (bytes, bytearray)) else \
-        np.ascontiguousarray(deps_blob, dtype=np.uint8)
-    if deps_arr.size == 0:
-        deps_arr = np.zeros(1, dtype=np.uint8)
+    deps_arr = _deps_array(deps_blob)
     head32 = np.ascontiguousarray(head32, dtype=np.uint8)
     head_n = np.ascontiguousarray(head_n, dtype=np.int32)
     # the actor column's ids are dense interned indexes; the scratch
@@ -765,6 +773,7 @@ def turbo_gate(doc_off, actor, seq, hash32, deps_off, deps_blob,
     n_actors = int(actor.max()) + 1 if n_changes else 1
     doc_ok = np.zeros(max(n_docs, 1), dtype=np.uint8)
     hostcheck = np.zeros(max(n_docs, 1), dtype=np.uint8)
+    seq_ok = np.zeros(max(n_docs, 1), dtype=np.uint8)
     cap = max(n_changes, 1)
     g_doc = np.zeros(cap, dtype=np.int32)
     g_actor = np.zeros(cap, dtype=np.int32)
@@ -777,13 +786,91 @@ def turbo_gate(doc_off, actor, seq, hash32, deps_off, deps_blob,
         head32.ctypes.data_as(u8p), head_n.ctypes.data_as(i32p),
         n_docs, n_changes, n_actors,
         doc_ok.ctypes.data_as(u8p), hostcheck.ctypes.data_as(u8p),
+        seq_ok.ctypes.data_as(u8p),
         g_doc.ctypes.data_as(i32p), g_actor.ctypes.data_as(i32p),
         g_first.ctypes.data_as(i64p), g_last.ctypes.data_as(i64p))
     if n_groups < 0:
         return None
     k = int(n_groups)
     return (doc_ok[:n_docs].astype(bool), hostcheck[:n_docs].astype(bool),
+            seq_ok[:n_docs].astype(bool),
             g_doc[:k], g_actor[:k], g_first[:k], g_last[:k])
+
+
+def dag_gate(doc_off, hash32, deps_off, deps_blob, head32, head_n,
+             multi_heads, cand):
+    """Batched causal gate for documents off the chain that are still
+    causally ordered (codec.cpp am_dag_gate), fanned over the pool with
+    the GIL released: concurrent branches interleaved in one buffer,
+    merge changes naming two heads.
+
+    ``cand`` marks the documents to look at (the caller has already held
+    their seq runs to its clock columns). A candidate passes iff, in
+    buffer order, every dependency of every change is an earlier change
+    of the same document in this batch or one of its current heads, and
+    no hash repeats one of those. Current heads: none (``head_n`` 0),
+    the ``head32`` row (1), or for a multi-head frontier (-1) the
+    document's entry in ``multi_heads``, a dict from batch position to
+    the concatenated 32-byte hashes. For a document that passes,
+    `HashGraph._causal_gate` would apply every change in buffer order and
+    leave no queue; the new frontier is returned with the verdict.
+    Nothing is mutated either way.
+
+    Returns None when the codec is unavailable or the inputs are
+    malformed, else ``(dag_ok, nh_off, nh)``: per-doc verdict bools and
+    the new heads as a ragged ``[n, 32]`` uint8 array in bytewise (= hex)
+    order, ``nh[nh_off[d]:nh_off[d + 1]]`` for document d (empty for a
+    document not taken)."""
+    lib = _load()
+    if lib is None:
+        return None
+    i64 = ctypes.c_int64
+    i64p = ctypes.POINTER(i64)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    if not hasattr(lib, '_dag_gate_ready'):
+        lib.am_dag_gate.argtypes = [
+            i64p, u8p, i64p, u8p, u8p, i32p, i64p, u8p, u8p, i64, i64,
+            u8p, i64p, u8p, i64]
+        lib.am_dag_gate.restype = i64
+        lib._dag_gate_ready = True
+    n_docs = len(doc_off) - 1
+    doc_off = np.ascontiguousarray(doc_off, dtype=np.int64)
+    n_changes = int(doc_off[-1])
+    hash32 = np.ascontiguousarray(hash32, dtype=np.uint8)
+    deps_off = np.ascontiguousarray(deps_off, dtype=np.int64)
+    deps_arr = _deps_array(deps_blob)
+    head32 = np.ascontiguousarray(head32, dtype=np.uint8)
+    head_n = np.ascontiguousarray(head_n, dtype=np.int32)
+    cand = np.ascontiguousarray(cand, dtype=np.uint8)
+    if hash32.size != 32 * n_changes or len(deps_off) != n_changes + 1 or \
+            deps_arr.size < 32 * int(deps_off[-1]) or \
+            head32.size != 32 * n_docs or \
+            not len(head_n) == len(cand) == n_docs:
+        raise ValueError('dag_gate: columns disagree with doc_off')
+    mh_off = np.zeros(n_docs + 1, dtype=np.int64)
+    for d, blob in multi_heads.items():
+        mh_off[d + 1] = len(blob) // 32
+    np.cumsum(mh_off, out=mh_off)
+    mh_blob = np.frombuffer(
+        b''.join(multi_heads[d] for d in sorted(multi_heads)) or b'\0',
+        dtype=np.uint8)
+    dag_ok = np.zeros(max(n_docs, 1), dtype=np.uint8)
+    nh_off = np.zeros(n_docs + 1, dtype=np.int64)
+    nh_cap = n_changes + n_docs + int(mh_off[-1])
+    nh = np.empty((max(nh_cap, 1), 32), dtype=np.uint8)
+    taken = lib.am_dag_gate(
+        doc_off.ctypes.data_as(i64p), hash32.ctypes.data_as(u8p),
+        deps_off.ctypes.data_as(i64p), deps_arr.ctypes.data_as(u8p),
+        head32.ctypes.data_as(u8p), head_n.ctypes.data_as(i32p),
+        mh_off.ctypes.data_as(i64p), mh_blob.ctypes.data_as(u8p),
+        cand.ctypes.data_as(u8p), n_docs, n_changes,
+        dag_ok.ctypes.data_as(u8p), nh_off.ctypes.data_as(i64p),
+        nh.ctypes.data_as(u8p), nh_cap)
+    if taken < 0:
+        return None
+    # copied: the view would keep the worst-case buffer alive
+    return dag_ok[:n_docs].astype(bool), nh_off, nh[:int(nh_off[-1])].copy()
 
 
 def parse_documents(buffers):

@@ -1746,7 +1746,7 @@ int64_t am_ingest_changes_list(PyObject *buffers, int with_meta,
 // Monotone ABI stamp, bumped on any C-surface change. The Python wrapper
 // refuses to run against a binary whose stamp mismatches (a stale .so
 // would otherwise silently run the old single-threaded codec).
-int64_t am_abi_version() { return 3; }
+int64_t am_abi_version() { return 4; }
 
 int64_t am_pool_configure(int n) { return NativePool::inst().configure(n); }
 
@@ -1914,10 +1914,15 @@ int64_t am_ingest_meta_fetch(int32_t *actor, int64_t *seq, int64_t *start_op,
 //     each run is emitted as a group record (g_doc/g_actor/g_first/
 //     g_last, capacity n_changes) so the caller can verify the bases
 //     against its clock columns vectorized — and scatter g_last back as
-//     the clock advance without re-deriving groups.
+//     the clock advance without re-deriving groups. The groups of EVERY
+//     document are whole (the walk goes on past a chain refusal): a
+//     document off the chain may still be DAG-ordered (am_dag_gate) and
+//     then commits its clock from the same records. doc_seq_ok[d] says
+//     the runs of d alone are contiguous, whatever its chain.
 //
 // Any violation clears doc_ok[d] (doc granularity is all the turbo path
-// needs: one bad change sends the whole doc to the general gate).
+// needs: one bad change sends the whole doc on to am_dag_gate, and what
+// that refuses to the general gate).
 // Returns the group count, or -1 on out-of-range actor ids.
 int64_t am_turbo_gate(const int64_t *doc_off, const int32_t *actor,
                       const int64_t *seq, const uint8_t *hash32,
@@ -1925,6 +1930,7 @@ int64_t am_turbo_gate(const int64_t *doc_off, const int32_t *actor,
                       const uint8_t *head32, const int32_t *head_n,
                       int64_t n_docs, int64_t n_changes, int64_t n_actors,
                       uint8_t *doc_ok, uint8_t *doc_hostcheck,
+                      uint8_t *doc_seq_ok,
                       int32_t *g_doc, int32_t *g_actor, int64_t *g_first,
                       int64_t *g_last) {
   if (n_docs < 0 || n_changes < 0 || n_actors < 0) return -1;
@@ -1935,27 +1941,28 @@ int64_t am_turbo_gate(const int64_t *doc_off, const int32_t *actor,
   int64_t n_groups = 0;
   for (int64_t d = 0; d < n_docs; d++) {
     int64_t lo = doc_off[d], hi = doc_off[d + 1];
-    uint8_t ok = 1;
+    uint8_t chain = 1, seq_ok = 1;
     doc_hostcheck[d] = 0;
     if (lo > hi || lo < 0 || hi > n_changes) return -1;
-    for (int64_t i = lo; i < hi && ok; i++) {
+    for (int64_t i = lo; i < hi; i++) {
       int64_t dc = deps_off[i + 1] - deps_off[i];
-      if (i == lo) {
+      if (!chain) {
+        // off the chain already: only the seq runs are still walked
+      } else if (i == lo) {
         int32_t hn = head_n[d];
         if (hn == 0) {
-          if (dc != 0) ok = 0;
+          if (dc != 0) chain = 0;
         } else if (hn == 1) {
           if (dc != 1 ||
               memcmp(deps_blob + deps_off[i] * 32, head32 + d * 32, 32) != 0)
-            ok = 0;
+            chain = 0;
         } else {
           doc_hostcheck[d] = 1;  // caller compares against the attr heads
         }
-      } else {
-        if (dc != 1 ||
-            memcmp(deps_blob + deps_off[i] * 32, hash32 + (i - 1) * 32,
-                   32) != 0)
-          ok = 0;
+      } else if (dc != 1 ||
+                 memcmp(deps_blob + deps_off[i] * 32, hash32 + (i - 1) * 32,
+                        32) != 0) {
+        chain = 0;
       }
       int32_t a = actor[i];
       if (a < 0 || a >= n_actors) return -1;
@@ -1968,14 +1975,154 @@ int64_t am_turbo_gate(const int64_t *doc_off, const int32_t *actor,
         g_last[n_groups] = seq[i];
         n_groups++;
       } else {
-        if (seq[i] != a_last[size_t(a)] + 1) ok = 0;
+        if (seq[i] != a_last[size_t(a)] + 1) seq_ok = 0;
         g_last[a_group[size_t(a)]] = seq[i];
       }
       a_last[size_t(a)] = seq[i];
     }
-    doc_ok[d] = ok;
+    doc_ok[d] = chain & seq_ok;
+    doc_seq_ok[d] = seq_ok;
   }
   return n_groups;
+}
+
+// ---- batched DAG gate -----------------------------------------------------
+//
+// The causal gate for the documents am_turbo_gate refused that are still
+// causally ORDERED: concurrent branches interleaved in one buffer, merge
+// changes naming two heads. A candidate document (cand[d] != 0; the
+// caller has already held its seq runs to the clock) is DAG-ordered iff,
+// walking its changes in buffer order,
+//   - every dependency is the hash of an earlier change of the same
+//     document in this batch, or one of the document's current heads
+//     (head_n 0 / 1 from head32; head_n == -1 reads the ragged
+//     mh_off / mh_blob, 32 bytes a head), and
+//   - its own hash equals no earlier hash of the batch and no current
+//     head.
+// For such a document HashGraph._causal_gate finds every change ready in
+// its first pass, applies all of them in buffer order and leaves no
+// queue, so the verdict plus the new frontier is the whole answer:
+// every old head and batch change that nothing referenced, in bytewise
+// order (hex order is byte order, so the caller's sorted(heads) holds).
+// Nothing is mutated; a document refused here goes on to the Python gate
+// untouched.
+//
+// Per document one open-addressing table over the hashes (SHA-256: the
+// first 8 bytes pick the slot, the 32-byte compare confirms) and a
+// referenced flag per change and per old head. Documents are independent
+// and fan out over the pool in slices of about equal change counts; the
+// frontier is gathered serially afterwards (a flag scan).
+//
+// Outputs: dag_ok[n_docs]; nh_off[n_docs + 1] / nh_blob, the ragged new
+// heads of the documents that passed (capacity nh_cap hashes; the caller
+// sizes it n_changes + old heads, which cannot be exceeded). Returns the
+// number of documents taken, or -1 on malformed offsets.
+int64_t am_dag_gate(const int64_t *doc_off, const uint8_t *hash32,
+                    const int64_t *deps_off, const uint8_t *deps_blob,
+                    const uint8_t *head32, const int32_t *head_n,
+                    const int64_t *mh_off, const uint8_t *mh_blob,
+                    const uint8_t *cand, int64_t n_docs, int64_t n_changes,
+                    uint8_t *dag_ok, int64_t *nh_off, uint8_t *nh_blob,
+                    int64_t nh_cap) {
+  if (n_docs < 0 || n_changes < 0) return -1;
+  for (int64_t d = 0; d < n_docs; d++) {
+    if (doc_off[d] > doc_off[d + 1] || doc_off[d] < 0 ||
+        doc_off[d + 1] > n_changes || mh_off[d] > mh_off[d + 1] ||
+        mh_off[d] < 0)
+      return -1;
+    dag_ok[d] = 0;
+  }
+  // old heads of doc d live at [d + mh_off[d], ...): room for the one
+  // columnar head plus every multi-head entry before it
+  auto old_heads = [&](int64_t d, const uint8_t **base) -> int64_t {
+    if (head_n[d] == 1) { *base = head32 + d * 32; return 1; }
+    if (head_n[d] == -1) {
+      *base = mh_blob + mh_off[d] * 32;
+      return mh_off[d + 1] - mh_off[d];
+    }
+    *base = nullptr;
+    return 0;
+  };
+  std::vector<uint8_t> ref_c(size_t(n_changes), 0);
+  std::vector<uint8_t> ref_h(size_t(n_docs + mh_off[n_docs]), 0);
+  int threads = NativePool::inst().threads();
+  int64_t n_slices = int64_t(slice_count(uint64_t(n_docs), threads));
+  if (n_slices < 1) n_slices = 1;
+  NativePool::inst().run(int(n_slices), [&](int t, int) {
+    // documents whose first change falls in this slice of the changes
+    const int64_t *d_lo = std::lower_bound(
+        doc_off, doc_off + n_docs, n_changes * int64_t(t) / n_slices);
+    const int64_t *d_hi = t + 1 == n_slices ? doc_off + n_docs :
+        std::lower_bound(doc_off, doc_off + n_docs,
+                         n_changes * int64_t(t + 1) / n_slices);
+    std::vector<int32_t> table;
+    for (int64_t d = d_lo - doc_off; d < d_hi - doc_off; d++) {
+      if (!cand[d]) continue;
+      int64_t lo = doc_off[d], hi = doc_off[d + 1], n = hi - lo;
+      const uint8_t *oh;
+      int64_t k = old_heads(d, &oh);
+      uint8_t *rh = ref_h.data() + d + mh_off[d];
+      size_t size = 16;
+      while (size < size_t(2 * (n + k))) size <<= 1;
+      size_t mask = size - 1;
+      table.assign(size, -1);
+      // entry e < n is change lo + e, e >= n is old head e - n
+      auto at = [&](int32_t e) {
+        return e < n ? hash32 + (lo + e) * 32 : oh + (e - n) * 32;
+      };
+      // the slot holding `h`, or the free slot where it would go
+      auto probe = [&](const uint8_t *h) {
+        uint64_t key;
+        memcpy(&key, h, 8);
+        size_t s = size_t(key) & mask;
+        while (table[s] >= 0 && memcmp(at(table[s]), h, 32) != 0)
+          s = (s + 1) & mask;
+        return s;
+      };
+      bool ok = true;
+      for (int64_t j = 0; j < k && ok; j++) {
+        size_t s = probe(oh + j * 32);
+        if (table[s] >= 0) ok = false;   // a frontier naming a hash twice
+        table[s] = int32_t(n + j);
+      }
+      for (int64_t i = lo; i < hi && ok; i++) {
+        for (int64_t j = deps_off[i]; j < deps_off[i + 1]; j++) {
+          int32_t e = table[probe(deps_blob + j * 32)];
+          if (e < 0) { ok = false; break; }   // neither batch nor heads
+          if (e < n) ref_c[size_t(lo + e)] = 1; else rh[e - n] = 1;
+        }
+        if (!ok) break;
+        size_t s = probe(hash32 + i * 32);
+        if (table[s] >= 0) ok = false;        // seen before: a duplicate
+        table[s] = int32_t(i - lo);
+      }
+      dag_ok[d] = ok;
+    }
+  });
+  int64_t taken = 0, out = 0;
+  std::vector<const uint8_t *> heads;
+  nh_off[0] = 0;
+  for (int64_t d = 0; d < n_docs; d++) {
+    if (dag_ok[d]) {
+      taken++;
+      heads.clear();
+      const uint8_t *oh;
+      int64_t k = old_heads(d, &oh);
+      const uint8_t *rh = ref_h.data() + d + mh_off[d];
+      for (int64_t j = 0; j < k; j++)
+        if (!rh[j]) heads.push_back(oh + j * 32);
+      for (int64_t i = doc_off[d]; i < doc_off[d + 1]; i++)
+        if (!ref_c[size_t(i)]) heads.push_back(hash32 + i * 32);
+      if (out + int64_t(heads.size()) > nh_cap) return -1;
+      std::sort(heads.begin(), heads.end(),
+                [](const uint8_t *a, const uint8_t *b) {
+                  return memcmp(a, b, 32) < 0;
+                });
+      for (const uint8_t *h : heads) memcpy(nh_blob + 32 * out++, h, 32);
+    }
+    nh_off[d + 1] = out;
+  }
+  return taken;
 }
 
 // Copy sequence-op columns captured by am_ingest_changes(with_seq=1).

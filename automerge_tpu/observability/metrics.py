@@ -103,6 +103,11 @@ class Metrics:
         'offchain_heads',        # the host compare of a multi-head frontier
         'offchain_seq',          # an actor's first seq does not extend
                                  # the document's clock
+        'offchain_dag',          # of those, the documents native.dag_gate
+                                 # took back into the columnar commit
+                                 # (causally ordered, just not one chain);
+                                 # the three reasons summed, less this, is
+                                 # what reached the general gate
     )
 
     def __init__(self):

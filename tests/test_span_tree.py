@@ -208,15 +208,27 @@ def linear_log(doc, n=6):
     return log
 
 
-GATE = ['gate.chain', 'gate.shape', 'gate.decode', 'gate.general',
-        'gate.validate']
+def out_of_order_log(doc, n=3):
+    """The two-headed log with one actor's first two changes swapped: not
+    causally ordered, so the DAG gate refuses it too."""
+    log = two_headed_log(doc, n)
+    log[0], log[2] = log[2], log[0]
+    return log
+
+
+GATE = ['gate.chain', 'gate.shape', 'gate.dag', 'gate.decode',
+        'gate.general', 'gate.validate']
 COMMIT = ['commit.columnar', 'commit.staged', 'commit.handles']
 
 
 @pytest.mark.skipif(not native.available(), reason='needs the native codec')
-@pytest.mark.parametrize('make_log,off_chain', [(two_headed_log, True),
-                                                (linear_log, False)])
-def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain):
+@pytest.mark.parametrize('make_log,off_chain,dag', [
+    (two_headed_log, True, True),       # off the chain, DAG-ordered
+    (linear_log, False, False),
+    (out_of_order_log, True, False),    # the Python gate, per document
+])
+def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain,
+                                                     dag):
     n_docs = 4
     fleet = DocFleet(doc_capacity=n_docs, key_capacity=8)
     handles = init_docs(n_docs, fleet)
@@ -245,13 +257,16 @@ def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain):
     mine = [s for s in spans if s['tid'] == threading.get_ident()]
     assert len(mine) > 12 and all(s['root'] == batch['id'] for s in mine)
 
-    off = n_docs if off_chain else 0
+    refused = n_docs if off_chain else 0    # by the chain check
+    taken = n_docs if dag else 0            # of those, by the DAG gate
+    off = refused - taken                   # what reaches the Python gate
     reasons = {k: v for k, v in named['turbo_gate'][0]['attrs'].items()
                if k.startswith('offchain_')}
-    assert reasons == {'offchain_native': off, 'offchain_heads': 0,
-                       'offchain_seq': 0}
+    assert reasons == {'offchain_native': refused, 'offchain_heads': 0,
+                       'offchain_seq': 0, 'offchain_dag': taken}
     assert (fleet.metrics.offchain_native, fleet.metrics.offchain_heads,
-            fleet.metrics.offchain_seq) == (off, 0, 0)
+            fleet.metrics.offchain_seq, fleet.metrics.offchain_dag) == \
+        (refused, 0, 0, taken)
     assert fleet.metrics.turbo_commit_fallback_docs == off
     assert named['gate.general'][0]['attrs'] == {'docs': off}
     assert named['commit.staged'][0]['attrs'] == {'docs': off}
@@ -262,7 +277,7 @@ def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain):
         assert all(s['parent'] == general['id'] and
                    s['attrs']['changes'] == len(per_doc[0])
                    for s in per_document)
-    if not off_chain:
+    if not off:
         # nothing staged: the phase is there and as good as empty
         staged = named['commit.staged'][0]
         assert staged['dur_ns'] < named['turbo_commit'][0]['dur_ns'] / 2
@@ -281,7 +296,7 @@ def test_a_skipped_seq_is_its_own_reason_and_a_raise_closes_every_phase():
     named = by_name(observability.iter_spans())
     (gate,) = named['turbo_gate']
     assert gate['attrs'] == {'offchain_native': 0, 'offchain_heads': 0,
-                             'offchain_seq': 1}
+                             'offchain_seq': 1, 'offchain_dag': 0}
     assert fleet.metrics.offchain_seq == 1
     # the general gate raised inside gate.drain: each open phase closed
     assert named['gate.drain'][0]['error'] == 'ValueError'   # typed above it
