@@ -367,6 +367,7 @@ class DocFleet:
         self.seq_rows = []        # row -> {'slot','object_id','type'} | None
         self.seq_place = []       # row -> (cls, idx) | None (unwritten)
         self.seq_len = []         # row -> host upper bound on elements
+        self.seq_writers = []     # row -> actors (hex) that wrote a lane
         self.seq_free = []
         self.slot_seq = {}        # slot -> {objectId: row}
         # Optional durability hook (fleet/durability.py ChangeJournal):
@@ -593,15 +594,16 @@ class DocFleet:
         if src_idx is not None:
             self._op_index[dst] = src_idx.copy()
         copies = {}    # cls -> ([src idx], [dst idx])
-        lanes = self._seq_lane_width()
         for oid, row in list(self.slot_seq.get(src, {}).items()):
             info = self.seq_rows[row]
             dst_row = self._alloc_seq_row(dst, oid, info['type'])
             place = self.seq_place[row]
             if place is not None:
-                idx = self.seq_pools.alloc(place[0], lanes)
+                # the same class: its pool is as wide as the source needs
+                idx = self.seq_pools.alloc(place[0], self._seq_lanes(row))
                 self.seq_place[dst_row] = (place[0], idx)
                 self.seq_len[dst_row] = self.seq_len[row]
+                self.seq_writers[dst_row] = set(self.seq_writers[row])
                 srcs, dsts = copies.setdefault(place[0], ([], []))
                 srcs.append(place[1])
                 dsts.append(idx)
@@ -671,16 +673,22 @@ class DocFleet:
             self.seq_rows[row] = info
             self.seq_place[row] = None
             self.seq_len[row] = 0
+            self.seq_writers[row] = set()
         else:
             row = len(self.seq_rows)
             self.seq_rows.append(info)
             self.seq_place.append(None)
             self.seq_len.append(0)
+            self.seq_writers.append(set())
         self.slot_seq.setdefault(slot, {})[object_id] = row
         return row
 
-    def _seq_lane_width(self):
-        return _pow2(max(len(self.actors), 4))
+    def _seq_lanes(self, row):
+        """Register lanes a pool needs to hold `row`: one for each actor
+        that has written the row (an element's lanes are one a writer),
+        pow2, whatever the fleet's actor table holds."""
+        from .sequence import DEFAULT_ACTOR_SLOTS
+        return _pow2(max(len(self.seq_writers[row]), DEFAULT_ACTOR_SLOTS))
 
     def _seq_need(self, row, need_len):
         """(size class, performs-a-fresh-pool-alloc) for placing `row` at
@@ -699,15 +707,32 @@ class DocFleet:
         self.seq_len[row] = max(self.seq_len[row], need_len, 1)
         pools = self.seq_pools
         place = self.seq_place[row]
-        lanes = self._seq_lane_width()
+        lanes = self._seq_lanes(row)
         if place is None:
             idx = pools.alloc(need_cls, lanes)
             place = (need_cls, idx)
         elif need_cls > place[0]:
             idx = pools.migrate(place[0], place[1], need_cls, lanes)
+            self.metrics.seq_migrations += 1
             place = (need_cls, idx)
         self.seq_place[row] = place
         return place
+
+    def _place_seq_rows(self, rows, need_lens):
+        """_place_seq_row for many rows, each pool's capacity and lanes
+        reserved ONCE for all the rows landing in it (the round-5 on-chip
+        mixed-seam dispatch storm: per-alloc pow2 growth cost 72 device
+        copies at 500 fresh docs). Returns each row's (cls, idx)."""
+        want = {}     # cls -> [fresh rows, lanes]
+        for row, need_len in zip(rows, need_lens):
+            need_cls, fresh = self._seq_need(row, need_len)
+            entry = want.setdefault(need_cls, [0, 0])
+            entry[0] += fresh
+            entry[1] = max(entry[1], self._seq_lanes(row))
+        for cls, (count, lanes) in want.items():
+            self.seq_pools.reserve(cls, count, lanes)
+        return [self._place_seq_row(row, need_len)
+                for row, need_len in zip(rows, need_lens)]
 
     def seq_row_inexact(self, row):
         """Host read of one device row's inexact flag (False when the row
@@ -734,24 +759,19 @@ class DocFleet:
     @_spanned('actor_remap')
     def _remap_seq_actors(self, perm):
         """Renumber the actor bits of packed elemIds/register opIds in every
-        sequence pool after a sorted-order actor insertion, permuting the
-        actor-lane axis the same way (lanes are indexed by actor number,
-        like _remap_reg_actors; machinery shared via _lane_permutation)."""
+        sequence pool after a sorted-order actor insertion. The lanes stay
+        where they are: an element's lanes are an unordered set found by
+        value, not indexed by actor number as the map registers' are
+        (_remap_reg_actors)."""
         if not self.seq_pools.pools:
             return
-        import jax.numpy as jnp
         from .sequence import SeqState
-        # Grow every pool's lane axis FIRST (same rationale as
-        # _remap_reg_actors)
-        self.seq_pools.ensure_lanes(self._seq_lane_width())
         self.metrics.remaps += 1
+        renum = self._actor_renumber(perm)
         for cls, st in list(self.seq_pools.pools.items()):
-            move, renum = self._lane_permutation(perm, st.reg.shape[2])
             self.seq_pools.pools[cls] = SeqState(
-                renum(st.elem_id), jnp.asarray(st.nxt),
-                renum(move(st.reg, 0)), move(st.killed, False),
-                move(st.val, 0), move(st.counter, 0), jnp.asarray(st.n),
-                jnp.asarray(st.inexact))
+                renum(st.elem_id), st.nxt, renum(st.reg), st.killed,
+                st.val, st.counter, st.n, st.inexact)
 
     def _intern_value(self, value):
         """Inline int32 in [0, 2^31) or a value-table ref -(i + 2)."""
@@ -875,53 +895,47 @@ class DocFleet:
         """Place every touched row in a size-class pool with enough
         capacity (migrating rows that outgrew their class) and batch-apply
         all pending sequence ops — ONE dispatch per active size class.
-        seq_ops rows are (row, kind, ref, packed, value, pred0..D-1, flag)."""
+        seq_ops rows are (row, kind, ref, packed, value, pred0..D-1, flag).
+        Its three phases tile it as spans: seq.place, seq.columns,
+        seq.enqueue (one a class)."""
         from .sequence import SeqOpBatch, apply_seq_batch_donated, \
-            INSERT, \
-            SEQ_PRED_LANES
+            ACTOR_MASK, INSERT, SET, SEQ_PRED_LANES
         if len(self.seq_rows) == 0 or len(seq_ops) == 0:
             return
-        # Widen every pool's lane axis FIRST: a new actor whose hex sorts
-        # after all existing ones produces no remap (identity perm), yet
-        # its lane must exist before its ops apply
-        self.seq_pools.ensure_lanes(self._seq_lane_width())
+        ps = _span_seq()
+        ps.mark('seq.place')
         D = SEQ_PRED_LANES
         arr = np.asarray(seq_ops, dtype=np.int64)   # [M, 6 + D] op tuples
         row_a = arr[:, 0]
         n_rows = len(self.seq_rows)
         counts = np.bincount(row_a, minlength=n_rows)
         ins = np.bincount(row_a[arr[:, 1] == INSERT], minlength=n_rows)
+        # An insert or a set takes a lane on its element: note who wrote
+        # each row, so that its pool has a lane for every writer
+        writes = (arr[:, 1] == INSERT) | (arr[:, 1] == SET)
+        for pair in np.unique((row_a[writes] << ACTOR_BITS) |
+                              (arr[writes, 3] & ACTOR_MASK)).tolist():
+            self.seq_writers[pair >> ACTOR_BITS].add(
+                self.actors.actors[pair & ACTOR_MASK])
         # Placement pass: host-tracked element counts give each row's
-        # needed capacity class without any device reads. Reserve each
-        # pool's capacity ONCE for all rows landing in it this dispatch
-        # (the round-5 on-chip mixed-seam dispatch storm: per-alloc pow2
-        # growth cost 72 device copies at 500 fresh docs).
+        # needed capacity class without any device reads.
         pools = self.seq_pools
-        lanes = self._seq_lane_width()
-        uniq_rows = [int(r) for r in np.unique(row_a)]
-        new_by_cls = {}
-        for row in uniq_rows:
-            need_cls, fresh = self._seq_need(
-                row, self.seq_len[row] + int(ins[row]))
-            if fresh:
-                new_by_cls[need_cls] = new_by_cls.get(need_cls, 0) + 1
-        for cls, count in new_by_cls.items():
-            pools.reserve(cls, count, lanes)
-        cls_of = {}
-        for row in uniq_rows:
-            cls_of[row], _ = self._place_seq_row(
-                row, self.seq_len[row] + int(ins[row]))
+        uniq_rows = np.unique(row_a).tolist()
+        places = self._place_seq_rows(
+            uniq_rows, [self.seq_len[row] + int(ins[row])
+                        for row in uniq_rows])
         # One batch per active class, rows addressed by pool index
         by_cls = {}
-        for row, cls in cls_of.items():
+        for row, (cls, _idx) in zip(uniq_rows, places):
             by_cls.setdefault(cls, []).append(row)
+        ps.mark('seq.columns')
         order = np.argsort(row_a, kind='stable')
         row_sorted = row_a[order]
         pos_in_row = np.arange(len(row_sorted)) - \
             np.searchsorted(row_sorted, row_sorted, side='left')
+        batches = []
         for cls, rows in by_cls.items():
-            st = self.seq_pools.state(cls)
-            r_cap = st.elem_id.shape[0]
+            r_cap = pools.state(cls).elem_id.shape[0]
             sel = np.isin(row_sorted, rows)
             sub = order[sel]
             idx_of = np.zeros(n_rows, dtype=np.int64)
@@ -929,22 +943,40 @@ class DocFleet:
                 idx_of[row] = self.seq_place[row][1]
             rows_idx = idx_of[row_sorted[sel]]
             pos = pos_in_row[sel]
-            width = max(int(counts[rows].max()), 1)
+            # the longest list, to a power of two (PAD cells are no-ops):
+            # a pool's programs are one a width bucket, not one a length
+            width = _pow2(int(counts[rows].max()))
             cols = {name: np.zeros((r_cap, width), dtype=np.int32)
                     for name in ('kind', 'ref', 'packed', 'value')}
             preds = np.zeros((r_cap, width, D), dtype=np.int32)
             flag = np.zeros((r_cap, width), dtype=bool)
             for j, name in enumerate(('kind', 'ref', 'packed', 'value')):
                 cols[name][rows_idx, pos] = arr[sub, j + 1]
-            for d in range(D):
-                preds[rows_idx, pos, d] = arr[sub, 5 + d]
+            preds[rows_idx, pos] = arr[sub, 5:5 + D]
             flag[rows_idx, pos] = arr[sub, 5 + D] != 0
-            batch = SeqOpBatch(cols['kind'], cols['ref'], cols['packed'],
-                               cols['value'], preds, flag)
-            new_state, _stats = apply_seq_batch_donated(st, batch)
-            self.seq_pools.pools[cls] = new_state
+            batches.append((cls, len(sub), SeqOpBatch(
+                cols['kind'], cols['ref'], cols['packed'], cols['value'],
+                preds, flag)))
+        for cls, n_ops, batch in batches:
+            r_cap, width = batch.kind.shape
+            ps.mark('seq.enqueue', cls=cls, rows=r_cap, width=width,
+                    ops=n_ops)
+            pools.pools[cls], _stats = apply_seq_batch_donated(
+                pools.state(cls), batch)
             self.metrics.dispatches += 1
+            self.metrics.seq_op_cells += r_cap * width
+        ps.done()
+        self.metrics.seq_ops += len(seq_ops)
         self.metrics.device_ops += len(seq_ops)
+        self._note_seq_pools()
+
+    def _note_seq_pools(self):
+        """The sequence pools' size into the metrics, from shapes alone:
+        bytes of every pool's arrays, and rows x nodes."""
+        states = self.seq_pools.pools.values()
+        self.metrics.seq_pool_bytes = sum(
+            a.nbytes for st in states for a in st.tree_flatten()[0])
+        self.metrics.seq_nodes = sum(st.elem_id.size for st in states)
 
     def render_seq_all(self):
         """Render every live sequence row: {row: str/list}, with None for
@@ -985,6 +1017,7 @@ class DocFleet:
                 idx = self.seq_place[row][1]
                 if inexact[idx]:
                     out[row] = None
+                    self.metrics.seq_inexact_reads += 1
                     continue
                 # counter lanes bit-pack (sum << 2) | count-bits
                 items = [(int(v), int(c) >> 2) for v, c in
@@ -1118,17 +1151,32 @@ class DocFleet:
         self.reg_state = self._shard_docs(RegisterState(*grown, inexact))
 
     @staticmethod
-    def _lane_permutation(perm, n_lanes):
-        """Shared actor-lane permutation machinery for the register and
-        sequence engines: lanes are indexed by actor number, so a
-        sorted-order actor insertion (perm: old actor num -> new actor num)
-        both renumbers packed-id actor bits and moves every lane.
+    def _actor_renumber(perm):
+        """renum(arr) for a sorted-order actor insertion (perm: old actor
+        num -> new actor num): rewrites the actor bits of non-zero packed
+        opIds. All the sequence pools need; the map registers, whose lanes
+        are indexed by actor number, move their lanes too
+        (_lane_permutation)."""
+        import jax.numpy as jnp
+        mask = MAX_ACTORS - 1
+        perm_full = np.arange(MAX_ACTORS, dtype=np.int32)
+        perm_full[:len(perm)] = perm
+        bits = jnp.asarray(perm_full)
 
-        Returns (move, renum): move(arr, fill) permutes the trailing lane
-        axis of a [..., n_lanes] array — every pre-existing actor appears in
-        perm; lanes not fed by any old actor (newly inserted actors, plus
-        the unused tail) start as `fill` — and renum(arr) rewrites the
-        actor bits of non-zero packed opIds."""
+        def renum(arr):
+            arr = jnp.asarray(arr)
+            return jnp.where(arr != 0, (arr & ~mask) | bits[arr & mask], 0)
+
+        return renum
+
+    @staticmethod
+    def _lane_permutation(perm, n_lanes):
+        """move(arr, fill) for the register engine, whose lanes are indexed
+        by actor number, so that a sorted-order actor insertion moves every
+        lane: it permutes the trailing lane axis of a [..., n_lanes] array
+        — every pre-existing actor appears in perm; lanes not fed by any
+        old actor (newly inserted actors, plus the unused tail) start as
+        `fill`."""
         import jax.numpy as jnp
         old_of_new = np.zeros(n_lanes, dtype=np.int32)
         fresh = np.ones(n_lanes, dtype=bool)
@@ -1138,20 +1186,12 @@ class DocFleet:
                 fresh[new_i] = False
         gather = jnp.asarray(old_of_new)
         zero_new = jnp.asarray(fresh)
-        mask = MAX_ACTORS - 1
-        perm_full = np.arange(MAX_ACTORS, dtype=np.int32)
-        perm_full[:len(perm)] = perm
-        bits = jnp.asarray(perm_full)
 
         def move(arr, fill):
             out = jnp.asarray(arr)[..., gather]
             return jnp.where(zero_new, jnp.full_like(out, fill), out)
 
-        def renum(arr):
-            arr = jnp.asarray(arr)
-            return jnp.where(arr != 0, (arr & ~mask) | bits[arr & mask], 0)
-
-        return move, renum
+        return move
 
     @_spanned('actor_remap')
     def _remap_reg_actors(self, perm):
@@ -1169,7 +1209,8 @@ class DocFleet:
         self._ensure_reg_capacity(n_docs=self.n_slots, n_keys=len(self.keys))
         self.metrics.remaps += 1
         rs = self.reg_state
-        move, renum = self._lane_permutation(perm, rs.reg.shape[2])
+        move = self._lane_permutation(perm, rs.reg.shape[2])
+        renum = self._actor_renumber(perm)
         self.reg_state = RegisterState(
             renum(move(rs.reg, 0)), move(rs.killed, False),
             move(rs.value, 0), move(rs.counter, 0), rs.inexact)
@@ -2829,6 +2870,9 @@ class _FlatEngine(HashGraph):
                 _np.asarray(x) for x in jax.device_get(
                     (st.elem_id[idx], st.nxt[idx], st.reg[idx],
                      st.killed[idx], st.val[idx], st.counter[idx])))
+            # a lane array is [lanes * nodes] on the device
+            reg, killed, val, cnt = (x.reshape(st.actor_slots, -1).T
+                                     for x in (reg, killed, val, cnt))
             is_text = self.seq_objects.get(oid) == 'text'
             elems = []
             node = int(nxt[HEAD])

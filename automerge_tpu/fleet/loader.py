@@ -505,7 +505,7 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
     sequences) become link-valued elements, matching the ordinary apply
     path (backend._pack_seq_op)."""
     import jax.numpy as jnp
-    from .sequence import SeqState, END, HEAD, SLOT0
+    from .sequence import END, HEAD, SLOT0
 
     rows = np.flatnonzero(sel)
     if not len(rows):
@@ -564,12 +564,17 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
     # value lanes (text: single codepoints inline; lists: ints inline;
     # everything else boxes; counters flag the row, ref new.js:937-965)
     txt = is_text[inv]
-    values = np.zeros(len(rows), dtype=np.int64)
     flag_counter = np.zeros(len(rows), dtype=bool)
-    for i, j in enumerate(rows):
-        jj = int(j)
-        if inc_mask[jj]:
-            continue   # consumed via succ attribution into counter lanes
+    # the inline payloads in one pass: a text row's single code point, a
+    # list row's plain int; only what is left takes the per-op walk
+    vt_rows, vi_rows = vtype[rows], val_int[rows]
+    inline = ~inc_mask[rows] & ~make_mask[rows] & np.where(
+        txt, (vt_rows == 6) & (vi_rows >= 0),
+        (vt_rows == 4) & (vi_rows >= 0) & (vi_rows < (1 << 31)))
+    values = np.where(inline, vi_rows, 0).astype(np.int64)
+    # (incs are consumed via succ attribution into counter lanes)
+    for i in np.flatnonzero(~inline & ~inc_mask[rows]).tolist():
+        jj = int(rows[i])
         if make_mask[jj]:
             # Nested object as a sequence element: fleet._make_link_value
             # is THE shared make-op link rule (links the child, allocates
@@ -582,15 +587,9 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
                 # serves those reads (same rule as _pack_seq_op)
                 flag_counter[i] = True
             continue
-        vt, vi = int(vtype[jj]), int(val_int[jj])
-        if txt[i] and vt == 6 and vi >= 0:
-            values[i] = vi
-            continue
-        elif not txt[i] and vt == 4 and 0 <= vi < (1 << 31):
-            values[i] = vi
-            continue
         off, ln = int(out['val_off'][jj]), int(out['val_len'][jj])
-        decoded = decode_value((ln << 4) | vt, out['val_blob'][off:off + ln])
+        decoded = decode_value((ln << 4) | int(vtype[jj]),
+                               out['val_blob'][off:off + ln])
         dt = decoded.get('datatype')
         if isinstance(dt, str) and dt != 'int':
             # fleet._intern_typed — THE datatype-boxing rule (shared with
@@ -620,10 +619,15 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
         dup = np.isin(lane_cell, uq[cnt > 1])
         np.logical_or.at(inex_obj, inv[live_mask][dup], True)
 
-    # place each object in its size class (host-tracked lengths), then
-    # install per class: one chain/element/lane scatter set per class
-    place = [fleet._place_seq_row(int(fleet_row[u]), int(n_elems[u]))
-             for u in range(len(uniq))]
+    # who wrote each row (every op but an inc takes a lane on its
+    # element), so that its pool has a lane for every writer; then place
+    # each object in its size class (host-tracked lengths) and install per
+    # class: one chain/element/lane scatter set per class
+    wrote = np.zeros((len(uniq), MAX_ACTORS), dtype=bool)
+    wrote[inv[~inc_mask[rows]], id_actor[rows][~inc_mask[rows]]] = True
+    for u, a in np.argwhere(wrote).tolist():
+        fleet.seq_writers[int(fleet_row[u])].add(fleet.actors.actors[a])
+    place = fleet._place_seq_rows(fleet_row.tolist(), n_elems.tolist())
     cls_arr = np.array([p[0] for p in place], dtype=np.int64)
     idx_arr = np.array([p[1] for p in place], dtype=np.int64)
     idx_of_op = idx_arr[inv]
@@ -647,27 +651,30 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
                         np.arange(SLOT0 + 1, SLOT0 + n_k, dtype=np.int32)
                 nxt_host[i, SLOT0 + n_k - 1] = END
         tr = jnp.asarray(idx_arr[objs])
-        new_nxt = st.nxt.at[tr].set(jnp.asarray(nxt_host))
-        new_n = st.n.at[tr].set(jnp.asarray(n_host))
 
-        in_cls = np.isin(inv, objs)
+        def install(name, host_rows):
+            # one array at a time, the pool's state updated in place, so
+            # that only one array's old and new copy are alive at once
+            setattr(st, name,
+                    getattr(st, name).at[tr].set(jnp.asarray(host_rows)))
+
+        install('nxt', nxt_host)
+        install('n', n_host)
+
+        # The rows are fresh (a loaded object's row is placed just above),
+        # so each array's rows are built whole on the host and installed
+        # with ONE row scatter: a scatter of single elements costs the
+        # device about a microsecond an element, minutes for long texts
+        row_of = np.full(len(uniq), -1, dtype=np.int64)
+        row_of[objs] = np.arange(len(objs))
+        row_of_op = row_of[inv]
+        in_cls = row_of_op >= 0
         ins_sel = np.flatnonzero(ins & in_cls)
-        eidx = (jnp.asarray(idx_of_op[ins_sel]),
-                jnp.asarray(node[ins_sel]))
-        new_elem = st.elem_id.at[eidx].set(
-            jnp.asarray(packed32[rows][ins_sel].astype(np.int32)))
+        elem_host = np.zeros((len(objs), nodes), dtype=np.int32)
+        elem_host[row_of_op[ins_sel], node[ins_sel]] = packed32[rows][ins_sel]
+        install('elem_id', elem_host)
 
         live_sel = np.flatnonzero(live_mask & in_cls)
-        lidx = (jnp.asarray(idx_of_op[live_sel]),
-                jnp.asarray(node[live_sel]),
-                jnp.asarray(id_actor[rows][live_sel]))
-        new_reg = st.reg.at[lidx].set(
-            jnp.asarray(packed32[rows][live_sel].astype(np.int32)))
-        new_killed = st.killed.at[lidx].set(False)
-        new_val = st.val.at[lidx].set(
-            jnp.asarray(values[live_sel].astype(np.int32)))
-        new_counter = st.counter.at[lidx].set(
-            jnp.asarray(counter_add[rows][live_sel].astype(np.int32)))
         # Dead counter sets that consumed incs install as KILLED lanes
         # with their counter bits: the patch walk needs them to emit the
         # reference's phantom remove / remove->update edits for deleted
@@ -676,10 +683,10 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
             in_cls & ~live_mask & ~inc_mask[rows] & ~bad_upd &
             ((counter_add[rows] & 3) != 0))
         if len(dead_sel):
-            # A dead inc'd counter whose lane was reclaimed by the same
-            # actor cannot be represented (sequence.py flags the same
+            # A dead inc'd counter whose actor holds a live op on the
+            # element cannot be represented (sequence.py flags the same
             # shape reclaim_incd): route the object to the mirror rather
-            # than clobber the live lane
+            # than keep two ops of one actor on an element
             lane_key = (idx_of_op.astype(np.int64) * (1 << 40) +
                         node.astype(np.int64) * 512 +
                         id_actor[rows].astype(np.int64))
@@ -687,25 +694,42 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
             if taken.any():
                 np.logical_or.at(inex_obj, inv[dead_sel[taken]], True)
                 dead_sel = dead_sel[~taken]
-        if len(dead_sel):
-            didx = (jnp.asarray(idx_of_op[dead_sel]),
-                    jnp.asarray(node[dead_sel]),
-                    jnp.asarray(id_actor[rows][dead_sel]))
-            new_reg = new_reg.at[didx].set(
-                jnp.asarray(packed32[rows][dead_sel].astype(np.int32)))
-            new_killed = new_killed.at[didx].set(True)
-            new_val = new_val.at[didx].set(
-                jnp.asarray(values[dead_sel].astype(np.int32)))
-            new_counter = new_counter.at[didx].set(
-                jnp.asarray(counter_add[rows][dead_sel].astype(np.int32)))
-
-        new_inexact = st.inexact
+        # an element's lanes are an unordered set: its ops take lanes 0, 1,
+        # ... as they come, the live ones first
+        both = np.concatenate([live_sel, dead_sel])
+        lane = _lane_ordinals(idx_of_op, node, both)
+        # more ops on an element than its writers (two live ops of one
+        # actor, flagged above): the row is the mirror's, keep in bounds
+        over = lane >= st.actor_slots
+        if over.any():
+            np.logical_or.at(inex_obj, inv[both[over]], True)
+            lane = np.minimum(lane, st.actor_slots - 1)
+        # lane l of node i is at column l * nodes + i
+        at = (row_of_op[both], lane * nodes + node[both])
+        lanes_shape = (len(objs), st.actor_slots * nodes)
+        for name, values_of in (('reg', packed32[rows]), ('val', values),
+                                ('counter', counter_add[rows])):
+            host = np.zeros(lanes_shape, dtype=np.int32)
+            host[at] = values_of[both]
+            install(name, host)
+        host = np.zeros(lanes_shape, dtype=bool)
+        host[at[0][len(live_sel):], at[1][len(live_sel):]] = True
+        install('killed', host)
         inex = objs[inex_obj[objs]]
         if len(inex):
-            new_inexact = new_inexact.at[jnp.asarray(idx_arr[inex])].set(
-                True)
-        fleet.seq_pools.pools[cls] = SeqState(
-            new_elem, new_nxt, new_reg, new_killed, new_val, new_counter,
-            new_n, new_inexact)
+            st.inexact = st.inexact.at[jnp.asarray(idx_arr[inex])].set(True)
         fleet.metrics.dispatches += 1
     fleet.metrics.device_ops += len(rows)
+    fleet._note_seq_pools()
+
+
+def _lane_ordinals(idx_of_op, node, sel):
+    """For the op rows `sel`, in that order: each op's ordinal among those
+    of `sel` on the same (pool row, node)."""
+    cell = idx_of_op[sel].astype(np.int64) * (1 << 40) + node[sel]
+    order = np.argsort(cell, kind='stable')
+    cell_s = cell[order]
+    lane = np.empty(len(sel), dtype=np.int64)
+    lane[order] = np.arange(len(sel)) - np.searchsorted(cell_s, cell_s,
+                                                        side='left')
+    return lane

@@ -27,7 +27,8 @@ sorts its actor table the same way, ref backend/columnar.js:133-170).
 
 Per-element overwrite state is an exact multi-value register (the
 fleet/registers.py design applied to sequence elements): each element keeps
-an actor-slotted visible set — packed opId + payload per actor lane, with a
+a visible set of a few lanes — packed opId + payload per lane, one lane an
+actor that wrote the element, found by value and in no order — with a
 `killed` bit marking ops that have a successor (ref new.js:1204-1217's
 succNum == 0 visibility rule). A SET/DEL kills exactly its preds, never
 concurrent ops, so the two shapes where single-winner LWW diverges from the
@@ -63,8 +64,8 @@ ACTOR_MASK = MAX_ACTORS - 1
 # bound the *representable* conflict width, matching registers.RegisterOpBatch.
 SEQ_PRED_LANES = 4
 
-# Default actor-lane width for new states; grows on demand (pow2) with the
-# fleet's actor table.
+# Default lane width for new states; a pool widens (pow2) when a row in it
+# has had more writers.
 DEFAULT_ACTOR_SLOTS = 4
 
 
@@ -87,11 +88,17 @@ class SeqState:
       elem_id  packed elemId per slot (0 = unallocated)
       nxt      linked-list next pointers over node ids
 
-    Per-element multi-value registers ([N, S+3, A], actor-lane indexed by the
-    op's packed actor number — at most one live op per actor per element in
-    causally well-formed histories, since the frontend always preds its own
-    visible op, ref frontend/context.js:576-586):
-      reg      packed opId of actor lane a's op on this element (0 = none)
+    Per-element multi-value registers ([N, A * (S+3)]: lane l of node i at
+    column l * (S+3) + i, A node-indexed segments side by side. Not a third
+    axis: a TPU pads an array's last axis to 128 lanes, so a trailing axis
+    of 4 takes 32 times its size, and XLA moves a lane axis there for the
+    scan's gathers wherever it is declared. An unordered set of A lanes,
+    one for each actor that wrote the element — at most one live op
+    per actor per element in causally well-formed histories, since the
+    frontend always preds its own visible op, ref
+    frontend/context.js:576-586 — so A follows the writers of a pool's
+    rows, never the fleet's actor table):
+      reg      packed opId of the lane's op on this element (0 = empty)
       killed   that op has a successor (overwritten / deleted)
       val      the op's payload (char code / value-table ref)
       counter  accumulated inc deltas for the lane's op, bit-packed as
@@ -129,12 +136,12 @@ class SeqState:
 
     @property
     def actor_slots(self):
-        return self.reg.shape[2]
+        return self.reg.shape[1] // self.elem_id.shape[1]
 
     @classmethod
     def empty(cls, n_docs, capacity, actor_slots=DEFAULT_ACTOR_SLOTS, xp=np):
         nodes = (n_docs, capacity + 3)
-        lanes = (n_docs, capacity + 3, actor_slots)
+        lanes = (n_docs, actor_slots * (capacity + 3))
         nxt = xp.full(nodes, END, dtype=np.int32)
         return cls(
             xp.zeros(nodes, dtype=np.int32),
@@ -155,6 +162,13 @@ class SeqState:
         return cls(*children)
 
 
+def lane_segments(arr, actor_slots):
+    """The A node-indexed segments of a lane array [N, A * nodes], each
+    [N, nodes]."""
+    nodes = arr.shape[1] // actor_slots
+    return [arr[:, l * nodes:(l + 1) * nodes] for l in range(actor_slots)]
+
+
 def grow_seq_state(state, n_rows, capacity, actor_slots=None):
     """Host-side resize to at least (n_rows rows, capacity slots,
     actor_slots lanes): new rows/slots/lanes are zeroed/END-filled; existing
@@ -163,7 +177,7 @@ def grow_seq_state(state, n_rows, capacity, actor_slots=None):
     `state` unchanged if already big enough."""
     old_r, old_nodes = state.elem_id.shape
     old_cap = old_nodes - 3
-    old_a = state.reg.shape[2]
+    old_a = state.actor_slots
     want_a = old_a if actor_slots is None else actor_slots
     if n_rows <= old_r and capacity <= old_cap and want_a <= old_a:
         return state
@@ -175,8 +189,10 @@ def grow_seq_state(state, n_rows, capacity, actor_slots=None):
         return out.at[:old_r, :old_nodes].set(arr)
 
     def pad_lane(arr, fill, dtype):
-        out = jnp.full((r, cap + 3, a), fill, dtype=dtype)
-        return out.at[:old_r, :old_nodes, :old_a].set(arr)
+        segments = [pad(lane, fill, dtype)
+                    for lane in lane_segments(arr, old_a)]
+        segments += [jnp.full((r, cap + 3), fill, dtype=dtype)] * (a - old_a)
+        return jnp.concatenate(segments, axis=1)
 
     def pad_vec(arr, dtype):
         out = jnp.zeros((r,), dtype=dtype)
@@ -237,11 +253,14 @@ class SeqOpBatch:
 register_pytrees(SeqState, SeqOpBatch)
 
 
-def _apply_one_doc(carry, op, capacity, n_actor_slots):
+def _apply_one_doc(carry, op, capacity):
     """One op against one doc.
     carry = (elem_id, nxt, reg, killed, val, counter, n, inexact)."""
     elem_id, nxt, reg, killed, val, counter, n, inexact = carry
     kind, ref, packed, value, preds, flag = op
+    # lane l of node i is at l * nodes + i of the lane arrays
+    nodes = elem_id.shape[0]
+    lane_offsets = nodes * np.arange(reg.shape[0] // nodes, dtype=np.int32)
 
     is_ins = kind == INSERT
     is_upd = (kind == SET) | (kind == DEL)
@@ -300,22 +319,17 @@ def _apply_one_doc(carry, op, capacity, n_actor_slots):
                                                  elem_id[ins_slot]))
     n = n + can_ins.astype(jnp.int32)
 
-    # Own actor lane (the insert op IS the element's first set op; a SET
-    # occupies its actor's lane the same way, ref registers.py design note)
-    a = (packed & ACTOR_MASK).astype(jnp.int32)
-    a_ok = a < n_actor_slots
-    a_c = jnp.minimum(a, n_actor_slots - 1)
-
-    ins_lane_tgt = jnp.where(can_ins & a_ok, slot, jnp.int32(SCRATCH))
-    w_ins = can_ins & a_ok
-    reg = reg.at[ins_lane_tgt, a_c].set(
-        jnp.where(w_ins, packed, reg[ins_lane_tgt, a_c]))
-    killed = killed.at[ins_lane_tgt, a_c].set(
-        jnp.where(w_ins, False, killed[ins_lane_tgt, a_c]))
-    val = val.at[ins_lane_tgt, a_c].set(
-        jnp.where(w_ins, value, val[ins_lane_tgt, a_c]))
-    counter = counter.at[ins_lane_tgt, a_c].set(
-        jnp.where(w_ins, 0, counter[ins_lane_tgt, a_c]))
+    # An insert takes a fresh slot, whose lanes are all empty: the element's
+    # first op (the insert IS its first set op) goes in lane 0
+    ins_lane_tgt = jnp.where(can_ins, slot, jnp.int32(SCRATCH))
+    reg = reg.at[ins_lane_tgt].set(
+        jnp.where(can_ins, packed, reg[ins_lane_tgt]))
+    killed = killed.at[ins_lane_tgt].set(
+        jnp.where(can_ins, False, killed[ins_lane_tgt]))
+    val = val.at[ins_lane_tgt].set(
+        jnp.where(can_ins, value, val[ins_lane_tgt]))
+    counter = counter.at[ins_lane_tgt].set(
+        jnp.where(can_ins, 0, counter[ins_lane_tgt]))
 
     # ---- SET / DEL / INC: exact multi-value register update -------------
     # ref == HEAD_REF (0) marks a malformed update (no target): it would
@@ -323,48 +337,34 @@ def _apply_one_doc(carry, op, capacity, n_actor_slots):
     upd_ok = is_upd & found & (ref != HEAD_REF)
     inc_ok = is_inc & found & (ref != HEAD_REF)
     tgt = jnp.where(upd_ok | inc_ok, match, jnp.int32(SCRATCH))
-    reg_row = reg[tgt]          # [A]
-    killed_row = killed[tgt]
-    val_row = val[tgt]
-    counter_row = counter[tgt]
+    lanes_at = tgt + lane_offsets     # the target's A lanes
+    reg_row = reg[lanes_at]
+    killed_row = killed[lanes_at]
+    val_row = val[lanes_at]
+    counter_row = counter[lanes_at]
 
-    # Kill preds: each pred lane targets its actor's lane; the kill lands
-    # only if that lane still holds exactly the pred'd op (a pred naming an
-    # already-superseded op is a legitimate no-op succ entry, which the
-    # reference also accepts). Concurrent ops are never killed — that is
-    # the multi-value / resurrection rule (new.js:1204-1217).
-    lane_oob = jnp.bool_(False)
-    d_lanes = preds.shape[0]
-    for d in range(d_lanes):
-        p = preds[d]
-        s = (p & ACTOR_MASK).astype(jnp.int32)
-        s_ok = (s < n_actor_slots) & (p > 0)
-        s_c = jnp.minimum(s, n_actor_slots - 1)
-        lane_oob |= (upd_ok | inc_ok) & (p != 0) & ~s_ok
-        hit = upd_ok & s_ok & (reg_row[s_c] == p)
-        killed_row = killed_row.at[s_c].set(killed_row[s_c] | hit)
+    # Kill preds: a pred's lane is the one that holds exactly the pred'd
+    # op (lanes are an unordered conflict set, found by value); a pred
+    # naming an already-superseded op finds none, a legitimate no-op succ
+    # entry that the reference also accepts. Concurrent ops are never
+    # killed — that is the multi-value / resurrection rule
+    # (new.js:1204-1217). A negative pred names an actor unknown to the
+    # fleet and flags the row.
+    held = jnp.any((preds[:, None] > 0) & (reg_row == preds[:, None]),
+                   axis=0)          # [A]: the lane holds a pred'd op
+    bad_pred = (upd_ok | inc_ok) & jnp.any(preds < 0)
+    killed_row = killed_row | (upd_ok & held)
 
     # INC: counter attribution follows the reference (new.js:942-945):
     # the inc is consumed by its LAMPORT-MAX pred (even a dead one); it
     # accumulates into that lane iff the lane still holds the op live, and
     # every OTHER live pred'd lane hides forever (its counter state never
     # completes). Same rule as registers._apply_step.
-    max_pred = jnp.int32(0)
-    any_live_hit = jnp.bool_(False)
-    for d in range(d_lanes):
-        p = preds[d]
-        s = (p & ACTOR_MASK).astype(jnp.int32)
-        s_ok = (s < n_actor_slots) & (p > 0)
-        s_c = jnp.minimum(s, n_actor_slots - 1)
-        max_pred = jnp.where(is_inc & (p > 0),
-                             jnp.maximum(max_pred, p), max_pred)
-        any_live_hit |= inc_ok & s_ok & (reg_row[s_c] == p) & \
-            ~killed_row[s_c]
-    s_max = (max_pred & ACTOR_MASK).astype(jnp.int32)
-    s_max_ok = (s_max < n_actor_slots) & (max_pred != 0)
-    s_max_c = jnp.minimum(s_max, n_actor_slots - 1)
-    max_live = inc_ok & s_max_ok & (reg_row[s_max_c] == max_pred) & \
-        ~killed_row[s_max_c]
+    max_pred = jnp.max(jnp.where(is_inc & (preds > 0), preds, 0))
+    any_live_hit = inc_ok & jnp.any(held & ~killed_row)
+    max_hit = (max_pred != 0) & (reg_row == max_pred) & ~killed_row
+    max_live = inc_ok & jnp.any(max_hit)
+    s_max = jnp.argmax(max_hit).astype(jnp.int32)
     # (sum << 2) | count-bits packing (bits 0 -> 1 -> 3, 3 = "two or
     # more", saturating) — see the SeqState docstring. The shifted add
     # leaves the count bits alone. The ingest-side guards bound each
@@ -372,35 +372,34 @@ def _apply_one_doc(carry, op, capacity, n_actor_slots):
     # packed envelope (two +2^28 incs): flag the row inexact when it
     # does, mirroring the bulk loader's counter_over rule, so live-applied
     # and bulk-loaded replicas agree instead of wrapping silently.
-    old_cnt = counter_row[s_max_c]
+    old_cnt = counter_row[s_max]
     new_sum = (old_cnt >> 2) + value
     bad_sum = max_live & (jnp.abs(new_sum) >= jnp.int32(1 << 29))
     stepped = (old_cnt & ~3) + (value << 2)
     stepped = stepped | jnp.where((old_cnt & 3) == 0, 1, 3)
-    counter_row = counter_row.at[s_max_c].set(
+    counter_row = counter_row.at[s_max].set(
         jnp.where(max_live, stepped, old_cnt))
-    for d in range(d_lanes):
-        p = preds[d]
-        s = (p & ACTOR_MASK).astype(jnp.int32)
-        s_ok = (s < n_actor_slots) & (p > 0)
-        s_c = jnp.minimum(s, n_actor_slots - 1)
-        lose = inc_ok & s_ok & (reg_row[s_c] == p) & ~killed_row[s_c] & \
-            (p != max_pred)
-        killed_row = killed_row.at[s_c].set(killed_row[s_c] | lose)
+    killed_row = killed_row | (inc_ok & held & (reg_row != max_pred))
     bad_inc = inc_ok & ~any_live_hit & ~max_live
 
-    # SET: occupy own actor lane. If the lane already holds a live op this
+    # SET: occupy the lane that holds this actor's op on the element, else
+    # the first empty one. If the actor's lane already holds a live op this
     # op did NOT pred, the reference would keep both visible — outside the
     # one-op-per-actor shape (only constructible by hand-built changes), so
-    # flag the doc instead of losing data.
+    # flag the doc instead of losing data. No lane left (more writers on
+    # the element than the pool has lanes: the fleet widens a pool before
+    # that, from the actors that wrote the row) flags the row too.
     is_set_live = upd_ok & (kind == SET)
+    mine = (reg_row != 0) & ((reg_row & ACTOR_MASK) == (packed & ACTOR_MASK))
+    empty = reg_row == 0
+    a_ok = jnp.any(mine | empty)
+    a_c = jnp.where(jnp.any(mine), jnp.argmax(mine),
+                    jnp.argmax(empty)).astype(jnp.int32)
     own_prev = reg_row[a_c]
-    own_pred = jnp.bool_(False)
-    for d in range(d_lanes):
-        own_pred |= preds[d] == own_prev
+    own_pred = jnp.any(preds == own_prev)
     self_conflict = is_set_live & a_ok & (own_prev != 0) & \
         ~killed_row[a_c] & ~own_pred & (own_prev != packed)
-    set_actor_oob = is_set_live & ~a_ok
+    no_lane = is_set_live & ~a_ok
 
     w_set = is_set_live & a_ok
     # Reclaiming a lane whose previous op consumed incs loses the dead
@@ -414,36 +413,32 @@ def _apply_one_doc(carry, op, capacity, n_actor_slots):
     counter_row = counter_row.at[a_c].set(
         jnp.where(w_set, 0, counter_row[a_c]))
 
-    reg = reg.at[tgt].set(reg_row)
-    killed = killed.at[tgt].set(killed_row)
-    val = val.at[tgt].set(val_row)
-    counter = counter.at[tgt].set(counter_row)
+    reg = reg.at[lanes_at].set(reg_row)
+    killed = killed.at[lanes_at].set(killed_row)
+    val = val.at[lanes_at].set(val_row)
+    counter = counter.at[lanes_at].set(counter_row)
 
     # Dropped ops (over-capacity or unknown-referent inserts, SET/DELs on
     # unknown targets) report as not-applied so callers can detect loss from
     # the stats instead of getting silent truncation.
     applied = jnp.where(is_ins, can_ins, jnp.where(is_inc, inc_ok, upd_ok))
-    ins_actor_oob = can_ins & ~a_ok
     # Inexactness: host-flagged ops (pred overflow), any dropped live op,
-    # actor numbers past the lane width, self conflicts, preds naming
-    # unknown/out-of-range actors, and incs with no consumable target
-    inexact = inexact | flag | self_conflict | lane_oob | set_actor_oob | \
-        ins_actor_oob | bad_inc | bad_sum | reclaim_incd | \
-        ((kind > PAD) & ~applied)
+    # a set with no lane left, self conflicts, preds naming unknown actors,
+    # and incs with no consumable target
+    inexact = inexact | flag | self_conflict | bad_pred | no_lane | \
+        bad_inc | bad_sum | reclaim_incd | ((kind > PAD) & ~applied)
     return (elem_id, nxt, reg, killed, val, counter, n, inexact), applied
 
 
 def _apply_seq_batch_impl(state, ops):
     capacity = state.elem_id.shape[1] - 3
-    n_actor_slots = state.reg.shape[2]
 
     def per_doc(elem_id, nxt, reg, killed, val, counter, n, inexact,
                 kind, ref, packed, value, preds, flag):
         carry = (elem_id, nxt, reg, killed, val, counter, n, inexact)
         xs = (kind, ref, packed, value, preds, flag)
         carry, applied = lax.scan(
-            lambda c, x: _apply_one_doc(c, x, capacity, n_actor_slots),
-            carry, xs)
+            lambda c, x: _apply_one_doc(c, x, capacity), carry, xs)
         return carry, jnp.sum(applied, dtype=jnp.int32)
 
     carry, applied = jax.vmap(per_doc)(
@@ -466,13 +461,22 @@ def _visible_impl(state):
     """Per-element visibility and Lamport winner from the registers:
     (vis [N, S+3] bool, winner [N, S+3] int32 packed, value [N, S+3],
     counter [N, S+3] — the winning lane's accumulated inc deltas)."""
-    live = (state.reg != 0) & ~state.killed
-    vis = jnp.any(live, axis=-1)
-    masked = jnp.where(live, state.reg, -1)
-    w = jnp.argmax(masked, axis=-1)
-    winner = jnp.max(jnp.where(live, state.reg, 0), axis=-1)
-    value = jnp.take_along_axis(state.val, w[..., None], axis=-1)[..., 0]
-    cnt = jnp.take_along_axis(state.counter, w[..., None], axis=-1)[..., 0]
+    a = state.actor_slots
+    vis = winner = value = cnt = None
+    # lane by lane, [N, S+3] each: a later lane takes over where its live
+    # op is the greater (live packed opIds are > 0 and distinct)
+    for reg, killed, val, counter in zip(
+            lane_segments(state.reg, a), lane_segments(state.killed, a),
+            lane_segments(state.val, a), lane_segments(state.counter, a)):
+        live = jnp.where((reg != 0) & ~killed, reg, 0)
+        if winner is None:
+            vis, winner, value, cnt = live != 0, live, val, counter
+            continue
+        better = live > winner
+        vis = vis | (live != 0)
+        value = jnp.where(better, val, value)
+        cnt = jnp.where(better, counter, cnt)
+        winner = jnp.maximum(winner, live)
     return vis, winner, value, cnt
 
 
@@ -565,9 +569,10 @@ def element_conflicts(state, row):
     {packed opId: value}} for every element whose visible register holds
     more than one op (the raw-engine view of what
     fleet.backend._FlatEngine._device_patch_diffs serves as patch edits)."""
-    reg = np.asarray(jax.device_get(state.reg[row]))
-    killed = np.asarray(jax.device_get(state.killed[row]))
-    val = np.asarray(jax.device_get(state.val[row]))
+    a = state.actor_slots                                   # to [S+3, A]
+    reg = np.asarray(jax.device_get(state.reg[row])).reshape(a, -1).T
+    killed = np.asarray(jax.device_get(state.killed[row])).reshape(a, -1).T
+    val = np.asarray(jax.device_get(state.val[row])).reshape(a, -1).T
     elem = np.asarray(jax.device_get(state.elem_id[row]))
     live = (reg != 0) & ~killed
     out = {}
@@ -633,6 +638,33 @@ class SeqEncoder:
         return SeqOpBatch(kind, ref, packed, value, preds, flag)
 
 
+def _copy_rows_impl(d, s, si, di):
+    """State `d` with its rows `di` overwritten by rows `si` of state `s`
+    (no more nodes than d's: a prefix copy, the END-filled tail stays
+    inert). Where the lane widths differ the narrower one's lanes are
+    copied: an element's used lanes are a prefix, and the caller has made
+    `d` as wide as the rows' writers need."""
+    nodes, d_nodes = s.elem_id.shape[1], d.elem_id.shape[1]
+    lanes = min(s.actor_slots, d.actor_slots)
+
+    def put(darr, sarr):
+        return darr.at[di, :nodes].set(sarr[si])
+
+    def put_lanes(darr, sarr):
+        for l, lane in enumerate(lane_segments(sarr, s.actor_slots)[:lanes]):
+            darr = darr.at[di, l * d_nodes:l * d_nodes + nodes].set(lane[si])
+        return darr
+
+    return SeqState(
+        put(d.elem_id, s.elem_id), put(d.nxt, s.nxt),
+        put_lanes(d.reg, s.reg), put_lanes(d.killed, s.killed),
+        put_lanes(d.val, s.val), put_lanes(d.counter, s.counter),
+        d.n.at[di].set(s.n[si]), d.inexact.at[di].set(s.inexact[si]))
+
+
+_copy_rows = instrument_kernel('seq_copy_rows', jax.jit(_copy_rows_impl))
+
+
 class SeqPools:
     """Size-class pools of sequence rows.
 
@@ -691,18 +723,10 @@ class SeqPools:
             self.pools[cls] = grown
         return self.pools[cls]
 
-    def ensure_lanes(self, actor_slots):
-        """Grow every pool's actor-lane axis (before a lane permutation)."""
-        for cls in list(self.pools):
-            grown = grow_seq_state(self.pools[cls], 0, 0, actor_slots)
-            if grown is not self.pools[cls]:
-                self.grow_events += 1
-            self.pools[cls] = grown
-
     def alloc(self, cls, actor_slots):
         free = self.free.setdefault(cls, [])
         if free:
-            # a pool built under a narrower actor table must still widen
+            # a pool built for rows with fewer writers must still widen
             # its lane axis before the recycled row is written
             self._ensure(cls, self.used.get(cls, 1), actor_slots)
             return free.pop()
@@ -713,14 +737,14 @@ class SeqPools:
 
     def reserve(self, cls, count, actor_slots):
         """Pre-size a pool for `count` upcoming alloc() calls in one
-        growth: growing inside each alloc re-pads the whole pool's arrays
-        eagerly on device per pow2 step (~log2(rows) growths of 8 arrays
-        each for a batch of fresh rows — a dispatch storm on a real TPU).
-        Reservation is capacity-only; alloc() still does the bookkeeping,
-        it just finds the pool already big enough."""
-        fresh = count - len(self.free.get(cls, ()))
-        if fresh > 0:
-            self._ensure(cls, self.used.get(cls, 0) + fresh, actor_slots)
+        growth, and widen it to `actor_slots` lanes: growing inside each
+        alloc re-pads the whole pool's arrays eagerly on device per pow2
+        step (~log2(rows) growths of 8 arrays each for a batch of fresh
+        rows — a dispatch storm on a real TPU). Reservation is
+        capacity-only; alloc() still does the bookkeeping, it just finds
+        the pool already big enough."""
+        fresh = max(count - len(self.free.get(cls, ())), 0)
+        self._ensure(cls, self.used.get(cls, 0) + fresh, actor_slots)
 
     def release(self, cls, idx):
         """Zero a row and return it to its class's free list."""
@@ -753,31 +777,13 @@ class SeqPools:
         self.copy_rows(src[0], [src[1]], dst[0], [dst[1]])
 
     def copy_rows(self, src_cls, src_idxs, dst_cls, dst_idxs):
-        """Batched row copies between two classes (dst capacity >= src);
-        one indexed gather/scatter per array."""
+        """Batched row copies between two classes (dst capacity >= src),
+        one program for all the arrays."""
         import jax.numpy as jnp
-        width = max(self.pools[src_cls].reg.shape[2],
-                    self.pools[dst_cls].reg.shape[2])
-        if self.pools[src_cls].reg.shape[2] != \
-                self.pools[dst_cls].reg.shape[2]:
-            self.ensure_lanes(width)
-        s = self.pools[src_cls]
-        d = self.pools[dst_cls]
-        nodes = s.elem_id.shape[1]
-        si = jnp.asarray(np.array(src_idxs, dtype=np.int32))
-        di = jnp.asarray(np.array(dst_idxs, dtype=np.int32))
-
-        def put(darr, sarr):
-            if darr.ndim == 2:
-                return darr.at[di, :nodes].set(sarr[si])
-            return darr.at[di, :nodes, :].set(sarr[si])
-
-        self.pools[dst_cls] = SeqState(
-            put(d.elem_id, s.elem_id), put(d.nxt, s.nxt),
-            put(d.reg, s.reg), put(d.killed, s.killed), put(d.val, s.val),
-            put(d.counter, s.counter),
-            d.n.at[di].set(s.n[si]),
-            d.inexact.at[di].set(s.inexact[si]))
+        self.pools[dst_cls] = _copy_rows(
+            self.pools[dst_cls], self.pools[src_cls],
+            jnp.asarray(np.array(src_idxs, dtype=np.int32)),
+            jnp.asarray(np.array(dst_idxs, dtype=np.int32)))
 
     def migrate(self, cls, idx, new_cls, actor_slots):
         """Move a row to a bigger class; returns its new idx."""

@@ -59,7 +59,8 @@ def seq_sharding(mesh):
     axis only — the per-doc slot axis stays local (the RGA pointer walk is a
     per-document scan; sharding it would put pointer chasing on ICI). Arrays
     pick their spec by rank: [docs] vectors, [docs, slots] node arrays,
-    [docs, slots, lanes] register/pred-lane arrays."""
+    [docs, lanes * slots] register arrays, and [docs, ops, preds] pred
+    columns."""
     by_ndim = {1: NamedSharding(mesh, P('docs')),
                2: NamedSharding(mesh, P('docs', None)),
                3: NamedSharding(mesh, P('docs', None, None))}
@@ -115,7 +116,7 @@ def shard_long_seq(state, mesh):
     """Shard a long-document SeqState's node axis across the whole mesh,
     tail-padding to a device-count multiple first (safe because sentinels
     are front-anchored and padded tail slots read as unallocated)."""
-    from .sequence import END, SeqState
+    from .sequence import END, SeqState, lane_segments
     by_ndim = long_seq_sharding(mesh)
     n_dev = int(np.prod(mesh.devices.shape))
     size = state.elem_id.shape[1]
@@ -124,15 +125,20 @@ def shard_long_seq(state, mesh):
     def padded(x, fill):
         if pad == 0:
             return x
-        shape = (x.shape[0], size + pad) + x.shape[2:]
-        out = jnp.full(shape, fill, dtype=x.dtype)
+        out = jnp.full((x.shape[0], size + pad), fill, dtype=x.dtype)
         return out.at[:, :size].set(x)
+
+    def padded_lanes(x, fill):
+        # a lane array is A node-indexed segments side by side
+        return jnp.concatenate(
+            [padded(lane, fill)
+             for lane in lane_segments(x, state.actor_slots)], axis=1)
 
     return SeqState(*(
         jax.device_put(arr, by_ndim[arr.ndim]) for arr in (
             padded(state.elem_id, 0), padded(state.nxt, END),
-            padded(state.reg, 0), padded(state.killed, False),
-            padded(state.val, 0), padded(state.counter, 0),
+            padded_lanes(state.reg, 0), padded_lanes(state.killed, False),
+            padded_lanes(state.val, 0), padded_lanes(state.counter, 0),
             jnp.asarray(state.n), jnp.asarray(state.inexact))))
 
 

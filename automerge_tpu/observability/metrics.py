@@ -3,7 +3,7 @@
 - `Metrics`: cheap monotonic counters every DocFleet maintains
   (`fleet.metrics`): device dispatches, ops applied on device, changes
   ingested, bytes ingested, host fallbacks, actor renumber remaps,
-  capacity growths. `snapshot()` returns a plain dict; `delta(prev)`
+  capacity growths, and the sequence engine's ops, padding and pool size. `snapshot()` returns a plain dict; `delta(prev)`
   diffs two snapshots — subtract around a workload to get per-phase
   counts.
 - `trace(path)`: the operator's one entry to a capture — a context
@@ -108,6 +108,17 @@ class Metrics:
                                  # (causally ordered, just not one chain);
                                  # the three reasons summed, less this, is
                                  # what reached the general gate
+        # the sequence engine (fleet/backend.py _dispatch_seq)
+        'seq_ops',               # real sequence ops dispatched
+        'seq_op_cells',          # rows x width of the op columns handed to
+                                 # the device; less seq_ops, it is padding
+        'seq_migrations',        # rows moved up a size class
+        'seq_inexact_reads',     # rows a bulk render found flagged
+                                 # inexact and left to the host mirror
+        # gauges, not counters: what the pools hold after the last
+        # dispatch or bulk load (delta() gives their change)
+        'seq_pool_bytes',        # bytes of every pool's arrays
+        'seq_nodes',             # rows x nodes over all pools
     )
 
     def __init__(self):

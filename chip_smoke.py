@@ -194,7 +194,9 @@ def text_trace(rng, n_ops, n_actors, ops_per_change, concurrent):
     under different actors and double deletes to resolve; the seam
     routes such a batch through its exact path."""
     from automerge_tpu.columnar import decode_change_meta, encode_change
-    actors = [f'{0xaa + 0x11 * a:02x}' * 16 for a in range(n_actors)]
+    # every document has actor ids of its own, as every device has: shared
+    # ids would hide what a fleet's actor table costs the sequence pools
+    actors = [rng.bytes(16).hex() for _ in range(n_actors)]
     first = encode_change({
         'actor': actors[0], 'seq': 1, 'startOp': 1, 'time': 0,
         'message': '', 'deps': [],
