@@ -9,6 +9,12 @@ are allocated in op-arrival order and never move; an insert splices pointers,
 so per-op work is O(S) vector compares (the referent lookup) + an O(skip)
 pointer walk, with NO data movement of the sequence itself — the analogue of
 the reference editing a block in place instead of reshuffling the array.
+That holds of the compiled program too because a dispatch defers its splices:
+inside the op scan `elem_id` and `nxt` are only read, the batch's new slots
+and repointed nodes live in a per-row overlay as wide as the batch, and each
+array takes its overlay in one scatter when the scan is over (a scan step that
+wrote them while the skip walk's loop held them had both copied whole, every
+step: see _apply_seq_batch_impl).
 
 Application is a `vmap` over docs of a `lax.scan` over each doc's op stream:
 ops within one doc apply in causal order (as the reference's per-change op
@@ -253,14 +259,32 @@ class SeqOpBatch:
 register_pytrees(SeqState, SeqOpBatch)
 
 
-def _apply_one_doc(carry, op, capacity):
-    """One op against one doc.
-    carry = (elem_id, nxt, reg, killed, val, counter, n, inexact)."""
-    elem_id, nxt, reg, killed, val, counter, n, inexact = carry
+def _apply_one_doc(carry, op, elem_id, nxt, n0):
+    """One op against one doc. `elem_id`, `nxt` and `n0` are the row as the
+    dispatch found it, read and never written here; the batch's splices
+    live in the carry's overlay (see _apply_seq_batch_impl).
+    carry = (ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n,
+    inexact)."""
+    ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n, \
+        inexact = carry
     kind, ref, packed, value, preds, flag = op
     # lane l of node i is at l * nodes + i of the lane arrays
     nodes = elem_id.shape[0]
+    capacity = nodes - 3
     lane_offsets = nodes * np.arange(reg.shape[0] // nodes, dtype=np.int32)
+    new0 = SLOT0 + n0       # the first node this batch allocates
+
+    width = ov_id.shape[0]
+    entries = np.arange(width, dtype=np.int32)
+
+    def id_at(j):
+        return jnp.where(j >= new0, ov_id[j - new0], elem_id[j])
+
+    def nxt_at(j):
+        """(the node after j, the entry of j's pair or -1)"""
+        e = jnp.max(jnp.where(rp_node == j, entries, -1))
+        old = jnp.where(e >= 0, rp_nxt[e], nxt[j])
+        return jnp.where(j >= new0, ov_nxt[j - new0], old), e
 
     is_ins = kind == INSERT
     is_upd = (kind == SET) | (kind == DEL)
@@ -268,12 +292,15 @@ def _apply_one_doc(carry, op, capacity):
 
     # Referent / target node: packed elemIds are unique and non-zero, so an
     # equality one-hot over the node axis finds it (sentinel and scratch
-    # entries keep elem_id 0). A miss (op referencing an elemId not in the
-    # doc, e.g. one dropped by a capacity overflow) must not resolve to an
-    # arbitrary slot.
-    hits = elem_id == ref
-    found = jnp.any(hits)
-    match = jnp.argmax(hits).astype(jnp.int32)
+    # entries keep elem_id 0), or one over the batch's own inserts. A miss
+    # (op referencing an elemId not in the doc, e.g. one dropped by a
+    # capacity overflow) must not resolve to an arbitrary slot.
+    node_ids = np.arange(nodes, dtype=np.int32)
+    in_row = jnp.min(jnp.where(elem_id == ref, node_ids, nodes))
+    in_batch = jnp.min(jnp.where(ov_id == ref, entries, width))
+    found = (in_row < nodes) | (in_batch < width)
+    match = jnp.where(in_row < nodes, in_row,
+                      jnp.where(in_batch < width, new0 + in_batch, 0))
 
     # ---- INSERT: RGA splice -------------------------------------------
     # Start after the referent (HEAD sentinel for ref==0), then skip any
@@ -285,20 +312,22 @@ def _apply_one_doc(carry, op, capacity):
     my_key = jnp.where(is_ins, packed, INT32_MAX)
 
     def skip_cond(state):
-        r, j, h = state
+        r, j, h, e = state
         # Sentinels/scratch hold elem_id 0, which can never exceed a real
         # packed opId, so the walk stops at END (or list end) by itself; the
         # hop counter is a termination backstop so a corrupted/cyclic nxt
         # chain cannot hang the device kernel (a well-formed list has at
         # most capacity+3 nodes).
-        return (elem_id[j] > my_key) & (h < capacity + 3)
+        return (id_at(j) > my_key) & (h < capacity + 3)
 
     def skip_body(state):
-        r, j, h = state
-        return j, nxt[j], h + 1
+        r, j, h, e = state
+        after, e_j = nxt_at(j)
+        return j, after, h + 1, e_j
 
-    r, j, _ = lax.while_loop(skip_cond, skip_body,
-                             (r0, nxt[r0], jnp.int32(0)))
+    after, e_r0 = nxt_at(r0)
+    r, j, _, e_r = lax.while_loop(skip_cond, skip_body,
+                                  (r0, after, jnp.int32(0), e_r0))
 
     # Inserts past capacity or after an unknown referent are dropped
     # (reported via the per-op applied flag) rather than silently corrupting
@@ -306,37 +335,34 @@ def _apply_one_doc(carry, op, capacity):
     # insert, and a missed referent lookup must not splice after node 0.
     can_ins = is_ins & (n < capacity) & ((ref == HEAD_REF) | found)
     slot = SLOT0 + jnp.minimum(n, capacity - 1)  # allocation cursor, clamped
-    ins_slot = jnp.where(can_ins, slot, jnp.int32(SCRATCH))
-    ins_ptr_from = jnp.where(can_ins, r, jnp.int32(END))
-    ins_ptr_new = jnp.where(can_ins, slot, jnp.int32(END))
 
-    nxt = nxt.at[ins_ptr_new].set(jnp.where(can_ins, j, nxt[ins_ptr_new]))
-    nxt = nxt.at[ins_ptr_from].set(jnp.where(can_ins, slot, nxt[ins_ptr_from]))
-    # All masked writes preserve the scratch node's elem_id = 0 — the
-    # invariant the one-hot referent match depends on. (Scratch's register
-    # lanes absorb masked lane writes; their contents are never read.)
-    elem_id = elem_id.at[ins_slot].set(jnp.where(can_ins, packed,
-                                                 elem_id[ins_slot]))
+    # The splice, in the overlay: entry k is the new slot's id and next
+    # pointer (k counts this batch's inserts, so it is below the width);
+    # the node before it is a slot of this batch, or an old node whose pair
+    # is rewritten if the batch has repointed it before and else takes
+    # entry k (one entry a node: the write-back needs no order). A write
+    # that is masked goes past the overlay's end and is dropped.
+    k = n - n0
+    at_k = jnp.where(can_ins, k, width)
+    at_r = jnp.where(can_ins & (r >= new0), r - new0, width)
+    at_e = jnp.where(can_ins & (r < new0),
+                     jnp.where(e_r >= 0, e_r, k), width)
+    ov_id = ov_id.at[at_k].set(packed, mode='drop')
+    ov_nxt = ov_nxt.at[jnp.stack([at_k, at_r])].set(
+        jnp.stack([j, slot]), mode='drop')
+    rp_node = rp_node.at[at_e].set(r, mode='drop')
+    rp_nxt = rp_nxt.at[at_e].set(slot, mode='drop')
     n = n + can_ins.astype(jnp.int32)
-
-    # An insert takes a fresh slot, whose lanes are all empty: the element's
-    # first op (the insert IS its first set op) goes in lane 0
-    ins_lane_tgt = jnp.where(can_ins, slot, jnp.int32(SCRATCH))
-    reg = reg.at[ins_lane_tgt].set(
-        jnp.where(can_ins, packed, reg[ins_lane_tgt]))
-    killed = killed.at[ins_lane_tgt].set(
-        jnp.where(can_ins, False, killed[ins_lane_tgt]))
-    val = val.at[ins_lane_tgt].set(
-        jnp.where(can_ins, value, val[ins_lane_tgt]))
-    counter = counter.at[ins_lane_tgt].set(
-        jnp.where(can_ins, 0, counter[ins_lane_tgt]))
 
     # ---- SET / DEL / INC: exact multi-value register update -------------
     # ref == HEAD_REF (0) marks a malformed update (no target): it would
     # "match" every unallocated slot's zero elem_id, so reject it explicitly.
     upd_ok = is_upd & found & (ref != HEAD_REF)
     inc_ok = is_inc & found & (ref != HEAD_REF)
-    tgt = jnp.where(upd_ok | inc_ok, match, jnp.int32(SCRATCH))
+    # (an insert's node is its fresh slot: no rule below touches its row,
+    # and its lane 0 is written with the row, further down)
+    tgt = jnp.where(can_ins, slot,
+                    jnp.where(upd_ok | inc_ok, match, jnp.int32(SCRATCH)))
     lanes_at = tgt + lane_offsets     # the target's A lanes
     reg_row = reg[lanes_at]
     killed_row = killed[lanes_at]
@@ -413,10 +439,22 @@ def _apply_one_doc(carry, op, capacity):
     counter_row = counter_row.at[a_c].set(
         jnp.where(w_set, 0, counter_row[a_c]))
 
-    reg = reg.at[lanes_at].set(reg_row)
+    # An insert takes a fresh slot, whose lanes are all empty: the element's
+    # first op (the insert IS its first set op) goes in lane 0. Only
+    # `killed` can change in more than one lane of the row (the preds); of
+    # the others one element is written, the set's or insert's lane or the
+    # inc's: a scatter takes the device as long as it has elements.
+    w_lane = jnp.where(can_ins, 0, a_c)
+    c_lane = jnp.where(is_inc, s_max, w_lane)
+    killed_row = killed_row.at[w_lane].set(
+        jnp.where(can_ins, False, killed_row[w_lane]))
+    reg = reg.at[lanes_at[w_lane]].set(
+        jnp.where(can_ins, packed, reg_row[w_lane]))
+    val = val.at[lanes_at[w_lane]].set(
+        jnp.where(can_ins, value, val_row[w_lane]))
+    counter = counter.at[lanes_at[c_lane]].set(
+        jnp.where(can_ins, 0, counter_row[c_lane]))
     killed = killed.at[lanes_at].set(killed_row)
-    val = val.at[lanes_at].set(val_row)
-    counter = counter.at[lanes_at].set(counter_row)
 
     # Dropped ops (over-capacity or unknown-referent inserts, SET/DELs on
     # unknown targets) report as not-applied so callers can detect loss from
@@ -427,24 +465,49 @@ def _apply_one_doc(carry, op, capacity):
     # and incs with no consumable target
     inexact = inexact | flag | self_conflict | bad_pred | no_lane | \
         bad_inc | bad_sum | reclaim_incd | ((kind > PAD) & ~applied)
-    return (elem_id, nxt, reg, killed, val, counter, n, inexact), applied
+    return (ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n,
+            inexact), applied
 
 
 def _apply_seq_batch_impl(state, ops):
-    capacity = state.elem_id.shape[1] - 3
+    """Deferred splice: inside the scan `elem_id` and `nxt` are read-only
+    (an array the scan step wrote while the skip walk's `while` held it was
+    copied whole every step), and the batch's splices live in a per-row
+    overlay as wide as the batch: the ids and next pointers of the slots it
+    allocates (slot SLOT0 + n0 + k is entry k, n0 the row's cursor at
+    entry) and the repointed old nodes as (node, new next) pairs. Each
+    array takes the overlay in one scatter after the scan."""
+    rows, width = ops.kind.shape
+    width = max(width, 1)       # an empty batch still traces the step
 
     def per_doc(elem_id, nxt, reg, killed, val, counter, n, inexact,
-                kind, ref, packed, value, preds, flag):
-        carry = (elem_id, nxt, reg, killed, val, counter, n, inexact)
+                kind, ref, packed, value, preds, flag, zeros):
+        carry = (zeros, zeros, zeros - 1, zeros, reg, killed, val, counter,
+                 n, inexact)
         xs = (kind, ref, packed, value, preds, flag)
         carry, applied = lax.scan(
-            lambda c, x: _apply_one_doc(c, x, capacity), carry, xs)
-        return carry, jnp.sum(applied, dtype=jnp.int32)
+            lambda c, x: _apply_one_doc(c, x, elem_id, nxt, n),
+            carry, xs)
+        ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n_out, \
+            inexact = carry
+        # live entries name distinct nodes; the others go past the row's
+        # end and are dropped, like the scan's masked overlay writes
+        nodes = elem_id.shape[0]
+        new = SLOT0 + n + jnp.arange(width, dtype=jnp.int32)
+        new = jnp.where(new < SLOT0 + n_out, new, nodes)
+        elem_id = elem_id.at[new].set(ov_id, mode='drop')
+        nxt = nxt.at[jnp.concatenate(
+            [new, jnp.where(rp_node >= 0, rp_node, nodes)])].set(
+                jnp.concatenate([ov_nxt, rp_nxt]), mode='drop')
+        return (elem_id, nxt, reg, killed, val, counter, n_out, inexact), \
+            jnp.sum(applied, dtype=jnp.int32)
 
     carry, applied = jax.vmap(per_doc)(
         state.elem_id, state.nxt, state.reg, state.killed, state.val,
         state.counter, state.n, state.inexact, ops.kind, ops.ref,
-        ops.packed, ops.value, ops.preds, ops.flag)
+        ops.packed, ops.value, ops.preds, ops.flag,
+        # the empty overlay, batched like the rest of the scan's carry
+        jnp.zeros((rows, width), jnp.int32))
     return SeqState(*carry), jnp.sum(applied)
 
 

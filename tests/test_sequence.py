@@ -86,6 +86,14 @@ class TestRGAOrdering:
         state = run_ops([doc0, doc1, doc2], [A1])
         assert visible_text(state) == ['x', 'abc', '']
 
+    def test_empty_batch_is_a_no_op(self):
+        state = run_ops([[ins('_head', f'2@{A1}', 'a')], []], [A1])
+        again, applied = apply_seq_batch(
+            state, SeqEncoder([A1]).batch([[], []]))
+        assert int(applied) == 0
+        for a, b in zip(state.tree_flatten()[0], again.tree_flatten()[0]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_incremental_batches(self):
         """State carries correctly across separate apply_seq_batch calls."""
         enc = SeqEncoder([A1, A2])
@@ -432,3 +440,170 @@ class TestCounterSumOverflow:
     def test_negative_overflow_flags_inexact(self):
         state = self._inc_trace([-(1 << 28), -(1 << 28)])
         assert bool(np.asarray(state.inexact)[0])
+
+
+# ---- deferred splice (the batch's inserts live in an overlay until the
+# dispatch ends): what a batch does to nodes of its own ------------------
+
+def _typed(ref, start, n, actor=A1):
+    """A typing run: n inserts, each after the one before, the first after
+    `ref`; ids start@actor, start+1@actor, ..."""
+    ops = []
+    for i in range(n):
+        ops.append(ins(ref, f'{start + i}@{actor}', chr(ord('a') + i % 26)))
+        ref = f'{start + i}@{actor}'
+    return ops
+
+
+def _del(target, op_id):
+    return {'kind': 'del', 'target': target, 'id': op_id}
+
+
+def _set(target, op_id, ch):
+    return {'kind': 'set', 'target': target, 'id': op_id, 'value': ord(ch)}
+
+
+# name -> (capacity, width (None: 16; every dispatch of the case is padded to
+#          it, so that the cases share compiled programs), ops of an earlier
+#          dispatch, the batch, indexes of its ops that the kernel must drop)
+SPLICE_CASES = {
+    # each insert names the insert before it, the first an old element
+    'run_of_8': (64, None, _typed('_head', 2, 2),
+                 _typed(f'2@{A1}', 4, 8), ()),
+    # SET and DEL of elements of the same batch, an insert after the
+    # deleted one, a backspace of the character just typed
+    'set_del_same_batch': (64, None, _typed('_head', 2, 2), [
+        ins(f'3@{A1}', f'4@{A1}', 'x'), ins(f'4@{A1}', f'5@{A1}', 'y'),
+        _set(f'4@{A1}', f'6@{A1}', 'X'), _del(f'5@{A1}', f'7@{A1}'),
+        ins(f'5@{A1}', f'8@{A1}', 'z'), ins(f'8@{A1}', f'9@{A1}', 'w'),
+        _del(f'9@{A1}', f'10@{A1}')], ()),
+    # the same old node repointed again, and another old node once
+    'two_after_same_old': (64, None, _typed('_head', 2, 3), [
+        ins(f'2@{A1}', f'5@{A1}', 'p'), ins(f'2@{A1}', f'6@{A1}', 'q'),
+        ins(f'3@{A1}', f'7@{A1}', 'r')], ()),
+    # ... and a third time, by an insert that first skips the other two
+    'three_after_same_old': (64, None, _typed('_head', 2, 3), [
+        ins(f'2@{A1}', f'5@{A2}', 'p'), ins(f'2@{A1}', f'6@{A1}', 'q'),
+        ins(f'2@{A1}', f'5@{A1}', 'r'), ins('_head', f'7@{A1}', 's'),
+        ins('_head', f'8@{A1}', 't'), ins('_head', f'6@{A3}', 'u')], ()),
+    # skip walks that go old node -> slot of this batch -> old node, and
+    # stop on a slot of this batch
+    'walk_old_new_old': (64, None, [
+        ins('_head', f'2@{A1}', 'a'), ins(f'2@{A1}', f'9@{A2}', 'p'),
+        ins(f'2@{A1}', f'7@{A2}', 'q')], [
+        ins(f'2@{A1}', f'8@{A2}', 'n'), ins(f'2@{A1}', f'3@{A1}', 'b'),
+        ins(f'2@{A1}', f'6@{A3}', 'm'), ins(f'2@{A1}', f'4@{A1}', 'c')], ()),
+    # the row fills midway: the tail is dropped and reported (the last
+    # insert names a dropped one, the DEL too), wider than the row
+    'capacity_midway': (8, None, _typed('_head', 2, 2),
+                        _typed(f'3@{A1}', 4, 8) + [
+                            _set(f'9@{A1}', f'12@{A1}', 'Y'),
+                            _del(f'10@{A1}', f'13@{A1}')], (6, 7, 9)),
+    'unknown_referent_midway': (64, None, _typed('_head', 2, 2), [
+        ins(f'3@{A1}', f'4@{A1}', 'c'), ins(f'99@{A1}', f'5@{A1}', '?'),
+        ins(f'4@{A1}', f'6@{A1}', 'd'), _del(f'5@{A1}', f'7@{A1}'),
+        ins(f'6@{A1}', f'8@{A1}', 'e')], (1, 3)),
+    'width_1': (64, 1, _typed('_head', 2, 2),
+                [ins(f'2@{A1}', f'4@{A1}', 'x')], ()),
+    'width_64': (64, 64, _typed('_head', 2, 3),
+                 _typed(f'3@{A1}', 5, 20) + [_del(f'24@{A1}', f'25@{A1}')]
+                 + _typed(f'23@{A1}', 26, 12, A2)
+                 + [ins(f'3@{A1}', f'40@{A1}', 'k')], ()),
+    'wider_than_the_row': (8, None, _typed('_head', 2, 2),
+                           _typed(f'2@{A1}', 4, 5)
+                           + [_del(f'8@{A1}', f'9@{A1}')], ()),
+}
+
+
+class TestDeferredSplice:
+    ACTORS = [A1, A2, A3]
+    # a second row with another cursor, so that a row's overlay is
+    # numbered from its own n0
+    OTHER_OLD = _typed('_head', 2, 2, A2)
+    OTHER_NEW = _typed(f'2@{A2}', 4, 2, A2) + [_del(f'5@{A2}', f'6@{A2}')]
+
+    def _apply(self, state, parts, width):
+        """One dispatch a part: row 0 takes the part, row 1 as large a
+        share of OTHER_NEW."""
+        enc = SeqEncoder(self.ACTORS)
+        cuts = [0, 2, 3] if len(parts) == 2 else [0, 3]
+        total = 0
+        for part, lo, hi in zip(parts, cuts, cuts[1:]):
+            state, applied = apply_seq_batch(
+                state, enc.batch([part, self.OTHER_NEW[lo:hi]],
+                                 pad_to=width or 16))
+            total += int(applied)
+        return state, total
+
+    @pytest.mark.parametrize('name', sorted(SPLICE_CASES))
+    def test_batch_on_its_own_nodes(self, name):
+        from automerge_tpu.fleet.sequence import END, HEAD, SCRATCH
+        capacity, width, old, batch, dropped = SPLICE_CASES[name]
+        enc = SeqEncoder(self.ACTORS)
+        base, _ = apply_seq_batch(
+            SeqState.empty(2, capacity),
+            enc.batch([old, self.OTHER_OLD], pad_to=width or 16))
+        assert not np.asarray(base.inexact).any()
+
+        one, applied = self._apply(base, [batch], width)
+        half = len(batch) // 2
+        two, applied2 = self._apply(base, [batch[:half], batch[half:]],
+                                    width)
+        for a, b in zip(one.tree_flatten()[0], two.tree_flatten()[0]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+        kept = [op for i, op in enumerate(batch) if i not in dropped]
+        assert applied == applied2 == len(kept) + len(self.OTHER_NEW)
+        assert visible_text(one) == [
+            host_text(old + kept, self.ACTORS),
+            host_text(self.OTHER_OLD + self.OTHER_NEW, self.ACTORS)]
+        assert np.asarray(one.inexact).tolist() == [bool(dropped), False]
+        inserts = sum(op['kind'] == 'insert' for op in old + kept)
+        assert np.asarray(one.n).tolist() == [inserts, 4]
+        # the sentinels are never written by a live or a dropped insert
+        elem, nxt = np.asarray(one.elem_id), np.asarray(one.nxt)
+        assert (elem[:, [HEAD, END, SCRATCH]] == 0).all()
+        assert (nxt[:, [END, SCRATCH]] == END).all()
+        assert (elem[0, 3 + inserts:] == 0).all()
+
+
+def test_scan_body_copies_no_node_array():
+    """The compiled program may not copy an array of elem_id's shape
+    anywhere but in its entry computation: a scan step that writes an
+    array which the skip walk's `while` holds is given a whole-array copy
+    of it every step (two 134 MB copies a step were 54 % of the device
+    time of the text cell before the splices were deferred)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from automerge_tpu.fleet import sequence
+    rows, capacity, width, lanes = 8, 256, 16, 4
+    nodes = capacity + 3
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    state = SeqState(
+        spec((rows, nodes)), spec((rows, nodes)),
+        spec((rows, lanes * nodes)), spec((rows, lanes * nodes), jnp.bool_),
+        spec((rows, lanes * nodes)), spec((rows, lanes * nodes)),
+        spec((rows,)), spec((rows,), jnp.bool_))
+    ops = SeqOpBatch(
+        spec((rows, width)), spec((rows, width)), spec((rows, width)),
+        spec((rows, width)),
+        spec((rows, width, sequence.SEQ_PRED_LANES)),
+        spec((rows, width), jnp.bool_))
+    text = sequence.apply_seq_batch_donated.__wrapped__ \
+        .lower(state, ops).compile().as_text()
+
+    node_copy = re.compile(
+        r'= s32\[%d,%d\](\{[^}]*\})? copy\(' % (rows, nodes))
+    entry, found, bodies = False, [], 0
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith('{'):
+            entry = line.startswith('ENTRY')
+            bodies += not entry
+        elif not entry and node_copy.search(line):
+            found.append(line.strip())
+    assert bodies, 'no computation but the entry: the text was not parsed'
+    assert not found, found
