@@ -18,9 +18,8 @@ def in_package(path):
 
 def lintable(path):
     """Everything the tree-wide checks (except-pass, message-matching,
-    counter discipline) cover: the package, the tools, the bench."""
-    return in_package(path) or path.startswith('tools/') or \
-        path in ('bench.py',)
+    counter discipline) cover: the package and the tools."""
+    return in_package(path) or path.startswith('tools/')
 
 
 # --- typed-errors -----------------------------------------------------------

@@ -22,8 +22,8 @@ from . import get_heads, get_missing_deps, get_change_by_hash, get_changes, \
 
 # Containment counter: peer Bloom filters that failed to parse/probe and
 # were treated as empty (send-everything) instead of crashing the
-# generate round. Registered as a health source so bench.py and the
-# chaos tests can see corruption being absorbed.
+# generate round. Registered as a health source, so `health_counts()`
+# carries it; no test or benchmark cell reads the key today.
 _wire_stats = Counters({'rejected_filters': 0})
 register_health_source('rejected_filters',
                        lambda: _wire_stats['rejected_filters'])

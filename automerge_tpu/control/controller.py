@@ -11,7 +11,7 @@ the resulting decisions:
   pin lane / ``pressure_factor``, ``ShardRouter.rehome_tenant`` (the
   same migration machinery ``rebalance`` uses). Mode='shadow' runs the
   IDENTICAL decision path and records "would have acted" without
-  touching anything — the parity the bench section pins.
+  touching anything — the parity tests/test_control.py pins.
 - **ledger**: every decision (applied or shadow) lands in the bounded
   in-memory decision ledger AND the flight recorder, stamped with the
   input signal snapshot that justified it and the trace ids of affected

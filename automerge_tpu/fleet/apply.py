@@ -196,9 +196,10 @@ def _apply_op_batch_fresh_impl(ops, n_docs, n_keys):
     """First dispatch of a FRESH fleet: the zero state is created inside
     the jit, so XLA fuses the fill with the scatter instead of running a
     separate whole-grid memset dispatch first — a fresh 10k-doc x 1k-key
-    grid otherwise pays a ~120 MB zero-fill (measured 60-85 ms host-side
-    on the bench box) before its first merge. Shapes are static args:
-    one compile per capacity step, same as the growth path."""
+    grid otherwise pays a ~120 MB zero-fill (60-85 ms host-side in a
+    CPU-era run, not measured on the chip) before its first merge.
+    Shapes are static args: one compile per capacity step, same as the
+    growth path."""
     return _apply_op_batch_impl(FleetState.empty(n_docs, n_keys, xp=jnp),
                                 ops)
 

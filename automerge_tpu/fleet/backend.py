@@ -3539,8 +3539,8 @@ def apply_changes_docs_pipelined(handles, per_doc_changes, sub_batches=4,
     chunks over its thread pool, so the overlap is real CPU concurrency,
     not just dispatch asynchrony — the span rig shows `parse_chunk` /
     `native_parse` spans tiling under the previous sub-batch's
-    `turbo_commit`/`turbo_dispatch` phases (bench.py's seam section
-    measures the overlap from the exported trace).
+    `turbo_commit`/`turbo_dispatch` phases (nothing measures that
+    overlap today: the callers are four tests, none reads the trace).
 
     Committed state is byte-identical to `sub_batches` sequential
     apply_changes_docs calls over the same splits (the prefetched parse
@@ -4002,8 +4002,8 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     Phase attribution: when spans are enabled the call tiles into
     contiguous `turbo_setup` / `turbo_parse` / `turbo_gate` /
     `turbo_commit` / `turbo_stage` / `turbo_dispatch` spans (no
-    unattributed gap between marks — the coverage contract bench.py's
-    observability section checks), with the native parse / device
+    unattributed gap between marks — the coverage contract
+    `seam.untraced_ms_per_step` reads), with the native parse / device
     dispatch sub-spans nested inside. `turbo_gate` and `turbo_commit`
     are tiled in turn by a second sequence: `gate.chain` / `gate.shape`
     / `gate.dag` / `gate.decode` / `gate.general` (per document that

@@ -30,7 +30,8 @@ NUM_PROBES = 7
 # Device dispatches issued by the batched build/probe entry points since
 # import — the sync driver's equivalent of DocFleet.metrics.dispatches
 # (the driver runs over host backends, which have no fleet to count on).
-# bench.py diffs this around a sync round to report dispatches/round.
+# tests/test_sync_fabric.py and chip_smoke.py's sync leg diff this
+# around a sync round.
 _dispatches = 0
 
 
@@ -270,8 +271,8 @@ def build_bloom_filters_batch_begin(hash_lists):
     entry_counts = [len(row) for row in hash_lists]
     live = [i for i, n in enumerate(entry_counts) if n > 0]
     # fabric fan-in visibility: how many peer links each fused build
-    # actually carried (the sync_fabric bench and obs_report read the
-    # histogram to confirm rounds stay fused as the link count grows)
+    # actually carried (obs_report renders the histogram; nothing
+    # asserts on it)
     if _hist.on():
         _hist.record_value('bloom_fused_links', len(live), unit='links')
     if not live:

@@ -66,8 +66,9 @@ _GOLD = np.uint32(0x9E3779B9)     # Fibonacci-hash mix for the space id
 # Device dispatches issued by the batched insert/probe/compare entry
 # points since import — the frontier-index twin of bloom.dispatch_count()
 # (the table serves host-side protocol drivers, which have no fleet
-# dispatch counter in scope). bench.py and the quiet-tick pin tests diff
-# this around a round.
+# dispatch counter in scope). The quiet-tick pin tests
+# (tests/test_hashindex.py, tests/test_sync_fabric.py) and chip_smoke.py's
+# sync leg diff this around a round.
 _dispatches = 0
 
 
@@ -80,8 +81,9 @@ def dispatch_count():
 # AUTOMERGE_TPU_FRONTIER_INDEX=0 pins the classic host-dict membership
 # path EVERYWHERE the index would otherwise serve — the batched driver
 # AND the single-doc protocol (backend/sync.py known_hash_flags routes
-# through _FlatEngine.probe_hashes, which consults this) — the bench's
-# old-path contrast leg and a debugging escape hatch. Default on.
+# through _FlatEngine.probe_hashes, which consults this) — the old-path
+# contrast in tests/test_hashindex.py and tests/test_storage_tier.py, and
+# a debugging escape hatch. Default on.
 import os as _os  # noqa: E402
 _frontier_enabled = _os.environ.get('AUTOMERGE_TPU_FRONTIER_INDEX') != '0'
 
@@ -91,7 +93,7 @@ def frontier_enabled():
 
 
 def set_frontier_enabled(on):
-    """Toggle frontier-index routing (bench / debugging; returns the
+    """Toggle frontier-index routing (tests / debugging; returns the
     previous setting). Covers the batched sync driver and the warm
     single-doc probe path alike."""
     global _frontier_enabled
@@ -111,7 +113,7 @@ def _env_int(name, default, lo, hi):
 # The windowed-probe width and the host/device crossover were both tuned
 # against XLA-CPU dispatch overhead (a while_loop iteration costs
 # ~0.1 ms there). On-chip both tradeoffs move, so they are env-tunable —
-# no code change to re-tune the fabric — and bench.py sweeps the window.
+# no code change to re-tune the fabric. Neither has been swept on the chip.
 _DEF_PROBE_WINDOW = 16
 _DEF_DEVICE_MIN = 4096
 _probe_window = _env_int('AUTOMERGE_TPU_PROBE_WINDOW',
@@ -128,7 +130,7 @@ def probe_window():
 
 
 def set_probe_window(width):
-    """Set the probe window width (bench sweep / on-chip retune);
+    """Set the probe window width (tests / on-chip retune);
     returns the previous width. The probe kernel specializes per width
     (static jit arg), so each distinct width compiles once per batch
     shape and is cached thereafter."""

@@ -338,7 +338,7 @@ class SegmentArena:
 
     def advise_cold(self):
         """Drop this arena's clean pages from the page cache
-        (posix_fadvise DONTNEED) — the bench's cold-read lever. Best
+        (posix_fadvise DONTNEED) — a cold-read lever nothing calls today. Best
         effort; a platform without fadvise is a no-op."""
         if not hasattr(os, 'posix_fadvise'):
             return

@@ -28,8 +28,8 @@ split into two tiers:
 
 With the chunk bytes on disk, the 1M-docs-per-host ceiling becomes a
 disk number: RSS holds the causal lanes only (tests/test_storage_tier.py
-asserts the ceiling; bench.py's ``storage_tier`` section measures
-park/revive/materialize against the RAM-resident baseline).
+asserts the ceiling; park/revive/materialize have no benchmark cell,
+so their times are not measured on the chip).
 
 ``StorageEngine`` is the policy layer binding a live ``DocFleet`` to a
 ``MainStore``: ``park`` demotes cold fleet docs (canonical chunk via

@@ -10,7 +10,8 @@ packed layout in fleet/bloom.py gives every filter its exact wire-format
 byte span inside one concatenated vector, so differing entry counts no
 longer split the batch into per-size-class dispatches, and batch memory
 stays proportional to real filter bytes). `dispatch_count()` exposes the
-round's device-call count for bench.py and the regression tests:
+round's device-call count for the regression tests
+(tests/test_sync_fabric.py) and chip_smoke.py's sync leg:
 
 - ``generate_sync_messages_docs``: every doc's Bloom build (over its
   changes since sharedHeads) lands in one ``build_bloom_filters_batch``

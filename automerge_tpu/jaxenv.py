@@ -1,5 +1,5 @@
 """Process-level JAX set-up shared by the entry points (chip_smoke.py,
-bench.py, tools/loadgen.py): which platform a run may use, which device
+benchmarks/run.py, tools/loadgen.py): which platform a run may use, which device
 it got, and where compiled programs are cached. Everything here must be
 called before the first device use — importing ``automerge_tpu`` does
 not initialise the backend, the first kernel dispatch does.

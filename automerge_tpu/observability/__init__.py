@@ -55,8 +55,8 @@ And the tenant telemetry plane on top (ISSUE-10):
   wire envelope so two peers' sync span trees share one trace id —
   merged by `tools/obs_report.py --stitch`.
 
-`enable()`/`disable()` flip spans + histograms together (the switch the
-bench's <=2% overhead budget is measured across); the flight recorder's
+`enable()`/`disable()` flip spans + histograms together (what tracing
+costs on the chip is unresolved: PERF.md, PR 27); the flight recorder's
 event ring and the SLO accounting stay on either way (the latter has
 its own switch: `DocService(slo=False)`). `tools/obs_report.py` renders
 a phase-attribution report from an exported trace or a forensic dump.

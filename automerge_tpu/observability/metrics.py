@@ -15,9 +15,9 @@
   its dispatches in `fleet.metrics.dispatches`, but some batched paths run
   over HOST backends with no fleet in sight (the sync driver's Bloom
   build/probe lives in `fleet/bloom.py` module state); those modules
-  register a monotonic counter here, so bench.py and the dispatch-count
-  regression tests can diff total device dispatches around a workload
-  without knowing which modules dispatched.
+  register a monotonic counter here, so the dispatch-count regression
+  tests (tests/test_sync_driver.py) can diff total device dispatches
+  around a workload without knowing which modules dispatched.
 - `register_health_source(name, fn)` / `health_counts()`: the same
   roll-up pattern for fault-containment counters — quarantined docs,
   rejected changes/filters, sync retries, injected wire faults, fuzz
@@ -212,7 +212,7 @@ def health_counts():
 #
 # The counter twin of Histogram.snapshot()/delta(): the roll-ups return
 # plain monotonic dicts, and every consumer used to subtract them by hand
-# (bench.py's faults section, obs_report dump comparisons, now the SLO
+# (obs_report dump comparisons, now the SLO
 # windows every tick). One shared subtraction keeps the semantics in one
 # place: keys are unioned, a key missing from either side reads 0.
 

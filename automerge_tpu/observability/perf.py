@@ -55,8 +55,8 @@ trusts:
   tiering plane (fleet/tiering.py) consumes.
 
 Everything is off by default. ``enable_observatory()`` /
-``disable_observatory()`` flip all three legs together (the switch the
-bench's paired <=2% budget is measured across, BENCH_r14_perf.json);
+``disable_observatory()`` flip all three legs together (what having them
+on costs is not measured on the chip; tests/test_export.py turns them on);
 each leg also has its own switch. ``maybe_tick()`` is the cheap hook
 the service tick calls: a no-op unless the default baselines registry
 is enabled.

@@ -51,10 +51,9 @@ DIRTY pairs only, plus the pairs with a currently-firing alert (their
 clear hysteresis needs per-tick decay) — an idle pair costs NOTHING
 per tick, its windows catching up with zeros on the next push. The
 steady-state cost is therefore proportional to the tenants actually
-talking this tick, not the tenant universe, which is what holds the
-measured budget to <=2% on the 10k-session clean service leg (bench.py
-``slo`` section, paired alternating-order reps — BASELINE.md "SLO
-contract").
+talking this tick, not the tenant universe. The <=2% budget on the
+10k-session clean service leg (BASELINE.md "SLO contract") was a CPU-era
+figure; nothing measures it today (no benchmark cell reaches the service).
 """
 
 import array

@@ -23,9 +23,9 @@ phase's self time can be read without the collector.
 ``span_seq()`` is the shape the multi-phase seams use (turbo apply,
 recovery): ``mark(name)`` closes the previous phase and opens the next at
 the SAME timestamp, so consecutive phases tile an interval with no
-unattributed gap — that contiguity is what lets bench.py's observability
-section prove the emitted trace accounts for >= 90% of a seam batch's
-wall-clock.
+unattributed gap — that contiguity is what lets the benchmark's
+``seam.untraced_ms_per_step`` (benchmarks/metrics/) read the part of a
+seam call no phase accounts for.
 
 Spans stay in THIS ring only; the flight recorder reads the ring's tail
 at dump time (recorder.dump_flight_record) rather than mirroring every

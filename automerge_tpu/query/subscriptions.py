@@ -313,7 +313,7 @@ class SubscriptionHub:
 
         One diff per (doc, cursor-frontier) equivalence class; class
         members past the first are served from the memo (the
-        ``diffs_reused`` counter / reuse ratio in bench). An ALL-QUIET
+        ``diffs_reused`` counter, tests/test_query.py). An ALL-QUIET
         tick (every class cursor at its doc's frontier) is proven by ONE
         batched frontier-compare dispatch over the classes — cursor
         head32 rows against the fleet's columnar ``_DocCols`` heads —

@@ -7,8 +7,8 @@ request deadlines honored all-or-nothing at the fused-dispatch boundary
 (typed ``DeadlineExceeded``), jittered-backoff retries under per-tenant
 budgets (typed ``RetriesExhausted``), and a three-stage brownout ladder
 (widen fsync batching -> defer compaction -> shed background sync).
-``tools/loadgen.py`` is the standing scenario testbed; bench.py's
-``service`` section reports p99 request latency and sustained rounds/s.
+``tools/loadgen.py`` is the standing scenario testbed; no benchmark cell
+reaches the service yet, so its p99 and rounds/s are not measured.
 
 Layering note: ``core`` is loaded lazily (PEP 562) so the light policy
 modules (``backoff``, ``admission``, ``deadline``, ``brownout``) stay

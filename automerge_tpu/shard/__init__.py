@@ -5,8 +5,8 @@ kill-driven failover (the ROADMAP's horizontal-scale frontier).
 (cluster.py) own serving, inter-shard replication over the existing
 sync wire protocol, lease-based failure detection, replica promotion,
 and chunk-transfer rebalance. ``tools/loadgen.py``'s ``run_shard_leg``
-is the kill-and-recover chaos harness; bench.py's ``shards`` section
-reports aggregate req/s scaling and failover MTTR.
+is the kill-and-recover chaos harness (tests/test_service_chaos.py, tests/test_control.py); no benchmark
+cell measures req/s scaling or failover MTTR.
 """
 
 from .cluster import RouterTicket, Shard, ShardRouter, shard_stats

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke: the main path, once, on the attached TPU.
 
-Runs five legs in ONE process (a chip belongs to one process at a time),
+Runs four legs in ONE process (a chip belongs to one process at a time),
 each through the entry points a user calls, at the size the repo calls
 its headline, on data made from ``--seed``, and checks every leg against
 the host OpSet oracle (``automerge_tpu.backend``) outside any timing:
@@ -15,8 +15,6 @@ the host OpSet oracle (``automerge_tpu.backend``) outside any timing:
           10,000 docs x 2 peers, 8 changes of divergence per pair
   served  tools/loadgen.py run_leg('clean') -> DocService: 10,000
           sessions, 256 tenants, 20,000 requests, sync_fraction 0.25
-  pallas  pallas_apply_op_batch(interpret=False), both variants, one
-          256 x 256 x 256 batch against apply_op_batch
 
 It refuses to start unless JAX comes up on a TPU and the native codec
 is loaded, and exits non-zero when any leg fails. It prints two stdout
@@ -28,10 +26,10 @@ they are not to be quoted as rates. The last is the verdict, these keys
 and no others, the device as JAX reports it:
 {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}.
 
-``--cpu-rehearsal`` runs the same legs at a tiny size on the CPU (the
-Pallas kernels in interpret mode) to debug this script before chip
-time is spent; without it a non-TPU backend is a failure. ``--legs``
-runs a subset for debugging; a subset never reports ok.
+``--cpu-rehearsal`` runs the same legs at a tiny size on the CPU to
+debug this script before chip time is spent; without it a non-TPU
+backend is a failure. ``--legs`` runs a subset for debugging; a subset
+never reports ok.
 """
 
 import argparse
@@ -46,10 +44,10 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, 'tools'))
 
-LEGS = ('seam', 'text', 'sync', 'served', 'pallas')
+LEGS = ('seam', 'text', 'sync', 'served')
 
-# sizes: BASELINE.json configs 1, 2 and 4, bench.py's `seam` and
-# `service` section defaults
+# sizes: BASELINE.json configs 1, 2 and 4, and tools/loadgen.py's clean
+# leg at 10,000 sessions
 FULL = {
     'seam': dict(docs=10000, keys=1000, changes=20, chains=256, audit=64),
     'text': dict(docs=64, concurrent_docs=8, ops=10000, actors=3,
@@ -57,7 +55,6 @@ FULL = {
     'sync': dict(docs=10000, shared=2, divergence=8, audit=64,
                  device_min=None),
     'served': dict(sessions=10000, tenants=256, requests=20000),
-    'pallas': dict(docs=256, keys=256, ops=256),
 }
 # device_min=0 puts the rehearsal's few hundred hashes on the device
 # table, so the insert/probe kernels run there as they do at full size
@@ -67,7 +64,6 @@ REHEARSAL = {
                  ops_per_change=8),
     'sync': dict(docs=24, shared=2, divergence=4, audit=4, device_min=0),
     'served': dict(sessions=48, tenants=6, requests=160),
-    'pallas': dict(docs=8, keys=17, ops=12),
 }
 
 
@@ -494,44 +490,6 @@ def leg_served(size, seed):
             'slo_pairs_checked': report['slo_audit']['pairs_checked']}
 
 
-def leg_pallas(size, seed, interpret):
-    import jax
-    import numpy as np
-    from automerge_tpu.fleet import FleetState, OpBatch, apply_op_batch
-    from automerge_tpu.fleet.pallas_merge import pallas_apply_op_batch
-    from automerge_tpu.fleet.tensor_doc import ACTOR_BITS
-    rng = np.random.default_rng(seed + 4)
-    n_docs, n_keys, p = size['docs'], size['keys'], size['ops']
-    shape = (n_docs, p)
-    ctr = 1 + np.broadcast_to(np.arange(p, dtype=np.int32), shape)
-    actor = rng.integers(0, 4, shape, dtype=np.int32)
-    is_set = rng.random(shape) < 0.7
-    ops = OpBatch(rng.integers(0, n_keys, shape, dtype=np.int32),
-                  (ctr << ACTOR_BITS) | actor,
-                  rng.integers(-50, 1000, shape, dtype=np.int32),
-                  is_set, ~is_set, rng.random(shape) < 0.9)
-    state = FleetState.empty(n_docs, n_keys)
-    want, want_n = apply_op_batch(state, ops)
-    out = {}
-    for variant in ('dense', 'loop'):
-        def run():
-            got, n = pallas_apply_op_batch(state, ops, interpret=interpret,
-                                           variant=variant)
-            jax.block_until_ready(got.winners)
-            return got, n
-        (got, n), out[f'{variant}_first_s'] = timed(run)
-        (got, n), out[f'{variant}_warm_s'] = timed(run)
-        check(int(n) == int(want_n), f'{variant}: op count {int(n)}')
-        for name in ('winners', 'values', 'counters'):
-            check(np.array_equal(
-                np.asarray(getattr(got, name))[:, :n_keys],
-                np.asarray(getattr(want, name))[:, :n_keys]),
-                f'{variant}: {name} differ from apply_op_batch on the '
-                f'real key columns')
-    out.update(shape=[n_docs, n_keys, p], interpret=interpret)
-    return out
-
-
 # dispatches a leg must show: kernel-ledger kinds by prefix, and the
 # modules' own counters by the names dispatch_snapshot gives them
 REQUIRED_DISPATCHES = {
@@ -540,7 +498,6 @@ REQUIRED_DISPATCHES = {
     'sync': [('bloom_',), ('hashindex_',), ('bloom.dispatch_count',),
              ('hashindex.dispatch_count',)],
     'served': [('apply_op_batch', 'apply_register_batch'), ('bloom_',)],
-    'pallas': [('pallas_apply_op_batch',)],
 }
 
 
@@ -617,8 +574,6 @@ def main(argv=None):
         'text': lambda: leg_text(sizes['text'], args.seed),
         'sync': lambda: leg_sync(sizes['sync'], args.seed),
         'served': lambda: leg_served(sizes['served'], args.seed),
-        'pallas': lambda: leg_pallas(sizes['pallas'], args.seed,
-                                     interpret=args.cpu_rehearsal),
     }
     start = time.perf_counter()
     records = {name: run_leg(name, fns[name], counter) for name in legs}

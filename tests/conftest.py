@@ -2,7 +2,7 @@
 
 Multi-chip sharding is validated on host CPU devices (the driver
 separately dry-runs the multi-chip path via __graft_entry__.py);
-chip_smoke.py and bench.py run on the real TPU outside of pytest.
+chip_smoke.py and benchmarks/run.py run on the real TPU outside of pytest.
 """
 
 import os

@@ -488,12 +488,10 @@ class TestFixedViolations:
                                max_size=1 << 16)
 
     def test_fixed_jit_entry_points_are_in_the_ledger(self):
-        from automerge_tpu.fleet import pallas_merge, registers, sharding
+        from automerge_tpu.fleet import registers, sharding
         from automerge_tpu.observability import perf
         assert registers.visible_registers.kernel_kind == \
             'visible_registers'
-        assert pallas_merge.pallas_apply_op_batch.kernel_kind == \
-            'pallas_apply_op_batch'
         mesh = sharding.fleet_mesh()
         for factory, kind in (
                 (sharding.sharded_seq_apply, 'sharded_seq_apply'),
@@ -507,8 +505,8 @@ class TestFixedViolations:
         # before the first dispatch (kernel_snapshot shows them once
         # dispatched; kernel_kinds lists every wired kind)
         kinds = set(perf.kernel_kinds())
-        assert {'visible_registers', 'pallas_apply_op_batch',
-                'sharded_seq_apply', 'sharded_apply'} <= kinds
+        assert {'visible_registers', 'sharded_seq_apply',
+                'sharded_apply'} <= kinds
 
     def test_register_source_registration_is_locked(self):
         # the round-13 registries now take the counters lock; pin the

@@ -200,8 +200,7 @@ class TestCommitRegressionGuard:
 
     def test_seam_commit_dispatches_flat(self):
         """One device dispatch per turbo batch, independent of doc
-        count — the seam_commit bench section's dispatch pin, as a
-        tier-1 test."""
+        count."""
         for n in (8, 64):
             fleet = DocFleet(doc_capacity=n, key_capacity=8)
             handles = init_docs(n, fleet)

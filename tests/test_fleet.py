@@ -14,7 +14,9 @@ from automerge_tpu.fleet import (
     FleetState, OpBatch, apply_op_batch, pack_op_id,
     build_bloom_filters, probe_bloom_filters, bloom_filter_bytes,
 )
+from automerge_tpu.fleet import apply as fleet_apply
 from automerge_tpu.fleet.bloom import hashes_to_words, num_filter_bits
+from automerge_tpu.fleet.tensor_doc import ACTOR_BITS
 
 
 def random_map_workload(rng, n_docs, n_keys, n_actors, rounds, ops_per_round):
@@ -156,6 +158,195 @@ class TestFleetMergeDifferential:
         winners = np.asarray(state.winners)
         assert values[0, 0] == 42
         assert np.all(winners[1, :3] == 0)
+
+
+def random_batch(rng, n_docs, n_keys, ops_per_doc, ctr0=1):
+    shape = (n_docs, ops_per_doc)
+    key_id = rng.integers(0, n_keys, shape, dtype=np.int32)
+    actor = rng.integers(0, 4, shape, dtype=np.int32)
+    ctrs = ctr0 + np.broadcast_to(np.arange(ops_per_doc, dtype=np.int32), shape)
+    packed = (ctrs.astype(np.int32) << ACTOR_BITS) | actor
+    value = rng.integers(-50, 1000, shape, dtype=np.int32)
+    is_set = rng.random(shape) < 0.7
+    valid = rng.random(shape) < 0.9
+    return OpBatch(key_id, packed, value, is_set, ~is_set, valid)
+
+
+def assert_states_match(a, b, n_keys):
+    """All real key columns; the scratch column absorbs masked scatter
+    lanes by design and holds garbage."""
+    for name in ('winners', 'values', 'counters'):
+        got = np.asarray(getattr(a, name))[:, :n_keys]
+        want = np.asarray(getattr(b, name))[:, :n_keys]
+        np.testing.assert_array_equal(got, want, err_msg=name)
+
+
+def without_incs(ops):
+    return OpBatch(ops.key_id, ops.packed, ops.value, ops.is_set,
+                   np.zeros_like(ops.is_inc), ops.valid)
+
+
+# entry point -> (starts from a standing state, takes inc lanes, call);
+# the donating ones consume the state they are handed
+GRID_VARIANTS = {
+    'donated': (True, True, lambda state, ops, n_docs, n_keys:
+                fleet_apply.apply_op_batch_donated(state, ops)),
+    'fresh': (False, True, lambda state, ops, n_docs, n_keys:
+              fleet_apply.apply_op_batch_fresh(ops, n_docs, n_keys)),
+    'kills_no_kill_lanes': (
+        True, True, lambda state, ops, n_docs, n_keys:
+        fleet_apply.apply_op_batch_kills(
+            state, ops, np.zeros((n_docs, 4), np.int32),
+            np.zeros((n_docs, 4), np.int32))),
+    'noinc_donated': (
+        True, False, lambda state, ops, n_docs, n_keys:
+        fleet_apply.apply_op_batch_noinc_donated(state, ops)),
+    'noinc_fresh': (False, False, lambda state, ops, n_docs, n_keys:
+                    fleet_apply.apply_op_batch_noinc_fresh(ops, n_docs,
+                                                           n_keys)),
+}
+
+
+@pytest.mark.parametrize('variant', sorted(GRID_VARIANTS))
+@pytest.mark.parametrize('n_docs,n_keys,p', [
+    (8, 17, 12),      # everything unaligned
+    (128, 127, 32),   # 128 documents exactly
+    (200, 300, 16),   # keys past 256
+    (16, 40, 200),    # more ops a document than keys
+    (8, 130, 300),    # both
+])
+def test_grid_variants_agree(n_docs, n_keys, p, variant):
+    """Every grid entry point the fleet dispatches holds apply_op_batch's
+    state on the same batch. The set-only kernels get the batch with its
+    inc lanes cleared (their soundness gate: a counter-free grid); the
+    fresh ones start from the empty grid they build inside the jit, the
+    others from the state two earlier rounds left, one of older opIds
+    than the batch's and one of newer, so its sets both win and lose."""
+    standing, takes_incs, call = GRID_VARIANTS[variant]
+    rng = np.random.default_rng(n_docs + n_keys)
+
+    def batch(ctr0):
+        ops = random_batch(rng, n_docs, n_keys, p, ctr0=ctr0)
+        return ops if takes_incs else without_incs(ops)
+
+    state = FleetState.empty(n_docs, n_keys)
+    if standing:
+        for ctr0 in (1, 1 + 2 * p):
+            state, _ = apply_op_batch(state, batch(ctr0))
+    ops = batch(1 + p if standing else 1)
+    want, want_n = apply_op_batch(state, ops)
+    got, got_n = call(state, ops, n_docs, n_keys)   # last: may consume state
+    assert int(got_n) == int(want_n)
+    assert_states_match(got, want, n_keys)
+
+
+def grid_rule(state, ops):
+    """The grid's merge rule stated in plain NumPy, one op at a time: per
+    (doc, key) the largest packed opId among valid sets wins and its
+    value is stored; a key whose winner changed restarts its counter;
+    valid incs add. Imports nothing of fleet/apply.py."""
+    winners, values, counters = (np.array(np.asarray(x)) for x in (
+        state.winners, state.values, state.counters))
+    key_id, packed, value, is_set, is_inc, valid = (
+        np.asarray(c) for c in (ops.key_id, ops.packed, ops.value,
+                                ops.is_set, ops.is_inc, ops.valid))
+    before = winners.copy()
+    n_docs, width = key_id.shape
+    for d in range(n_docs):
+        for j in range(width):
+            k = key_id[d, j]
+            if valid[d, j] and is_set[d, j] and packed[d, j] >= winners[d, k]:
+                winners[d, k] = packed[d, j]
+                values[d, k] = value[d, j]
+    counters[winners != before] = 0
+    for d in range(n_docs):
+        for j in range(width):
+            if valid[d, j] and is_inc[d, j]:
+                counters[d, key_id[d, j]] += value[d, j]
+    return FleetState(winners, values, counters), int(valid.sum())
+
+
+def one_op_batch(n_docs, key, packed, value, is_set):
+    full = lambda v, dtype: np.full((n_docs, 1), v, dtype)
+    return OpBatch(full(key, np.int32), full(packed, np.int32),
+                   full(value, np.int32), full(is_set, bool),
+                   full(not is_set, bool), full(True, bool))
+
+
+class TestGridRule:
+    """apply_op_batch against grid_rule: the kernel's oracle that is not
+    another JAX program."""
+
+    def check_round(self, state, ops, n_keys):
+        want, want_n = grid_rule(state, ops)
+        got, got_n = apply_op_batch(state, ops)
+        assert int(got_n) == want_n
+        assert_states_match(got, want, n_keys)
+        return got
+
+    def test_duplicate_delivery_is_idempotent(self):
+        """The same op delivered twice (same packed opId, same value: the
+        sync path can re-send) selects the winner's value once; it does
+        not sum it."""
+        rng = np.random.default_rng(42)
+        n_docs, n_keys, p = 12, 23, 160
+        ops = random_batch(rng, n_docs, n_keys, p)
+        cols = np.stack([ops.key_id, ops.packed, ops.value,
+                         ops.is_set.astype(np.int32),
+                         ops.is_inc.astype(np.int32),
+                         ops.valid.astype(np.int32)])
+        src = rng.integers(0, p // 2, 30)
+        dst = p - 1 - rng.permutation(30)   # mirrored into the far lanes
+        cols[:, :, dst] = cols[:, :, src]
+        dup = OpBatch(cols[0], cols[1], cols[2], cols[3] != 0, cols[4] != 0,
+                      cols[5] != 0)
+        self.check_round(FleetState.empty(n_docs, n_keys), dup, n_keys)
+
+    def test_multiple_rounds_carry_state(self):
+        rng = np.random.default_rng(7)
+        n_docs, n_keys = 16, 33
+        state = FleetState.empty(n_docs, n_keys)
+        for r in range(3):
+            ops = random_batch(rng, n_docs, n_keys, 8, ctr0=1 + 8 * r)
+            state = self.check_round(state, ops, n_keys)
+
+    def test_counter_accumulation_and_overwrite(self):
+        """Counters add across batches; a later set overwrites an earlier
+        one and its accumulator restarts with it."""
+        n_docs, n_keys = 4, 8
+        key = np.zeros((n_docs, 2), dtype=np.int32)
+        packed = np.tile(np.array([[1 << ACTOR_BITS, 2 << ACTOR_BITS]],
+                                  dtype=np.int32), (n_docs, 1))
+        value = np.tile(np.array([[5, 7]], dtype=np.int32), (n_docs, 1))
+        is_set = np.tile(np.array([[True, False]]), (n_docs, 1))
+        ops = OpBatch(key, packed, value, is_set, ~is_set,
+                      np.ones((n_docs, 2), dtype=bool))
+        state = self.check_round(FleetState.empty(n_docs, n_keys), ops,
+                                 n_keys)
+        assert np.asarray(state.values)[0, 0] == 5
+        assert np.asarray(state.counters)[0, 0] == 7
+        state = self.check_round(
+            state, one_op_batch(n_docs, 0, 9 << ACTOR_BITS, 42, True), n_keys)
+        assert np.asarray(state.values)[0, 0] == 42
+        assert np.asarray(state.winners)[0, 0] == 9 << ACTOR_BITS
+        assert np.asarray(state.counters)[0, 0] == 0
+
+    def test_counter_reset(self):
+        """A changed winner restarts the counter; the standing winner
+        delivered again keeps it."""
+        n_docs, n_keys = 4, 8
+        state = FleetState.empty(n_docs, n_keys)
+        for key, packed, value, is_set in (
+                (0, 1 << ACTOR_BITS, 10, True),     # counter base
+                (0, 2 << ACTOR_BITS, -4, False),    # negative inc
+                (0, 1 << ACTOR_BITS, 10, True),     # delivered again: kept
+                (0, 9 << ACTOR_BITS, 100, True),    # overwrite: restart
+                (0, 11 << ACTOR_BITS, 2, False)):   # inc on the new winner
+            state = self.check_round(
+                state, one_op_batch(n_docs, key, packed, value, is_set),
+                n_keys)
+        assert np.asarray(state.counters)[0, 0] == 2
+        assert np.asarray(state.values)[0, 0] == 100
 
 
 class TestFleetBloom:

@@ -1024,7 +1024,7 @@ class TestExactDeviceMode:
 
 
 class TestAdviceRegressions:
-    """Round-1 advisor findings (ADVICE.md): turbo multi-chunk buffers,
+    """Round-1 advisor findings (fixed in PR 1): turbo multi-chunk buffers,
     unknown pred actors, null-value register materialization."""
 
     def test_turbo_multichunk_buffer_not_dropped(self):

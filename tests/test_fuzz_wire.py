@@ -30,7 +30,7 @@ def test_fuzz_wire_smoke():
 
 
 def test_fuzz_corpus_registered():
-    """The corpus size lands in the health roll-up so bench/CI can see
+    """The corpus size lands in the health roll-up so CI can see
     the fuzz surface."""
     from automerge_tpu.observability import health_counts
     build_corpus()

@@ -6,10 +6,14 @@ codec cannot build next to its source — so this doubles as the graceful-
 degradation test for native.available() == False (pure-Python codecs,
 host-mirror fleet paths)."""
 
+import glob
 import os
+import re
 import subprocess
 import sys
 import zipfile
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -72,3 +76,41 @@ def test_runs_from_zip_without_native_codec(tmp_path):
         cwd=str(tmp_path))
     assert proc.returncode == 0, proc.stderr
     assert 'ZIP-PACKAGED OK' in proc.stdout
+
+
+# ---- the documents that describe the tree as it is --------------------------
+
+_TREE_PREFIXES = ('automerge_tpu/', 'tests/', 'tools/', 'benchmarks/')
+_ROOT_NAME = re.compile(r'[\w.\-*]+\.(py|json|jsonl|md)')
+# `path.py:120`, `path.py:120-140`, `path.py::TestClass::test_name`
+_SUFFIX = re.compile(r'(::.*|:\d+([-–]\d+)?)$')
+
+
+def _named_paths(text):
+    """The back-ticked tokens of a document that name a place in the
+    tree: those that start with one of the tree's directories (the first
+    word of the token is the path) and bare `*.py` / `*.json` / `*.jsonl`
+    / `*.md` names, which are the root's."""
+    for token in re.findall(r'`([^`\n]+)`', text):
+        if token.startswith(_TREE_PREFIXES):
+            path = token.split()[0]
+        elif _ROOT_NAME.fullmatch(token):
+            path = token
+        else:
+            continue
+        yield _SUFFIX.sub('', path.rstrip('.,;:)'))
+
+
+@pytest.mark.parametrize('doc', ['README.md', 'PARITY.md',
+                                 '.claude/skills/verify/SKILL.md'])
+def test_docs_name_only_files_that_exist(doc):
+    """README.md, PARITY.md and the verify skill say what the tree holds
+    today, so every file they name is there (a `*` matches as a glob).
+    The records (PERF.md, ROADMAP.md, CHANGES.md, BASELINE.md) may name
+    what was removed and are not read here."""
+    with open(os.path.join(ROOT, doc)) as f:
+        named = sorted(set(_named_paths(f.read())))
+    assert named, f'{doc} names no file at all'
+    missing = [path for path in named
+               if not glob.glob(os.path.join(ROOT, path))]
+    assert not missing, f'{doc} names files that do not exist: {missing}'

@@ -2,8 +2,8 @@
 surface in one process — capacity growth, actor-table renumbering, turbo
 ingest, the batched sync driver, bulk load, and whole-fleet readback all
 interact at a size the per-feature suites (doc_capacity 2-8) never reach.
-Shapes stay small enough for the CI budget; BENCH-scale runs live in
-bench.py."""
+Shapes stay small enough for the CI budget; deployment-scale runs are
+the benchmark's cells (benchmarks/)."""
 
 import numpy as np
 import pytest

@@ -539,7 +539,7 @@ def test_disk_tier_million_docs_resident_budget(tmp_path):
     assert stats['disk_bytes'] > 100 << 20          # chunks went to disk
     rss1 = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
     grew_kib = rss1 - rss0
-    # the ceiling the 10M bench extrapolates from: resident lanes only
+    # the ceiling a 10M-doc host extrapolates from: resident lanes only
     assert grew_kib < 300 << 10, f'RSS grew {grew_kib} KiB'
     # spot-check far-end reads and a revive round trip off the map
     assert eng.n_changes(n - 1) == 1

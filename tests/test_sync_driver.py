@@ -186,7 +186,7 @@ class TestDifferentialEquality:
         # THE O(1)-dispatch contract for sync rounds: a generate round over
         # 4x the peers issues exactly the same number of device dispatches
         # (2: one flat Bloom build, one flat probe), observed through the
-        # observability roll-up the bench reports from
+        # observability roll-up
         from automerge_tpu.observability import dispatch_counts
         counts = {}
         for n in (6, 24):

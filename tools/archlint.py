@@ -10,7 +10,7 @@ Usage:
                                                   obs_report --archlint)
   python tools/archlint.py --list-rules           show the rule table
 
-Default paths: automerge_tpu/ tools/ bench.py (the whole shipped tree).
+Default paths: automerge_tpu/ tools/ (the whole shipped tree).
 
 --check exits non-zero on: any unsuppressed violation, any inline
 suppression not recorded in tools/archlint_baseline.json, any stale
@@ -29,13 +29,13 @@ sys.path.insert(0, REPO_ROOT)
 
 from automerge_tpu import analysis                           # noqa: E402
 
-DEFAULT_PATHS = ('automerge_tpu', 'tools', 'bench.py')
+DEFAULT_PATHS = ('automerge_tpu', 'tools')
 DEFAULT_BASELINE = os.path.join(REPO_ROOT, 'tools',
                                 'archlint_baseline.json')
 
 
 def run(paths, baseline_path, root=None):
-    """Lint + baseline check; returns the result dict tests and bench
+    """Lint + baseline check; returns the result dict the tests
     consume (counts, findings, stale entries, parse errors)."""
     rules = analysis.get_rules()
     findings, files, errors = analysis.lint_paths(paths, rules, root=root)

@@ -5,8 +5,8 @@ Runs the cross-backend chaos differential at the deep-dose knobs
 mid-run joiners) plus a fleet drop/rebuild-from-logs leg exercising the
 donation failure contract (fleet/apply.py: device state is a derived
 cache; documents rebuild into a fresh fleet from their change logs),
-then writes a summary artifact (default CHAOS_r05.json) so the dose is
-reproducible evidence, not a claim.
+then writes a summary artifact (default: chaos_dose.json under the
+system temp directory) so the dose is reproducible evidence, not a claim.
 
 Usage: python tools/chaos_dose.py [out.json]
 Knobs: CHAOS_SEEDS / CHAOS_STEPS / REBUILD_LEGS env vars.
@@ -17,6 +17,7 @@ import os
 import random
 import subprocess
 import sys
+import tempfile
 import time
 
 # the dose is a host-semantics differential on eight virtual CPU devices
@@ -30,7 +31,8 @@ if '--xla_force_host_platform_device_count' not in flags:
 SEEDS = int(os.environ.get('CHAOS_SEEDS', '30'))
 STEPS = int(os.environ.get('CHAOS_STEPS', '200'))
 REBUILD_LEGS = int(os.environ.get('REBUILD_LEGS', '10'))
-OUT = sys.argv[1] if len(sys.argv) > 1 else 'CHAOS_r05.json'
+OUT = sys.argv[1] if len(sys.argv) > 1 else os.path.join(
+    tempfile.gettempdir(), 'chaos_dose.json')
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

@@ -30,8 +30,8 @@ tallies must match the client-observed typed outcomes EXACTLY, so a
 double-count or missed-reject in the accounting plane fails the leg —
 and ``latency_step=(tick, extra_s)`` injects a synthetic mid-leg
 latency regression for timing the burn-rate alert's detection. Used by
-tests/test_service_chaos.py (small doses) and bench.py's ``service``
-and ``slo`` sections (10k sessions).
+tests/test_service_chaos.py (small doses) and chip_smoke.py's ``served``
+leg (10k sessions).
 
 Standalone:  python tools/loadgen.py            # default three legs
              LOADGEN_SESSIONS=10000 LOADGEN_REQUESTS=40000 \
@@ -249,8 +249,7 @@ def run_leg(name, *, sessions=1000, tenants=64, zipf_s=1.2,
     leg's arrivals end, every pump advances the fake clock by an extra
     `extra_s`, so every in-flight request's measured latency jumps by
     it — the controlled fault the SLO fast-window burn alert must catch
-    (the bench `slo` section and the acceptance test time its
-    detection). The report then carries `slo_step_tick` and
+    (tests/test_slo.py times its detection). The report then carries `slo_step_tick` and
     `slo_alerts`.
 
     Every leg whose service keeps the default SLO accounting ends with

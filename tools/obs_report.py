@@ -12,7 +12,6 @@ Usage:
     python tools/archlint.py --check --json - | \\
                                python tools/obs_report.py --archlint -
     python tools/obs_report.py --floor kernel_ledger.json [trace.json]
-    python tools/obs_report.py --trajectory [BENCH_LEDGER.jsonl]
     python tools/obs_report.py --control control_ledger.json [--json]
     python tools/obs_report.py --control flight-quarantine-1.json
 
@@ -23,7 +22,8 @@ achieved GB/s) from a ``perf.dump_ledger`` JSON, beside the host
 phases of an optional span trace — so "native parse vs scatter
 dispatch vs host phases" reads from live data
 (``observability.perf.instrument_kernel`` wraps every jitted entry
-point; the bench ``perf`` section writes the ledger dump).
+point; ``perf.dump_ledger`` writes the ledger dump, and
+``traces/kernel_ledger.json`` is a recorded one).
 
 Metrics mode reads a Prometheus exposition page (a MetricsExporter
 ``write_snapshot`` file or a curl'd /metrics body) and surfaces the
@@ -50,8 +50,7 @@ Flight mode pretty-prints a forensic dump: trigger, per-doc errors
 (slot, durable id, stage, typed error), then the surrounding event ring.
 With a second (baseline) dump, the health counters print as the DELTA
 between the two dumps — the counter twin of the histogram delta, so two
-forensic snapshots bracket an incident the way two bench snapshots
-bracket a workload.
+forensic snapshots bracket an incident.
 
 Control mode renders the control plane's why-did-it-act timeline from
 a ``Controller.dump_decisions`` ledger or a flight dump's
@@ -636,17 +635,10 @@ def main(argv):
     if argv[0] == '--floor':
         if len(argv) < 2:
             print('--floor needs a kernel-ledger JSON path '
-                  '(perf.dump_ledger / bench perf section)',
+                  '(perf.dump_ledger)',
                   file=sys.stderr)
             return 2
         return render_floor(argv[1], argv[2] if len(argv) > 2 else None)
-    if argv[0] == '--trajectory':
-        # the bench-ledger trajectory, from the observability front door
-        # (implementation lives in tools/bench_ledger.py)
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import bench_ledger
-        return bench_ledger.render_trajectory(
-            argv[1] if len(argv) > 1 else None)
     if argv[0] == '--archlint':
         if len(argv) < 2:
             print('--archlint needs an `archlint --json` payload path '
