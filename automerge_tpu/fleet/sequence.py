@@ -6,7 +6,7 @@ host mirror: automerge_tpu/backend/op_set.py ObjState.insert_rga): a fleet of
 N sequence documents (one Text or list object each) lives as padded [N, S]
 slot tensors plus a linked-list `nxt` pointer array encoding RGA order. Slots
 are allocated in op-arrival order and never move; an insert splices pointers,
-so per-op work is O(S) vector compares (the referent lookup) + an O(skip)
+so an op's work is its referent lookup (O(S) vector compares) + an O(skip)
 pointer walk, with NO data movement of the sequence itself — the analogue of
 the reference editing a block in place instead of reshuffling the array.
 That holds of the compiled program too because a dispatch defers its splices:
@@ -14,7 +14,10 @@ inside the op scan `elem_id` and `nxt` are only read, the batch's new slots
 and repointed nodes live in a per-row overlay as wide as the batch, and each
 array takes its overlay in one scatter when the scan is over (a scan step that
 wrote them while the skip walk's loop held them had both copied whole, every
-step: see _apply_seq_batch_impl).
+step: see _apply_seq_batch_impl). And since the scan never writes `elem_id`,
+the lookups of a whole batch are made before it, each part of the row compared
+with many refs while it is on the chip (_referent_lookup): a scan step reads
+no node array in full, only the overlay, as wide as the batch.
 
 Application is a `vmap` over docs of a `lax.scan` over each doc's op stream:
 ops within one doc apply in causal order (as the reference's per-change op
@@ -264,10 +267,11 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     dispatch found it, read and never written here; the batch's splices
     live in the carry's overlay (see _apply_seq_batch_impl).
     carry = (ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n,
-    inexact)."""
+    inexact); the op's `in_row` is its referent's node in the row as the
+    dispatch found it, or `nodes` (_referent_lookup)."""
     ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n, \
         inexact = carry
-    kind, ref, packed, value, preds, flag = op
+    kind, ref, in_row, packed, value, preds, flag = op
     # lane l of node i is at l * nodes + i of the lane arrays
     nodes = elem_id.shape[0]
     capacity = nodes - 3
@@ -290,13 +294,11 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     is_upd = (kind == SET) | (kind == DEL)
     is_inc = kind == INC
 
-    # Referent / target node: packed elemIds are unique and non-zero, so an
-    # equality one-hot over the node axis finds it (sentinel and scratch
-    # entries keep elem_id 0), or one over the batch's own inserts. A miss
-    # (op referencing an elemId not in the doc, e.g. one dropped by a
-    # capacity overflow) must not resolve to an arbitrary slot.
-    node_ids = np.arange(nodes, dtype=np.int32)
-    in_row = jnp.min(jnp.where(elem_id == ref, node_ids, nodes))
+    # Referent / target node: the row's own (`in_row`, looked up before
+    # the scan) or one of the batch's inserts, found by an equality one-hot
+    # over the overlay. A miss (op referencing an elemId not in the doc,
+    # e.g. one dropped by a capacity overflow) must not resolve to an
+    # arbitrary slot.
     in_batch = jnp.min(jnp.where(ov_id == ref, entries, width))
     found = (in_row < nodes) | (in_batch < width)
     match = jnp.where(in_row < nodes, in_row,
@@ -469,6 +471,51 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
             inexact), applied
 
 
+# Nodes of a row that the referent lookup compares with a batch's refs at a
+# time. The chip settled it (PERF.md section 6, PR 32): the lookup is bound
+# by the compares, 2.4 ms a dispatch at 128 rows x 64 refs x 262,147 nodes
+# where one pass over the row for every ref took 11.6; blocks of 1,024 to
+# 8,192 nodes read within a tenth of one another, and the whole row at once
+# or blocks of 32,768 were slower at that shape and at 64 rows x 16,384 refs.
+LOOKUP_BLOCK = 2048
+
+
+def _referent_lookup(elem_id, ref):
+    """[N, P]: for every op cell the least node of its row whose `elem_id`
+    is the cell's `ref`, or `nodes` where the row has none. Packed elemIds
+    are unique and non-zero, so an equality one-hot over the node axis finds
+    the referent; `ref == 0` (a head insert, a PAD cell) finds node 0, since
+    the sentinels and unallocated slots keep elem_id 0. The row is what the
+    dispatch found: the batch's own inserts are looked up in the scan.
+
+    The row is read once, a block of nodes at a time, and each block is
+    compared with all of its row's refs while it is on the chip; the
+    compare, select and min of a block fuse, so the [N, P, nodes] product is
+    never laid out, and where a backend does not fuse them (XLA's CPU one)
+    what it lays out is a block wide."""
+    nodes = elem_id.shape[1]
+    # blocks tile the slots (a pool's capacity is a power of two); what is
+    # left over, the three nodes of the sentinels' offset, is a block of
+    # its own
+    block = max(min(LOOKUP_BLOCK, nodes - SLOT0), 1)
+
+    def among(start, size):
+        part = lax.dynamic_slice_in_dim(elem_id, start, size, axis=1)
+        node_ids = start + lax.iota(jnp.int32, size)
+        return jnp.min(jnp.where(part[:, None, :] == ref[:, :, None],
+                                 node_ids, nodes), axis=2)
+
+    with jax.named_scope('seq.referent_lookup'):
+        least = lax.fori_loop(
+            0, nodes // block,
+            lambda k, least: jnp.minimum(least, among(k * block, block)),
+            jnp.full(ref.shape, nodes, jnp.int32))
+        rest = nodes % block
+        if rest:
+            least = jnp.minimum(least, among(nodes - rest, rest))
+        return least
+
+
 def _apply_seq_batch_impl(state, ops):
     """Deferred splice: inside the scan `elem_id` and `nxt` are read-only
     (an array the scan step wrote while the skip walk's `while` held it was
@@ -476,15 +523,18 @@ def _apply_seq_batch_impl(state, ops):
     overlay as wide as the batch: the ids and next pointers of the slots it
     allocates (slot SLOT0 + n0 + k is entry k, n0 the row's cursor at
     entry) and the repointed old nodes as (node, new next) pairs. Each
-    array takes the overlay in one scatter after the scan."""
+    array takes the overlay in one scatter after the scan. For the same
+    reason every op's referent among the row's old nodes is found before
+    the scan, for the whole batch in one pass (_referent_lookup), and not
+    by a search of `elem_id` in every scan step."""
     rows, width = ops.kind.shape
     width = max(width, 1)       # an empty batch still traces the step
 
     def per_doc(elem_id, nxt, reg, killed, val, counter, n, inexact,
-                kind, ref, packed, value, preds, flag, zeros):
+                kind, ref, in_row, packed, value, preds, flag, zeros):
         carry = (zeros, zeros, zeros - 1, zeros, reg, killed, val, counter,
                  n, inexact)
-        xs = (kind, ref, packed, value, preds, flag)
+        xs = (kind, ref, in_row, packed, value, preds, flag)
         carry, applied = lax.scan(
             lambda c, x: _apply_one_doc(c, x, elem_id, nxt, n),
             carry, xs)
@@ -505,7 +555,8 @@ def _apply_seq_batch_impl(state, ops):
     carry, applied = jax.vmap(per_doc)(
         state.elem_id, state.nxt, state.reg, state.killed, state.val,
         state.counter, state.n, state.inexact, ops.kind, ops.ref,
-        ops.packed, ops.value, ops.preds, ops.flag,
+        _referent_lookup(state.elem_id, ops.ref), ops.packed, ops.value,
+        ops.preds, ops.flag,
         # the empty overlay, batched like the rest of the scan's carry
         jnp.zeros((rows, width), jnp.int32))
     return SeqState(*carry), jnp.sum(applied)

@@ -143,11 +143,15 @@ def shard_long_seq(state, mesh):
 
 
 def sharded_long_seq_apply(mesh):
-    """Jitted op application for slot-sharded long documents. Per-op work is
-    a one-hot referent lookup over the sharded slot axis (an all-reduce per
-    op) plus the RGA pointer walk's scalar gathers; causality keeps the op
-    stream itself sequential — the win is that the document's state never
-    has to fit one chip."""
+    """Jitted op application for slot-sharded long documents. A dispatch
+    looks all its ops' referents up at once, before the op scan
+    (sequence._referent_lookup): the partitioner gathers `elem_id`, 4 of a
+    node's 60 bytes, once a dispatch for it, where the lookup in the scan
+    cost an all-reduce an op (read from the compiled program on virtual
+    devices; not measured on chips). Per-op work is the RGA pointer walk's
+    scalar gathers, an all-reduce each. Causality keeps the op stream itself
+    sequential — the win is that the rest of the document's state never has
+    to fit one chip."""
     from .sequence import _apply_seq_batch_impl
     by_ndim = long_seq_sharding(mesh)
 

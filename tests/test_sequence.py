@@ -567,17 +567,115 @@ class TestDeferredSplice:
         assert (elem[0, 3 + inserts:] == 0).all()
 
 
-def test_scan_body_copies_no_node_array():
-    """The compiled program may not copy an array of elem_id's shape
-    anywhere but in its entry computation: a scan step that writes an
-    array which the skip walk's `while` holds is given a whole-array copy
-    of it every step (two 134 MB copies a step were 54 % of the device
-    time of the text cell before the splices were deferred)."""
-    import re
+# ---- the referent lookup, hoisted out of the scan: every op cell's referent
+# among the row's old nodes is found before the scan, for the whole batch ---
+
+# ids 2..5 of A1 in both rows, at other nodes: row 0 typed them in order
+# (nodes 3, 4, 5, 6: "abcd"), row 1 took 3, 2, 5, 4 (id 2 at node 4, 3 at 3,
+# 4 at 6, 5 at 5: "bdac"), so a lookup that read another row's `elem_id`
+# would splice after the wrong character
+SAME_IDS_OLD = (_typed('_head', 2, 4), [
+    ins('_head', f'3@{A1}', 'b'), ins('_head', f'2@{A1}', 'a'),
+    ins(f'3@{A1}', f'5@{A1}', 'd'), ins(f'2@{A1}', f'4@{A1}', 'c')])
+
+# name -> (capacity, width the batch is padded to (0: as wide as it is),
+#          the batch (both rows take it), indexes of the ops both rows drop)
+LOOKUP_CASES = {
+    'old_element': (64, 16, [
+        ins(f'3@{A1}', f'6@{A1}', 'x'), _del(f'4@{A1}', f'7@{A1}'),
+        _set(f'2@{A1}', f'8@{A1}', 'A'), ins(f'5@{A1}', f'9@{A1}', 'y')], ()),
+    'insert_earlier_in_the_batch': (64, 16, [
+        ins(f'4@{A1}', f'6@{A1}', 'x'), ins(f'6@{A1}', f'7@{A1}', 'y'),
+        _del(f'6@{A1}', f'8@{A1}'), ins(f'7@{A1}', f'9@{A1}', 'z')], ()),
+    'head': (64, 16, [
+        ins('_head', f'6@{A1}', 'x'), ins('_head', f'7@{A2}', 'y'),
+        ins(f'2@{A1}', f'8@{A1}', 'z')], ()),
+    'id_the_row_never_had': (64, 16, [
+        ins(f'2@{A1}', f'6@{A1}', 'x'), ins(f'2@{A2}', f'7@{A1}', '?'),
+        ins(f'6@{A1}', f'8@{A1}', 'y')], (1,)),
+    # four old elements in a row of 8: the fifth insert is dropped, and so
+    # are the insert and the DEL that name it
+    'insert_dropped_at_capacity': (8, 16, _typed(f'2@{A1}', 6, 4) + [
+        ins(f'9@{A1}', f'10@{A1}', 'z'), ins(f'10@{A1}', f'11@{A1}', 'w'),
+        _del(f'10@{A1}', f'12@{A1}'), _set(f'3@{A1}', f'13@{A1}', 'B')],
+        (4, 5, 6)),
+    'width_1': (64, 0, [ins(f'4@{A1}', f'6@{A1}', 'x')], ()),
+    'empty': (64, 0, [], ()),
+    # a typing run's start repeated: every op names the same old element
+    'all_one_old_id': (64, 16, [
+        ins(f'3@{A1}', f'{6 + i}@{A1}', chr(ord('p') + i))
+        for i in range(8)] + [_set(f'3@{A1}', f'14@{A1}', 'B')], ()),
+}
+
+
+def _plain_lookup(elem, ref):
+    """The least node of its row that holds each ref, or the row's length."""
+    return np.array([[min(np.flatnonzero(row == r), default=len(row))
+                      for r in refs] for row, refs in zip(elem, ref)],
+                    dtype=np.int32).reshape(np.shape(ref))
+
+
+class TestReferentLookup:
+    ACTORS = [A1, A2]
+
+    @pytest.mark.parametrize('name', sorted(LOOKUP_CASES))
+    def test_batch_against_the_host(self, name):
+        from automerge_tpu.fleet.sequence import _referent_lookup
+        capacity, width, batch, dropped = LOOKUP_CASES[name]
+        enc = SeqEncoder(self.ACTORS)
+        base, _ = apply_seq_batch(
+            SeqState.empty(2, capacity),
+            enc.batch(list(SAME_IDS_OLD), pad_to=16))
+        elem = np.asarray(base.elem_id)
+        assert sorted(elem[0, 3:7]) == sorted(elem[1, 3:7])
+        assert (elem[0, 3:7] != elem[1, 3:7]).all()
+
+        cols = enc.batch([batch, batch], pad_to=width)
+        np.testing.assert_array_equal(
+            np.asarray(_referent_lookup(base.elem_id, cols.ref)),
+            _plain_lookup(elem, cols.ref))
+
+        state, applied = apply_seq_batch(base, cols)
+        kept = [op for i, op in enumerate(batch) if i not in dropped]
+        assert int(applied) == 2 * len(kept)
+        assert visible_text(state) == [
+            host_text(old + kept, self.ACTORS) for old in SAME_IDS_OLD]
+        assert np.asarray(state.inexact).tolist() == [bool(dropped)] * 2
+        inserts = 4 + sum(op['kind'] == 'insert' for op in kept)
+        assert np.asarray(state.n).tolist() == [inserts] * 2
+
+
+@pytest.mark.parametrize('capacity', [1, 5, 2048, 4196, 6144])
+def test_lookup_in_blocks(capacity):
+    """The lookup over rows shorter than a block, of whole blocks and with
+    a last block of its own length, against a plain search; and where a row
+    is longer than a block the compiled lookup has no array of
+    [rows, width, nodes]: what a backend lays out that does not fuse the
+    compare into the min (this one) is a block wide."""
+    import jax
+    from automerge_tpu.fleet.sequence import LOOKUP_BLOCK, _referent_lookup
+    rows, width, nodes = 3, 5, capacity + 3
+    rng = np.random.default_rng(capacity)
+    elem = np.zeros((rows, nodes), dtype=np.int32)
+    used = capacity - capacity // 4
+    for row in elem:
+        row[3:3 + used] = rng.permutation(3 * capacity)[:used] + 1
+    ref = elem[:, rng.integers(0, nodes, size=width)]
+    ref[0, 0], ref[1, 1], ref[2, 2] = 0, 3 * capacity + 9, elem[2, nodes - 1]
+    lookup = jax.jit(_referent_lookup)
+    np.testing.assert_array_equal(np.asarray(lookup(elem, ref)),
+                                  _plain_lookup(elem, ref))
+    if nodes > LOOKUP_BLOCK:
+        text = lookup.lower(elem, ref).compile().as_text()
+        assert f'[{rows},{width},{LOOKUP_BLOCK}]' in text
+        assert f'[{rows},{width},{nodes}]' not in text
+
+
+def _compiled_text(rows, capacity, width, lanes=4):
+    """The optimized program of one dispatch at these shapes, as text."""
     import jax
     import jax.numpy as jnp
     from automerge_tpu.fleet import sequence
-    rows, capacity, width, lanes = 8, 256, 16, 4
     nodes = capacity + 3
 
     def spec(shape, dtype=jnp.int32):
@@ -593,17 +691,95 @@ def test_scan_body_copies_no_node_array():
         spec((rows, width)),
         spec((rows, width, sequence.SEQ_PRED_LANES)),
         spec((rows, width), jnp.bool_))
-    text = sequence.apply_seq_batch_donated.__wrapped__ \
+    return sequence.apply_seq_batch_donated.__wrapped__ \
         .lower(state, ops).compile().as_text()
 
-    node_copy = re.compile(
-        r'= s32\[%d,%d\](\{[^}]*\})? copy\(' % (rows, nodes))
-    entry, found, bodies = False, [], 0
+
+def _computations(text):
+    """{name: (is the entry, [instruction lines])} of a program's text."""
+    import re
+    out, name = {}, None
     for line in text.splitlines():
         if line and not line[0].isspace() and line.rstrip().endswith('{'):
-            entry = line.startswith('ENTRY')
-            bodies += not entry
-        elif not entry and node_copy.search(line):
-            found.append(line.strip())
-    assert bodies, 'no computation but the entry: the text was not parsed'
+            name = re.match(r'(?:ENTRY )?%?([\w.\-]+)', line).group(1)
+            out[name] = (line.startswith('ENTRY'), [])
+        elif name and ' = ' in line:
+            out[name][1].append(line.strip())
+    return out
+
+
+def test_scan_body_copies_no_node_array():
+    """The compiled program may not copy an array of elem_id's shape
+    anywhere but in its entry computation: a scan step that writes an
+    array which the skip walk's `while` holds is given a whole-array copy
+    of it every step (two 134 MB copies a step were 54 % of the device
+    time of the text cell before the splices were deferred)."""
+    import re
+    rows, capacity, width = 8, 256, 16
+    comps = _computations(_compiled_text(rows, capacity, width))
+    node_copy = re.compile(
+        r'= s32\[%d,%d\](\{[^}]*\})? copy\(' % (rows, capacity + 3))
+    found = [line for entry, lines in comps.values() if not entry
+             for line in lines if node_copy.search(line)]
+    assert len(comps) > 1, \
+        'no computation but the entry: the text was not parsed'
     assert not found, found
+
+
+@pytest.mark.parametrize('rows,capacity,width', [(8, 256, 16),
+                                                 (4, 512, 512)])
+def test_scan_body_searches_no_node_array(rows, capacity, width):
+    """Only the lookup ahead of the scan may compare or reduce an array of
+    elem_id's shape: a scan step that searched the row for its op's
+    referent read 134 MB a step in the text cell, 39 % of its device time,
+    for what one pass over the row finds for the whole batch. And that pass
+    may not lay the row out once an op cell: no instruction outside a
+    fusion yields [rows, width, nodes] (8.6 GB at the cell's shapes)."""
+    import re
+    nodes = capacity + 3
+    comps = _computations(_compiled_text(rows, capacity, width))
+    called = re.compile(
+        r'(?:calls|to_apply|body|condition)=%?([\w.\-]+)')
+    # name, dimensions, opcode and operands of an instruction that yields
+    # an array
+    instr = re.compile(r'(?:ROOT )?%?([\w.\-]+) = \w+\[([0-9,]*)\]'
+                       r'(?:\{[^}]*\})? ([\w\-]+)\(([^)]*)\)')
+    parsed = {name: [(m, line) for line in lines
+                     for m in [instr.match(line)] if m]
+              for name, (_entry, lines) in comps.items()}
+
+    # the scan's side: every computation reached from a `while` that is not
+    # the lookup's own
+    todo = [name for _entry, lines in comps.values() for line in lines
+            if ' while(' in line and 'seq.referent_lookup' not in line
+            for name in called.findall(line)]
+    assert todo, 'no scan found: the text was not parsed'
+    scan_side = set()
+    while todo:
+        name = todo.pop()
+        if name in scan_side or name not in comps:
+            continue
+        scan_side.add(name)
+        todo += [c for line in comps[name][1] for c in called.findall(line)]
+
+    row_shape = f'{rows},{nodes}'
+    searches, lookups = [], 0
+    for name, found in parsed.items():
+        dims = {m.group(1): m.group(2) for m, _line in found}
+        for m, line in found:
+            if m.group(3) not in ('compare', 'reduce', 'reduce-window'):
+                continue
+            lookups += 'seq.referent_lookup' in line
+            operands = re.findall(r'%([\w.\-]+)', m.group(4))
+            if name in scan_side and row_shape in (
+                    m.group(2), *(dims.get(o) for o in operands)):
+                searches.append(f'{name}: {line[:160]}')
+    assert not searches, searches
+    assert lookups, 'the lookup ahead of the scan was not found'
+
+    fused = {c for _entry, lines in comps.values() for line in lines
+             if ' fusion(' in line for c in called.findall(line)}
+    laid_out = [f'{name}: {line[:160]}' for name, found in parsed.items()
+                if name not in fused for m, line in found
+                if m.group(2) == f'{rows},{width},{nodes}']
+    assert not laid_out, laid_out
