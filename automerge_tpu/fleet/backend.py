@@ -913,10 +913,14 @@ class DocFleet:
         # An insert or a set takes a lane on its element: note who wrote
         # each row, so that its pool has a lane for every writer
         writes = (arr[:, 1] == INSERT) | (arr[:, 1] == SET)
-        for pair in np.unique((row_a[writes] << ACTOR_BITS) |
-                              (arr[writes, 3] & ACTOR_MASK)).tolist():
+        row_actor = (row_a << ACTOR_BITS) | (arr[:, 3] & ACTOR_MASK)
+        for pair in np.unique(row_actor[writes]).tolist():
             self.seq_writers[pair >> ACTOR_BITS].add(
                 self.actors.actors[pair & ACTOR_MASK])
+        # rows whose op list holds more than one actor (concurrent
+        # writers' branches in one batch: the skip walk has work to do)
+        multi = np.bincount(np.unique(row_actor) >> ACTOR_BITS,
+                            minlength=n_rows) > 1
         # Placement pass: host-tracked element counts give each row's
         # needed capacity class without any device reads.
         pools = self.seq_pools
@@ -954,18 +958,19 @@ class DocFleet:
                 cols[name][rows_idx, pos] = arr[sub, j + 1]
             preds[rows_idx, pos] = arr[sub, 5:5 + D]
             flag[rows_idx, pos] = arr[sub, 5 + D] != 0
-            batches.append((cls, len(sub), SeqOpBatch(
+            batches.append((cls, len(sub), int(multi[rows].sum()), SeqOpBatch(
                 cols['kind'], cols['ref'], cols['packed'], cols['value'],
                 preds, flag)))
-        for cls, n_ops, batch in batches:
+        for cls, n_ops, n_multi, batch in batches:
             r_cap, width = batch.kind.shape
             ps.mark('seq.enqueue', cls=cls, rows=r_cap, width=width,
-                    ops=n_ops)
+                    ops=n_ops, multiwriter_rows=n_multi)
             pools.pools[cls], _stats = apply_seq_batch_donated(
                 pools.state(cls), batch)
             self.metrics.dispatches += 1
             self.metrics.seq_op_cells += r_cap * width
         ps.done()
+        self.metrics.seq_multiwriter_rows += int(multi.sum())
         self.metrics.seq_ops += len(seq_ops)
         self.metrics.device_ops += len(seq_ops)
         self._note_seq_pools()
@@ -4005,16 +4010,20 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     unattributed gap between marks — the coverage contract
     `seam.untraced_ms_per_step` reads), with the native parse / device
     dispatch sub-spans nested inside. `turbo_gate` and `turbo_commit`
-    are tiled in turn by a second sequence: `gate.chain` / `gate.shape`
-    / `gate.dag` / `gate.decode` / `gate.general` (per document that
+    are tiled in turn by a second sequence: `gate.chain` / `gate.dag`
+    / `gate.shape` / `gate.decode` / `gate.general` (per document that
     reaches it a `gate.meta` and a `gate.drain` span) / `gate.validate`,
     and `commit.columnar` / `commit.staged` / `commit.handles` — named
     without the `turbo_` prefix, so readers that sum `turbo_*` count
     each millisecond once. `turbo_gate` carries why documents left the
     chain path (`offchain_native` / `offchain_heads` / `offchain_seq`)
-    and how many of them the DAG gate took back (`offchain_dag`), all
-    also in `fleet.metrics`; the three reasons less `offchain_dag` is
-    what reached `gate.general`."""
+    and how many of them the DAG gate took back (`offchain_dag`; of
+    those, `dag_seq_docs` hold sequence ops), all also in
+    `fleet.metrics`; the three reasons less `offchain_dag` is what
+    reached `gate.general`. The DAG gate's verdict is known before the
+    shape check asks: a call that holds sequence, make or nested ops
+    leaves for the exact path, whole, only for a document that is
+    neither on the chain nor DAG-ordered."""
     ps = _span_seq()
     sub = _span_seq()   # the sub-phases of turbo_gate, then turbo_commit
     ps.mark('turbo_setup', docs=len(handles))
@@ -4167,6 +4176,38 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         offchain_native - offchain_heads
     ps.note(offchain_native=offchain_native, offchain_heads=offchain_heads,
             offchain_seq=offchain_seq)
+    sub.mark('gate.dag')
+    # ---- DAG gate: the documents the chain check refused whose batch is
+    # still causally ORDERED (concurrent branches in one buffer, a merge
+    # change naming two heads) — every dependency an earlier change of
+    # the batch or a current head, no hash twice, seq runs extending the
+    # clock (seq_ok, from the groups above). _causal_gate would apply all
+    # of such a document in buffer order and leave no queue, so it joins
+    # the columnar commit with the frontier the kernel computed; what the
+    # kernel refuses goes to the general gate untouched.
+    chain_mask = fast_mask
+    offchain_dag = dag_seq_docs = 0
+    cand = ~chain_mask & has_changes & seq_ok
+    if cand.any():
+        multi_heads = {}
+        for d in np.flatnonzero(cand & (head_n_d == -1)).tolist():
+            heads = engines[d].heads
+            try:
+                blob = bytes.fromhex(''.join(heads))
+            except (TypeError, ValueError):
+                blob = b''
+            if len(blob) == 32 * len(heads):
+                multi_heads[d] = blob
+            else:
+                cand[d] = False   # heads that are no hashes: general gate
+        dag = native.dag_gate(doc_off, hash32, nmeta['deps_off'],
+                              nmeta['deps_blob'], head32_d, head_n_d,
+                              multi_heads, cand)
+        if dag is not None:
+            dag_ok, dag_nh_off, dag_nh = dag
+            offchain_dag = int(dag_ok.sum())
+            fast_mask = chain_mask | dag_ok
+    ps.note(offchain_dag=offchain_dag)
     sub.mark('gate.shape')
 
     flags_all = rows['flags']
@@ -4176,11 +4217,19 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     nested_sel = (flags_all <= 2) & (rows['obj'] != 0)
     if seq_sel.any() or make_sel.any() or nested_sel.any() or \
             seq_make_sel.any():
-        # RGA application is order-sensitive: if any doc needs the general
-        # causal gate (whose applied order can differ from buffer order),
-        # route the whole call to the exact path
+        # RGA application is order-sensitive, and buffer order is all it
+        # needs: a document on the chain or DAG-ordered (concurrent
+        # writers' branches, a merge change) applies in buffer order and
+        # keeps its sequence ops here. A document NEITHER gate took needs
+        # the general causal gate, whose applied order can differ from
+        # buffer order: that routes the whole call to the exact path
         if (~fast_mask[doc_of]).any():
             return None
+        if offchain_dag:
+            # documents the DAG gate committed that hold sequence ops
+            dag_seq_docs = int(dag_ok[np.unique(change_doc[rows['doc'][
+                seq_sel | seq_make_sel]])].sum())
+            ps.note(dag_seq_docs=dag_seq_docs)
         # Every op's containing object must resolve to a registered object
         # or a make earlier in this batch; dangling objects get exact-path
         # error handling. Seq ops must target seq objects, keyed ops map
@@ -4224,38 +4273,6 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
             if oid not in made_map[d] and \
                     oid not in engines[d].map_objects:
                 return None
-    sub.mark('gate.dag')
-    # ---- DAG gate: the documents the chain check refused whose batch is
-    # still causally ORDERED (concurrent branches in one buffer, a merge
-    # change naming two heads) — every dependency an earlier change of
-    # the batch or a current head, no hash twice, seq runs extending the
-    # clock (seq_ok, from the groups above). _causal_gate would apply all
-    # of such a document in buffer order and leave no queue, so it joins
-    # the columnar commit with the frontier the kernel computed; what the
-    # kernel refuses goes to the general gate untouched.
-    chain_mask = fast_mask
-    offchain_dag = 0
-    cand = ~chain_mask & has_changes & seq_ok
-    if cand.any():
-        multi_heads = {}
-        for d in np.flatnonzero(cand & (head_n_d == -1)).tolist():
-            heads = engines[d].heads
-            try:
-                blob = bytes.fromhex(''.join(heads))
-            except (TypeError, ValueError):
-                blob = b''
-            if len(blob) == 32 * len(heads):
-                multi_heads[d] = blob
-            else:
-                cand[d] = False   # heads that are no hashes: general gate
-        dag = native.dag_gate(doc_off, hash32, nmeta['deps_off'],
-                              nmeta['deps_blob'], head32_d, head_n_d,
-                              multi_heads, cand)
-        if dag is not None:
-            dag_ok, dag_nh_off, dag_nh = dag
-            offchain_dag = int(dag_ok.sum())
-            fast_mask = chain_mask | dag_ok
-    ps.note(offchain_dag=offchain_dag)
     sub.mark('gate.decode')
     # Decode every arena-boxed payload BEFORE the commit point: a payload
     # decode_value rejects (out-of-range leb, invalid UTF-8, bad float
@@ -4315,6 +4332,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     fleet.metrics.offchain_heads += offchain_heads
     fleet.metrics.offchain_seq += offchain_seq
     fleet.metrics.offchain_dag += offchain_dag
+    fleet.metrics.dag_seq_docs += dag_seq_docs
 
     # Phase 1 — fallible: general causal gate for docs that are neither
     # one chain nor DAG-ordered.

@@ -9,8 +9,10 @@ phase, and what happened around it. Four layers, one package:
 
 - **Counters & roll-ups** (metrics.py): per-fleet monotonic `Metrics`
   (among them why a document left the turbo chain path:
-  `offchain_native` / `offchain_heads` / `offchain_seq`, and how many
-  of those the native DAG gate took back, `offchain_dag`),
+  `offchain_native` / `offchain_heads` / `offchain_seq`, how many of
+  those the native DAG gate took back, `offchain_dag`, and how many of
+  THOSE hold sequence ops, `dag_seq_docs`; `seq_multiwriter_rows`, the
+  rows of a sequence dispatch whose op list holds two actors or more),
   `register_dispatch_source`/`dispatch_counts` and
   `register_health_source`/`health_counts` system-wide roll-ups, and
   `trace`, the operator's one entry to a profiler capture: it turns the

@@ -108,11 +108,17 @@ class Metrics:
                                  # (causally ordered, just not one chain);
                                  # the three reasons summed, less this, is
                                  # what reached the general gate
+        'dag_seq_docs',          # of offchain_dag, the documents that
+                                 # hold sequence ops: concurrent writers
+                                 # on a Text or list, applied on the device
+                                 # in buffer order
         # the sequence engine (fleet/backend.py _dispatch_seq)
         'seq_ops',               # real sequence ops dispatched
         'seq_op_cells',          # rows x width of the op columns handed to
                                  # the device; less seq_ops, it is padding
         'seq_migrations',        # rows moved up a size class
+        'seq_multiwriter_rows',  # rows of a dispatch whose op list holds
+                                 # more than one actor
         'seq_inexact_reads',     # rows a bulk render found flagged
                                  # inexact and left to the host mirror
         # gauges, not counters: what the pools hold after the last

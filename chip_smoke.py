@@ -187,8 +187,9 @@ def text_trace(rng, n_ops, n_actors, ops_per_change, concurrent):
     change depends on exactly the one before it — the linear chains the
     turbo seam takes. Concurrent, every actor extends the SAME heads in
     each round, so the RGA has same-position inserts, equal counters
-    under different actors and double deletes to resolve; the seam
-    routes such a batch through its exact path."""
+    under different actors and double deletes to resolve; such a batch
+    is causally ordered in buffer order, so the seam's DAG gate keeps it
+    on the turbo path."""
     from automerge_tpu.columnar import decode_change_meta, encode_change
     # every document has actor ids of its own, as every device has: shared
     # ids would hide what a fleet's actor table costs the sequence pools
@@ -357,12 +358,16 @@ def leg_text(size, seed):
     check(m['turbo_calls'] >= 1 and m['fallbacks'] == 0,
           f'serialized text left the turbo path: {m}')
     # concurrent traces: same-position inserts and actor tie-breaks on
-    # the device RGA. Which path the seam takes is recorded, not asserted
+    # the device RGA. Their batches are causally ordered in buffer order,
+    # so the DAG gate keeps them on the turbo path: asserted
     conc = [text_trace(rng, size['ops'], size['actors'],
                        size['ops_per_change'], concurrent=True)
             for _ in range(size['concurrent_docs'])]
     (cfleet, chandles), conc_s = timed(lambda: apply(conc))
     cm, cchars = audit('concurrent', cfleet, chandles, conc)
+    check(cm['turbo_calls'] >= 1 and cm['exact_calls'] == 0 and
+          cm['fallbacks'] == 0,
+          f'concurrent text left the turbo path: {cm}')
     return {'first_s': first_s, 'warm_s': warm_s, 'docs': len(per_doc),
             'ops_per_doc': size['ops'] + 1, 'visible_chars': chars,
             'device_ops': m['device_ops'], 'dispatches': m['dispatches'],

@@ -216,7 +216,7 @@ def out_of_order_log(doc, n=3):
     return log
 
 
-GATE = ['gate.chain', 'gate.shape', 'gate.dag', 'gate.decode',
+GATE = ['gate.chain', 'gate.dag', 'gate.shape', 'gate.decode',
         'gate.general', 'gate.validate']
 COMMIT = ['commit.columnar', 'commit.staged', 'commit.handles']
 
