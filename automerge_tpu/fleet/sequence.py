@@ -262,33 +262,63 @@ class SeqOpBatch:
 register_pytrees(SeqState, SeqOpBatch)
 
 
+def _pick(at, row):
+    """The element of `row` where the mask `at` holds; `at` holds in one
+    place at most, and where in none this reads 0."""
+    return jnp.sum(jnp.where(at, row, 0))
+
+
 def _apply_one_doc(carry, op, elem_id, nxt, n0):
     """One op against one doc. `elem_id`, `nxt` and `n0` are the row as the
     dispatch found it, read and never written here; the batch's splices
     live in the carry's overlay (see _apply_seq_batch_impl).
     carry = (ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n,
     inexact); the op's `in_row` is its referent's node in the row as the
-    dispatch found it, or `nodes` (_referent_lookup)."""
+    dispatch found it, or `nodes` (_referent_lookup).
+
+    What is indexed and what is selected. The step reads or writes ONE
+    element of many arrays at a computed place. Of the four lane arrays
+    (`reg`, `killed`, `val`, `counter`: A * nodes a row, up to a million
+    elements) it gathers the target's A lanes once and scatters them back
+    once, and `elem_id` and `nxt` it only gathers from: those are as long
+    as the document, and an index is the only way to touch one element of
+    them without reading the rest. The SMALL arrays — the overlay, as wide
+    as the batch, and the target's register row, A wide — are not indexed
+    at all: a write is `where(iota == at, new, old)`, a read a sum over the
+    same compare (`_pick`). On the chip a scatter in a scan step is a
+    program of its own, 7 to 14 us for 128 rows however little it moves,
+    and a gather 1 to 2: the ten scatters and some fourteen gathers that
+    these arrays took were half of the step, 0.22 ms, and without them it
+    is 0.12 (PERF.md section 6, PR 36). Compares and selects over
+    [rows, width] and [rows, A] fuse with their neighbours into a few
+    passes, and cost less than the scatters at every width read, 4 to
+    16,384: there is one form and no threshold. The masked forms keep
+    their meaning: a place of `width` (a write that is masked) or a
+    negative one (a read that the outer `where` throws away) matches no
+    entry."""
     ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n, \
         inexact = carry
     kind, ref, in_row, packed, value, preds, flag = op
     # lane l of node i is at l * nodes + i of the lane arrays
     nodes = elem_id.shape[0]
     capacity = nodes - 3
-    lane_offsets = nodes * np.arange(reg.shape[0] // nodes, dtype=np.int32)
+    lanes = np.arange(reg.shape[0] // nodes, dtype=np.int32)
     new0 = SLOT0 + n0       # the first node this batch allocates
 
     width = ov_id.shape[0]
     entries = np.arange(width, dtype=np.int32)
 
     def id_at(j):
-        return jnp.where(j >= new0, ov_id[j - new0], elem_id[j])
+        return jnp.where(j >= new0, _pick(entries == j - new0, ov_id),
+                         elem_id[j])
 
     def nxt_at(j):
         """(the node after j, the entry of j's pair or -1)"""
-        e = jnp.max(jnp.where(rp_node == j, entries, -1))
-        old = jnp.where(e >= 0, rp_nxt[e], nxt[j])
-        return jnp.where(j >= new0, ov_nxt[j - new0], old), e
+        pair = rp_node == j         # at most one: an old node has one entry
+        e = jnp.max(jnp.where(pair, entries, -1))
+        old = jnp.where(e >= 0, _pick(pair, rp_nxt), nxt[j])
+        return jnp.where(j >= new0, _pick(entries == j - new0, ov_nxt),
+                         old), e
 
     is_ins = kind == INSERT
     is_upd = (kind == SET) | (kind == DEL)
@@ -343,17 +373,16 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     # the node before it is a slot of this batch, or an old node whose pair
     # is rewritten if the batch has repointed it before and else takes
     # entry k (one entry a node: the write-back needs no order). A write
-    # that is masked goes past the overlay's end and is dropped.
+    # that is masked is placed past the overlay's end, where no entry is.
     k = n - n0
-    at_k = jnp.where(can_ins, k, width)
-    at_r = jnp.where(can_ins & (r >= new0), r - new0, width)
-    at_e = jnp.where(can_ins & (r < new0),
-                     jnp.where(e_r >= 0, e_r, k), width)
-    ov_id = ov_id.at[at_k].set(packed, mode='drop')
-    ov_nxt = ov_nxt.at[jnp.stack([at_k, at_r])].set(
-        jnp.stack([j, slot]), mode='drop')
-    rp_node = rp_node.at[at_e].set(r, mode='drop')
-    rp_nxt = rp_nxt.at[at_e].set(slot, mode='drop')
+    at_k = entries == jnp.where(can_ins, k, width)
+    at_r = entries == jnp.where(can_ins & (r >= new0), r - new0, width)
+    at_e = entries == jnp.where(can_ins & (r < new0),
+                                jnp.where(e_r >= 0, e_r, k), width)
+    ov_id = jnp.where(at_k, packed, ov_id)
+    ov_nxt = jnp.where(at_k, j, jnp.where(at_r, slot, ov_nxt))
+    rp_node = jnp.where(at_e, r, rp_node)
+    rp_nxt = jnp.where(at_e, slot, rp_nxt)
     n = n + can_ins.astype(jnp.int32)
 
     # ---- SET / DEL / INC: exact multi-value register update -------------
@@ -365,7 +394,7 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     # and its lane 0 is written with the row, further down)
     tgt = jnp.where(can_ins, slot,
                     jnp.where(upd_ok | inc_ok, match, jnp.int32(SCRATCH)))
-    lanes_at = tgt + lane_offsets     # the target's A lanes
+    lanes_at = tgt + nodes * lanes    # the target's A lanes
     reg_row = reg[lanes_at]
     killed_row = killed[lanes_at]
     val_row = val[lanes_at]
@@ -393,6 +422,7 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     max_hit = (max_pred != 0) & (reg_row == max_pred) & ~killed_row
     max_live = inc_ok & jnp.any(max_hit)
     s_max = jnp.argmax(max_hit).astype(jnp.int32)
+    at_s = lanes == s_max
     # (sum << 2) | count-bits packing (bits 0 -> 1 -> 3, 3 = "two or
     # more", saturating) — see the SeqState docstring. The shifted add
     # leaves the count bits alone. The ingest-side guards bound each
@@ -400,13 +430,12 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     # packed envelope (two +2^28 incs): flag the row inexact when it
     # does, mirroring the bulk loader's counter_over rule, so live-applied
     # and bulk-loaded replicas agree instead of wrapping silently.
-    old_cnt = counter_row[s_max]
+    old_cnt = _pick(at_s, counter_row)
     new_sum = (old_cnt >> 2) + value
     bad_sum = max_live & (jnp.abs(new_sum) >= jnp.int32(1 << 29))
     stepped = (old_cnt & ~3) + (value << 2)
     stepped = stepped | jnp.where((old_cnt & 3) == 0, 1, 3)
-    counter_row = counter_row.at[s_max].set(
-        jnp.where(max_live, stepped, old_cnt))
+    counter_row = jnp.where(at_s & max_live, stepped, counter_row)
     killed_row = killed_row | (inc_ok & held & (reg_row != max_pred))
     bad_inc = inc_ok & ~any_live_hit & ~max_live
 
@@ -423,39 +452,41 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     a_ok = jnp.any(mine | empty)
     a_c = jnp.where(jnp.any(mine), jnp.argmax(mine),
                     jnp.argmax(empty)).astype(jnp.int32)
-    own_prev = reg_row[a_c]
+    at_c = lanes == a_c
+    own_prev = _pick(at_c, reg_row)
     own_pred = jnp.any(preds == own_prev)
     self_conflict = is_set_live & a_ok & (own_prev != 0) & \
-        ~killed_row[a_c] & ~own_pred & (own_prev != packed)
+        ~jnp.any(at_c & killed_row) & ~own_pred & (own_prev != packed)
     no_lane = is_set_live & ~a_ok
 
     w_set = is_set_live & a_ok
     # Reclaiming a lane whose previous op consumed incs loses the dead
     # counter's phantom-remove patch trace (the reference's dangling inc
     # rows still emit edits for it): flag the row inexact instead
-    reclaim_incd = w_set & ((counter_row[a_c] & 3) != 0)
-    reg_row = reg_row.at[a_c].set(jnp.where(w_set, packed, reg_row[a_c]))
-    killed_row = killed_row.at[a_c].set(
-        jnp.where(w_set, False, killed_row[a_c]))
-    val_row = val_row.at[a_c].set(jnp.where(w_set, value, val_row[a_c]))
-    counter_row = counter_row.at[a_c].set(
-        jnp.where(w_set, 0, counter_row[a_c]))
+    reclaim_incd = w_set & ((_pick(at_c, counter_row) & 3) != 0)
+    reg_row = jnp.where(at_c & w_set, packed, reg_row)
+    killed_row = killed_row & ~(at_c & w_set)
+    val_row = jnp.where(at_c & w_set, value, val_row)
+    counter_row = jnp.where(at_c & w_set, 0, counter_row)
 
     # An insert takes a fresh slot, whose lanes are all empty: the element's
     # first op (the insert IS its first set op) goes in lane 0. Only
-    # `killed` can change in more than one lane of the row (the preds); of
-    # the others one element is written, the set's or insert's lane or the
-    # inc's: a scatter takes the device as long as it has elements.
+    # `killed` can change in more than one lane of the row (the preds), so
+    # its A lanes are scattered; of the others one element is written, the
+    # set's or insert's lane or the inc's, picked out of the row by a
+    # select and placed by arithmetic (lane l of the target is at
+    # tgt + l * nodes): a scatter into a lane array takes the device as
+    # long as it has elements, and each array has one in a step.
     w_lane = jnp.where(can_ins, 0, a_c)
     c_lane = jnp.where(is_inc, s_max, w_lane)
-    killed_row = killed_row.at[w_lane].set(
-        jnp.where(can_ins, False, killed_row[w_lane]))
-    reg = reg.at[lanes_at[w_lane]].set(
-        jnp.where(can_ins, packed, reg_row[w_lane]))
-    val = val.at[lanes_at[w_lane]].set(
-        jnp.where(can_ins, value, val_row[w_lane]))
-    counter = counter.at[lanes_at[c_lane]].set(
-        jnp.where(can_ins, 0, counter_row[c_lane]))
+    at_w = lanes == w_lane
+    killed_row = killed_row & ~(at_w & can_ins)
+    reg = reg.at[tgt + nodes * w_lane].set(
+        jnp.where(can_ins, packed, _pick(at_w, reg_row)))
+    val = val.at[tgt + nodes * w_lane].set(
+        jnp.where(can_ins, value, _pick(at_w, val_row)))
+    counter = counter.at[tgt + nodes * c_lane].set(
+        jnp.where(can_ins, 0, _pick(lanes == c_lane, counter_row)))
     killed = killed.at[lanes_at].set(killed_row)
 
     # Dropped ops (over-capacity or unknown-referent inserts, SET/DELs on

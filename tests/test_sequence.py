@@ -4,6 +4,7 @@ plus differential fuzzing against the full host engine (public API with
 multi-actor Text editing and merge) — the wasm.js-style cross-implementation
 harness, with the host OpSet as the oracle."""
 
+import functools
 import random
 
 import numpy as np
@@ -567,6 +568,245 @@ class TestDeferredSplice:
         assert (elem[0, 3 + inserts:] == 0).all()
 
 
+# ---- the scan step against a plain one: the step reads and writes its small
+# arrays (the overlay, a node's register row) by compare and select, and a
+# masked read or write is one whose place matches no entry; the plain step
+# below indexes the row's own arrays, one op at a time, and has no overlay --
+
+def _plain_apply(state, ops):
+    """The host's oracle for a dispatch's STATE: every op of every row in
+    order, by plain indexing of numpy arrays; returns the eight arrays in
+    SeqState's order, the applied flags [rows, width] and nothing else.
+    Written from the rules (sequence.py's module docstring, new.js), not
+    from the kernel: splices go to `elem_id` and `nxt` at once."""
+    from automerge_tpu.fleet.sequence import (
+        ACTOR_MASK, END, HEAD, INC, SCRATCH, SLOT0)
+    elem, nxt, reg, killed, val, cnt, n, inexact = [
+        np.array(a) for a in state.tree_flatten()[0]]
+    rows, nodes = elem.shape
+    capacity, lanes = nodes - 3, reg.shape[1] // nodes
+    kinds, refs, ids, values, pred_lists, flags = [
+        np.asarray(a) for a in ops.tree_flatten()[0]]
+    applied = np.zeros(kinds.shape, dtype=bool)
+    for d in range(rows):
+        for p in range(kinds.shape[1]):
+            kind, ref, op_id = kinds[d, p], int(refs[d, p]), int(ids[d, p])
+            value = int(values[d, p])
+            preds = [int(x) for x in pred_lists[d, p]]
+            inexact[d] |= flags[d, p]
+            if kind == PAD:
+                continue
+            where = np.flatnonzero(elem[d] == ref) if ref else []
+            if kind == INSERT:
+                ok = n[d] < capacity and (ref == 0 or len(where))
+                if ok:
+                    r = int(where[0]) if ref else HEAD
+                    j = int(nxt[d, r])
+                    while elem[d, j] > op_id:
+                        r, j = j, int(nxt[d, j])
+                    slot = SLOT0 + int(n[d])
+                    elem[d, slot], nxt[d, slot], nxt[d, r] = op_id, j, slot
+                    reg[d, slot], val[d, slot] = op_id, value    # lane 0
+                    killed[d, slot], cnt[d, slot] = False, 0
+                    n[d] += 1
+            else:
+                ok = bool(ref and len(where))
+            applied[d, p] = ok
+            inexact[d] |= not ok
+            if not ok or kind == INSERT:
+                continue
+            at = int(where[0]) + nodes * np.arange(lanes)   # the A lanes
+            named = [x for x in preds if x > 0]
+            held = np.array([reg[d, i] in named for i in at])
+            bad = any(x < 0 for x in preds)
+            if kind == INC:
+                top = max(named, default=0)
+                hit = [i for i in at
+                       if top and reg[d, i] == top and not killed[d, i]]
+                bad |= not hit and not (held & ~killed[d, at]).any()
+                if hit:
+                    old = int(cnt[d, hit[0]])
+                    bad |= abs((old >> 2) + value) >= 1 << 29
+                    stepped = ((old & ~3) + (value << 2)) | \
+                        (3 if old & 3 else 1)
+                    # (flagged above when it leaves the envelope, and
+                    # then wraps as the device's int32 does)
+                    cnt[d, hit[0]] = (stepped + (1 << 31)) % (1 << 32) \
+                        - (1 << 31)
+                killed[d, at] |= held & (reg[d, at] != top)
+                inexact[d] |= bad
+                continue
+            killed[d, at] |= held
+            if kind == SET:
+                mine = [i for i in at if reg[d, i] and
+                        (reg[d, i] & ACTOR_MASK) == (op_id & ACTOR_MASK)]
+                free = mine or [i for i in at if reg[d, i] == 0]
+                if not free:
+                    bad = True      # more writers than lanes
+                else:
+                    i, prev = free[0], int(reg[d, free[0]])
+                    bad |= bool(prev) and not killed[d, i] and \
+                        prev not in preds and prev != op_id
+                    bad |= (cnt[d, i] & 3) != 0
+                    reg[d, i], val[d, i] = op_id, value
+                    killed[d, i], cnt[d, i] = False, 0
+            inexact[d] |= bad
+    assert (elem[:, [HEAD, END, SCRATCH]] == 0).all()
+    return [elem, nxt, reg, killed, val, cnt, n, inexact], applied
+
+
+def _inc(target, op_id, delta, pred):
+    return {'kind': 'inc', 'ref': target, 'id': op_id, 'value': delta,
+            'pred': pred}
+
+
+def _set_p(target, op_id, ch, pred):
+    return dict(_set(target, op_id, ch), pred=pred)
+
+
+# name -> (capacity, width, ops of an earlier dispatch, the batch, what the
+#          is the host's OpSet asked what the row reads after both (not
+#          of counters, nor where the kernel must drop an op))
+STEP_CASES = {
+    'width_1': (64, 1, [ins('_head', f'2@{A1}', 'a')],
+                [ins(f'2@{A1}', f'3@{A1}', 'b')], True),
+    # eight inserts in a batch eight wide: entry 7, the overlay's last, is
+    # written, read by the walk of the insert after it and written back
+    'overlay_filled_to_its_last_entry': (64, 8, _typed('_head', 2, 2), (
+        _typed(f'2@{A1}', 4, 7) + [ins(f'10@{A1}', f'11@{A2}', 'z')]), True),
+    # the row fills at the batch's third insert: the others' writes are
+    # placed past the overlay's end, and so is the DEL's of a dropped one
+    'insert_over_capacity': (4, 8, _typed('_head', 2, 1), (
+        _typed(f'2@{A1}', 3, 6) + [_del(f'7@{A1}', f'9@{A1}'),
+                                   _del(f'4@{A1}', f'10@{A1}')]), False),
+    'insert_after_unknown_referent': (64, 8, _typed('_head', 2, 2), [
+        ins(f'77@{A2}', f'4@{A1}', '?'), ins(f'3@{A1}', f'5@{A1}', 'c'),
+        ins(f'4@{A1}', f'6@{A1}', '?'), _set(f'4@{A1}', f'7@{A1}', '?'),
+        ins(f'5@{A1}', f'8@{A1}', 'd')], False),
+    # old node 2@A1 gets a pair at the first insert (entry 0) and has it
+    # rewritten twice (e_r >= 0); old node 3@A1 takes entry 2 meanwhile
+    'same_old_node_repointed_twice': (64, 8, _typed('_head', 2, 3), [
+        ins(f'2@{A1}', f'5@{A1}', 'p'), ins(f'2@{A1}', f'6@{A1}', 'q'),
+        ins(f'3@{A1}', f'7@{A1}', 'r'), ins(f'2@{A1}', f'8@{A1}', 's'),
+        ins(f'4@{A1}', f'9@{A1}', 't')], True),
+    # every insert but the first goes after a slot of this batch (at_r),
+    # the last two after the same one, the later of them walking over
+    # nothing (its id is the greater) and the A2 one over both
+    'insert_after_a_slot_of_this_batch': (64, 8, _typed('_head', 2, 1), [
+        ins(f'2@{A1}', f'3@{A1}', 'b'), ins(f'3@{A1}', f'4@{A1}', 'c'),
+        ins(f'4@{A1}', f'5@{A1}', 'd'), ins(f'3@{A1}', f'6@{A1}', 'e'),
+        ins(f'3@{A1}', f'5@{A2}', 'f'), _del(f'5@{A2}', f'7@{A1}')], True),
+    # lane 0 holds A1's insert, lane 1 A2's set of it: A3's inc of that set
+    # counts in lane 1 (s_max) while A3's own lane would be 2 (a_c); the
+    # second inc finds the count bits at 1; the third names the dead insert
+    # beside the set and a lane that holds neither
+    'inc_lane_differs_from_own_lane': (64, 8, [
+        ins('_head', f'2@{A1}', 'a'),
+        _set_p(f'2@{A1}', f'3@{A2}', 'n', [f'2@{A1}'])], [
+        _inc(f'2@{A1}', f'4@{A3}', 5, [f'3@{A2}']),
+        _inc(f'2@{A1}', f'5@{A3}', -2, [f'3@{A2}']),
+        _inc(f'2@{A1}', f'6@{A1}', 7, [f'2@{A1}', f'3@{A2}'])], False),
+    # an inc whose preds are all dead or unknown, and one past the packed
+    # sum's envelope
+    'inc_with_no_live_target': (64, 8, [
+        ins('_head', f'2@{A1}', 'a'), ins(f'2@{A1}', f'3@{A1}', 'b'),
+        _del(f'2@{A1}', f'4@{A2}')], [
+        _inc(f'2@{A1}', f'5@{A3}', 1, [f'2@{A1}']),
+        _inc(f'3@{A1}', f'6@{A3}', 1 << 28, [f'3@{A1}']),
+        _inc(f'3@{A1}', f'7@{A3}', 1 << 28, [f'3@{A1}'])], False),
+    # A2's set kills A1's insert in lane 0 and takes lane 1; A1's next set
+    # preds A2's and takes lane 0 back, live again; A2 then overwrites its
+    # own dead op in lane 1
+    'set_reclaims_a_lane': (64, 8, [
+        ins('_head', f'2@{A1}', 'a'), ins(f'2@{A1}', f'3@{A1}', 'b')], [
+        _set_p(f'2@{A1}', f'4@{A2}', 'X', [f'2@{A1}']),
+        _set_p(f'2@{A1}', f'5@{A1}', 'Y', [f'4@{A2}']),
+        _set_p(f'2@{A1}', f'6@{A2}', 'Z', [f'5@{A1}']),
+        _set_p(f'3@{A1}', f'7@{A3}', 'W', [f'3@{A1}'])], True),
+    # a lane whose dead op consumed an inc is reclaimed: flagged
+    'set_reclaims_a_counted_lane': (64, 8, [
+        ins('_head', f'2@{A1}', 'a'),
+        _inc(f'2@{A1}', f'3@{A2}', 4, [f'2@{A1}'])], [
+        _set_p(f'2@{A1}', f'4@{A1}', 'b', [f'2@{A1}'])], False),
+}
+
+
+class TestStepAgainstPlainStep:
+    ACTORS = [A1, A2, A3]
+    OTHER = _typed('_head', 2, 3, A2)   # row 1: another cursor, other ids
+
+    def _both(self, state, cols):
+        want, want_applied = _plain_apply(state, cols)
+        got, applied = apply_seq_batch(state, cols)
+        for name, a, b in zip(
+                ('elem_id', 'nxt', 'reg', 'killed', 'val', 'counter', 'n',
+                 'inexact'), got.tree_flatten()[0], want):
+            np.testing.assert_array_equal(np.asarray(a), b, err_msg=name)
+        assert int(applied) == int(want_applied.sum())
+        return got, want_applied
+
+    @pytest.mark.parametrize('lanes', [4, 8])
+    @pytest.mark.parametrize('name', sorted(STEP_CASES))
+    def test_masked_corners(self, name, lanes):
+        capacity, width, old, batch, on_host = STEP_CASES[name]
+        enc = SeqEncoder(self.ACTORS)
+        state = SeqState.empty(2, capacity, actor_slots=lanes)
+        state, _ = self._both(state, enc.batch([old, self.OTHER],
+                                               pad_to=width))
+        assert not np.asarray(state.inexact).any()
+        # row 1 takes the same batch on its own ids' row: what it names is
+        # unknown there or another element, and masked the other way
+        state, applied = self._both(
+            state, enc.batch([batch, batch[:width // 2]], pad_to=width))
+        if on_host:
+            assert applied[0, :len(batch)].all()
+            assert not np.asarray(state.inexact)[0]
+            assert visible_text(state)[0] == host_text(old + batch,
+                                                       self.ACTORS)
+
+    @pytest.mark.parametrize('lanes', [4, 8])
+    @pytest.mark.parametrize('seed', [0, 1, 2])
+    def test_random_columns(self, seed, lanes):
+        """Columns no frontend would send (ids a row never had, preds of
+        any op, incs of anything, a row that fills) through four
+        dispatches: the step and the plain step agree on every array."""
+        from automerge_tpu.fleet.sequence import INC, SEQ_PRED_LANES
+        from automerge_tpu.fleet.tensor_doc import ACTOR_BITS
+        rng = np.random.default_rng(seed)
+        rows, width = 4, 16
+        state = SeqState.empty(rows, 24, actor_slots=lanes)  # a row fills
+        seen = [[0] for _ in range(rows)]
+        ctr = 2
+        for _dispatch in range(4):
+            shape = (rows, width)
+            kind = rng.choice([PAD, INSERT, INSERT, INSERT, SET, SET, DEL,
+                               INC], size=shape).astype(np.int32)
+            ref = np.zeros(shape, np.int32)
+            packed = np.zeros(shape, np.int32)
+            preds = np.zeros(shape + (SEQ_PRED_LANES,), np.int32)
+            for d in range(rows):
+                for p in range(width):
+                    packed[d, p] = (ctr << ACTOR_BITS) | rng.integers(0, 6)
+                    ctr += 1
+                    recent = seen[d][-1 - int(rng.geometric(0.3) - 1)
+                                     % len(seen[d])]
+                    ref[d, p] = rng.choice(
+                        [recent, 0, (999 << ACTOR_BITS) | 1],
+                        p=[0.8, 0.12, 0.08])
+                    for lane in range(rng.integers(0, SEQ_PRED_LANES + 1)):
+                        preds[d, p, lane] = rng.choice(
+                            [ref[d, p], seen[d][rng.integers(len(seen[d]))],
+                             -1], p=[0.5, 0.45, 0.05])
+                    if kind[d, p] in (INSERT, SET):
+                        seen[d].append(packed[d, p])
+            value = np.where(kind == INC,
+                             rng.choice([1, -3, 1 << 28], size=shape),
+                             rng.integers(-5, 1 << 20, size=shape))
+            cols = SeqOpBatch(kind, ref, packed, value.astype(np.int32),
+                              preds, rng.random(shape) < 0.01)
+            state, _ = self._both(state, cols)
+
+
 # ---- the referent lookup, hoisted out of the scan: every op cell's referent
 # among the row's old nodes is found before the scan, for the whole batch ---
 
@@ -671,6 +911,7 @@ def test_lookup_in_blocks(capacity):
         assert f'[{rows},{width},{nodes}]' not in text
 
 
+@functools.lru_cache(maxsize=None)
 def _compiled_text(rows, capacity, width, lanes=4):
     """The optimized program of one dispatch at these shapes, as text."""
     import jax
@@ -708,6 +949,41 @@ def _computations(text):
     return out
 
 
+_CALLED = r'(?:calls|to_apply|body|condition)=%?([\w.\-]+)'
+# name, dimensions, opcode and operands of an instruction that yields an
+# array
+_INSTR = (r'(?:ROOT )?%?([\w.\-]+) = \w+\[([0-9,]*)\]'
+          r'(?:\{[^}]*\})? ([\w\-]+)\(([^)]*)\)')
+
+
+def _parsed(comps):
+    """{computation: [(match of _INSTR, line)]}"""
+    import re
+    instr = re.compile(_INSTR)
+    return {name: [(m, line) for line in lines
+                   for m in [instr.match(line)] if m]
+            for name, (_entry, lines) in comps.items()}
+
+
+def _scan_side(comps):
+    """The scan's side of a program: every computation reached from a
+    `while` that is not the lookup's own."""
+    import re
+    called = re.compile(_CALLED)
+    todo = [name for _entry, lines in comps.values() for line in lines
+            if ' while(' in line and 'seq.referent_lookup' not in line
+            for name in called.findall(line)]
+    assert todo, 'no scan found: the text was not parsed'
+    scan_side = set()
+    while todo:
+        name = todo.pop()
+        if name in scan_side or name not in comps:
+            continue
+        scan_side.add(name)
+        todo += [c for line in comps[name][1] for c in called.findall(line)]
+    return scan_side
+
+
 def test_scan_body_copies_no_node_array():
     """The compiled program may not copy an array of elem_id's shape
     anywhere but in its entry computation: a scan step that writes an
@@ -738,29 +1014,8 @@ def test_scan_body_searches_no_node_array(rows, capacity, width):
     import re
     nodes = capacity + 3
     comps = _computations(_compiled_text(rows, capacity, width))
-    called = re.compile(
-        r'(?:calls|to_apply|body|condition)=%?([\w.\-]+)')
-    # name, dimensions, opcode and operands of an instruction that yields
-    # an array
-    instr = re.compile(r'(?:ROOT )?%?([\w.\-]+) = \w+\[([0-9,]*)\]'
-                       r'(?:\{[^}]*\})? ([\w\-]+)\(([^)]*)\)')
-    parsed = {name: [(m, line) for line in lines
-                     for m in [instr.match(line)] if m]
-              for name, (_entry, lines) in comps.items()}
-
-    # the scan's side: every computation reached from a `while` that is not
-    # the lookup's own
-    todo = [name for _entry, lines in comps.values() for line in lines
-            if ' while(' in line and 'seq.referent_lookup' not in line
-            for name in called.findall(line)]
-    assert todo, 'no scan found: the text was not parsed'
-    scan_side = set()
-    while todo:
-        name = todo.pop()
-        if name in scan_side or name not in comps:
-            continue
-        scan_side.add(name)
-        todo += [c for line in comps[name][1] for c in called.findall(line)]
+    parsed = _parsed(comps)
+    scan_side = _scan_side(comps)
 
     row_shape = f'{rows},{nodes}'
     searches, lookups = [], 0
@@ -778,8 +1033,57 @@ def test_scan_body_searches_no_node_array(rows, capacity, width):
     assert lookups, 'the lookup ahead of the scan was not found'
 
     fused = {c for _entry, lines in comps.values() for line in lines
-             if ' fusion(' in line for c in called.findall(line)}
+             if ' fusion(' in line for c in re.findall(_CALLED, line)}
     laid_out = [f'{name}: {line[:160]}' for name, found in parsed.items()
                 if name not in fused for m, line in found
                 if m.group(2) == f'{rows},{width},{nodes}']
     assert not laid_out, laid_out
+
+
+@pytest.mark.parametrize('lanes', [4, 8])
+@pytest.mark.parametrize('rows,capacity,width', [(8, 256, 16),
+                                                 (4, 512, 512)])
+def test_scan_body_indexes_no_small_array(rows, capacity, width, lanes):
+    """On the scan's side no scatter, gather, dynamic-slice or
+    dynamic-update-slice reads or writes an array of the overlay's shape
+    ([rows, width]) or of a node's register row ([rows, lanes]): their
+    elements are read and written by compares and selects, which fuse,
+    where on the chip every index was a program of its own and those of
+    the small arrays half of the scan step (PERF.md section 6, PR 36).
+    What is still indexed there is as long as the document: each lane array
+    ([rows, lanes * nodes]) is gathered from once and scattered into
+    once, and `elem_id` and `nxt` ([rows, nodes]) are gathered from and
+    never written. (The array an instruction indexes is its first
+    operand; the op columns the scan slices its steps from are
+    [width, rows], and the lane gather's indices are [rows, lanes] by
+    right.)"""
+    import re
+    nodes = capacity + 3
+    comps = _computations(_compiled_text(rows, capacity, width, lanes))
+    small = {f'{rows},{width}': 'overlay', f'{rows},{lanes}': 'register row'}
+    lane_shape, node_shape = f'{rows},{lanes * nodes}', f'{rows},{nodes}'
+    assert len({lane_shape, node_shape, *small}) == 4
+    indexed = {'scatter': [], 'gather': []}
+    on_small = []
+    scan_side = _scan_side(comps)
+    for name, found in _parsed(comps).items():
+        if name not in scan_side:
+            continue
+        dims = {m.group(1): m.group(2) for m, _line in found}
+        for m, line in found:
+            if m.group(3) not in ('scatter', 'gather', 'dynamic-slice',
+                                  'dynamic-update-slice'):
+                continue
+            operand = dims.get(re.findall(r'%([\w.\-]+)', m.group(4))[0])
+            writes = m.group(3) in ('scatter', 'dynamic-update-slice')
+            if operand in small or (writes and m.group(2) in small):
+                on_small.append(f'{small.get(operand)}: {name}: {line[:160]}')
+            if m.group(3) in indexed:
+                indexed[m.group(3)].append(operand)
+    assert not on_small, on_small
+    assert indexed['scatter'] and \
+        set(indexed['scatter']) == {lane_shape}, indexed['scatter']
+    assert len(indexed['scatter']) <= 4, indexed['scatter']
+    assert set(indexed['gather']) == {lane_shape, node_shape}, \
+        indexed['gather']
+    assert indexed['gather'].count(lane_shape) <= 4, indexed['gather']
