@@ -2066,9 +2066,11 @@ class _FlatEngine(HashGraph):
     # _doc_hashes/_doc_maxops carry the native extractor's per-change
     # hashes/maxOps after a native materialize (in place of the decoded
     # dicts the Python path keeps in _doc_decoded).
+    # _seen: the applied changes' hashes kept for the turbo path's
+    # general gate (see _applied_hashes); unset until that gate visits.
     __slots__ = ('fleet', 'slot', 'mirror', 'seq_objects', 'map_objects',
                  '_doc_decoded', '_log', '_defer', '_doc_hashes',
-                 '_doc_maxops')
+                 '_doc_maxops', '_seen')
 
     def __init__(self, fleet, slot):
         # fleet/slot FIRST: every col-backed property setter below (and
@@ -2480,6 +2482,28 @@ class _FlatEngine(HashGraph):
         if self._deferred:
             self.fleet.metrics.graph_builds += 1
         super()._ensure_graph()
+
+    def _applied_hashes(self):
+        """The hash (hex) of every applied change, as a set: what the
+        causal gate asks of history, without the query dicts (one string a
+        change instead of a dozen containers). Built on the first call from
+        the cheapest lane each record has (hashindex.engine_hash_population:
+        the native extractor's array for a loaded history, the parser's
+        hash lanes for the turbo commits) and kept up from the deferred
+        log as it grows; built again where something else consumed or
+        replaced that log (a read that materialized the graph, the exact
+        path, a park)."""
+        from .hashindex import engine_hash_population
+        seen = getattr(self, '_seen', None)
+        deferred = self._deferred            # folds the pending segments
+        n_graph = len(self.change_index_by_hash)
+        if seen is not None and seen[1] is deferred and seen[3] == n_graph:
+            hashes = seen[0]
+            hashes.update(engine_hash_population(self, since=seen[2]))
+        else:
+            hashes = set(engine_hash_population(self))
+        self._seen = (hashes, deferred, len(deferred), n_graph)
+        return hashes
 
     # Frontier-index maintenance (fleet/hashindex.py): every path that
     # lands an APPLIED change on this engine stages its hash — the
@@ -3931,9 +3955,10 @@ class _LazyHandle(dict):
 
 class _TurboMetaBatch:
     """Raw per-change metadata from the native parser, with lazy hex/dict
-    materialization: the fast path touches only numpy arrays; full dicts are
-    built per change only for the general gate (documents that are neither
-    one chain nor DAG-ordered) and for deferred hash-graph resolution."""
+    materialization: the fast path touches only numpy arrays; the general
+    gate (documents that are neither one chain nor DAG-ordered) reads a
+    run cut into chain segments, and dicts are built per change only for
+    the changes it queues and for deferred hash-graph resolution."""
 
     __slots__ = ('m', 'actors', 'buffers')
 
@@ -3955,17 +3980,78 @@ class _TurboMetaBatch:
         off = self.m['msg_off']
         return self.m['msg_blob'][off[i]:off[i + 1]].decode('utf8')
 
-    def meta(self, i):
-        """Full change-header dict (general gating path)."""
+    def metas(self, start, stop):
+        """What a queue entry carries of each change of a run (its buffer:
+        the exact path decodes a queued change again before it drains it,
+        and the turbo path parses it again). The hashes and dependencies of
+        the run are hexed once and cut, not change by change."""
         m = self.m
-        return {
-            'actor': self.actors[int(m['actor'][i])], 'seq': int(m['seq'][i]),
-            'startOp': int(m['startOp'][i]), 'time': int(m['time'][i]),
-            'message': self.message(i), 'deps': self.deps_hex(i),
-            'extraBytes': None, 'hash': self.hash_hex(i),
-            'buffer': self.buffers[i], 'ops': range(int(m['nops'][i])),
-            '_change_index': i,
-        }
+        hashes = m['hash32'][start:stop].tobytes().hex()
+        off = m['deps_off'][start:stop + 1].tolist()
+        deps = m['deps_blob'][32 * off[0]:32 * off[-1]].hex()
+        cut = [64 * (j - off[0]) for j in off]
+        actors = self.actors
+        return [{'actor': actors[actor], 'seq': seq, 'startOp': start_op,
+                 'deps': [deps[at:at + 64]
+                          for at in range(cut[k], cut[k + 1], 64)],
+                 'hash': hashes[64 * k:64 * k + 64],
+                 'buffer': self.buffers[start + k], 'ops': range(nops),
+                 '_change_index': start + k}
+                for k, (actor, seq, start_op, nops) in enumerate(zip(
+                    m['actor'][start:stop].tolist(),
+                    m['seq'][start:stop].tolist(),
+                    m['startOp'][start:stop].tolist(),
+                    m['nops'][start:stop].tolist()))]
+
+    def chain_links(self, run_starts):
+        """Per change: does it continue the change before it in the buffer
+        (its one dependency is that change's hash, its actor the same, its
+        seq the next)? False at every `run_starts` (a document's first).
+        One pass of compares over the parser's lanes."""
+        m = self.m
+        n = len(m['seq'])
+        link = np.zeros(n, dtype=bool)
+        if n > 1:
+            off = m['deps_off']
+            deps = np.frombuffer(m['deps_blob'], dtype=np.uint8)
+            deps = deps[:len(deps) - len(deps) % 32].reshape(-1, 32)
+            one = np.flatnonzero(off[2:] - off[1:-1] == 1) + 1
+            one = one[(deps[off[one]] == m['hash32'][one - 1]).all(axis=1)]
+            link[one] = (m['actor'][one] == m['actor'][one - 1]) & \
+                (m['seq'][one] == m['seq'][one - 1] + 1)
+        link[run_starts] = False
+        return link
+
+    def segments(self, start, stop, link, clock):
+        """The run [start, stop) of one document as the general gate takes
+        it (`_gate_segments`): its hashes (hex), the bounds of its chain
+        segments (places in the run; a segment is a change and every
+        change after it that `link` ties to the one before), of each
+        segment's first change the dependencies, the actor and the seq,
+        and `again`: a change of the run may have been applied or may
+        stand in it twice (a hash twice in the run; a seq that `clock`,
+        the document's, has reached: a change whose seq is past its
+        actor's is in no history). Every change is then a segment of its
+        own, and the gate asks of each whether it is delivered again."""
+        m = self.m
+        actors = self.actors
+        blob = m['hash32'][start:stop].tobytes().hex()
+        hashes = [blob[at:at + 64] for at in range(0, len(blob), 64)]
+        ids = m['actor'][start:stop].tolist()
+        seqs = m['seq'][start:stop].tolist()
+        reached = {a: clock.get(actors[a], 0) for a in set(ids)}
+        again = len(set(hashes)) != len(hashes) or \
+            any(seq <= reached[a] for a, seq in zip(ids, seqs))
+        bounds = list(range(stop - start)) if again else \
+            np.flatnonzero(~link[start:stop]).tolist()
+        off = m['deps_off'][start:stop + 1].tolist()
+        blob = m['deps_blob'][32 * off[0]:32 * off[-1]].hex()
+        deps = [[blob[at:at + 64] for at in range(
+            64 * (off[k] - off[0]), 64 * (off[k + 1] - off[0]), 64)]
+            for k in bounds]
+        return (hashes, bounds + [stop - start], deps,
+                [actors[ids[k]] for k in bounds],
+                [seqs[k] for k in bounds], again)
 
     def resolve(self, i):
         """(hash, deps, actor, changes_meta entry) for HashGraph._ensure_graph."""
@@ -4004,6 +4090,26 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     goes depends only on the shape of its own input. The call is atomic:
     any gate error rolls back every doc (the native gates mutate nothing).
 
+    Held-back changes: a change whose dependency has not arrived waits in
+    its document's queue (the entries carry their buffers). The next call
+    that brings the document anything parses and gates them again behind
+    what it brings, as the reference's fixed point does (`decoded +
+    self.queue`); what is causally ready then is applied in THIS call, the
+    rest queued again. Where the general gate applies a document's changes
+    in another order than the buffer's (a drained tail after the change
+    that frees it), `gate.order` brings the parser's op rows into the
+    applied order, so the sequence dispatch, the register batch and the
+    grid see every document's ops as they were applied. Neither a queue
+    nor a document off both native gates sends the call to the exact path.
+    The general gate runs the reference's fixed point over a document's
+    run cut into chain segments (`_gate_segments`: a change and the changes
+    that each follow only the one before them are applied or queued
+    together, as the reference does change by change), asks history through
+    `_FlatEngine._applied_hashes` (a set of hashes, no query dicts) only
+    for a dependency that is no change of the run, and the staged commit leaves one lazy hash-graph record a document, as a
+    seam segment does: a document that passes the general gate every other
+    call builds no graph for it.
+
     Phase attribution: when spans are enabled the call tiles into
     contiguous `turbo_setup` / `turbo_parse` / `turbo_gate` /
     `turbo_commit` / `turbo_stage` / `turbo_dispatch` spans (no
@@ -4012,18 +4118,18 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     dispatch sub-spans nested inside. `turbo_gate` and `turbo_commit`
     are tiled in turn by a second sequence: `gate.chain` / `gate.dag`
     / `gate.shape` / `gate.decode` / `gate.general` (per document that
-    reaches it a `gate.meta` and a `gate.drain` span) / `gate.validate`,
-    and `commit.columnar` / `commit.staged` / `commit.handles` — named
-    without the `turbo_` prefix, so readers that sum `turbo_*` count
-    each millisecond once. `turbo_gate` carries why documents left the
-    chain path (`offchain_native` / `offchain_heads` / `offchain_seq`)
-    and how many of them the DAG gate took back (`offchain_dag`; of
-    those, `dag_seq_docs` hold sequence ops), all also in
-    `fleet.metrics`; the three reasons less `offchain_dag` is what
-    reached `gate.general`. The DAG gate's verdict is known before the
-    shape check asks: a call that holds sequence, make or nested ops
-    leaves for the exact path, whole, only for a document that is
-    neither on the chain nor DAG-ordered."""
+    reaches it a `gate.meta` and a `gate.drain` span) / `gate.order` /
+    `gate.validate`, and `commit.columnar` / `commit.staged` /
+    `commit.handles` — named without the `turbo_` prefix, so readers that
+    sum `turbo_*` count each millisecond once. `turbo_gate` carries why
+    documents left the chain path (`offchain_native` / `offchain_heads` /
+    `offchain_seq`), how many of them the DAG gate took back
+    (`offchain_dag`; of those, `dag_seq_docs` hold sequence ops), how
+    many changes the call queued (`heldback_changes`) and how many it
+    applied out of a queue (`drained_changes`), all also in
+    `fleet.metrics` (with `heldback_docs`, the documents left with a
+    queue); the three reasons less `offchain_dag` is what reached
+    `gate.general`."""
     ps = _span_seq()
     sub = _span_seq()   # the sub-phases of turbo_gate, then turbo_commit
     ps.mark('turbo_setup', docs=len(handles))
@@ -4048,10 +4154,6 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         if handle.get('frozen') or not isinstance(state, FleetDoc) or \
                 not state.is_fleet:
             return None
-        if state._impl.queue:
-            # Draining held-back changes needs their op rows; the exact path
-            # re-ingests them on flush, so route this call there
-            return None
         engines.append(state._impl)
     fleet = engines[0].fleet
     if any(e.fleet is not fleet for e in engines):
@@ -4062,11 +4164,21 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # the trailing docs' counts at 0 (the exact path's zip-truncate
     # semantics), not uninitialized garbage feeding np.repeat
     doc_counts = np.zeros(len(handles), dtype=np.int64)
+    # A document's held-back changes (its queue's entries carry their
+    # buffers) are parsed and gated again with what the call brings for
+    # it, behind it in the run as in _drain_queue (`decoded + self.queue`);
+    # n_queued is that tail's length. A queue whose document gets nothing
+    # in this call cannot move and stays as it is.
+    n_queued = np.zeros(len(handles), dtype=np.int64)
     for d, changes in enumerate(per_doc_changes):
         k = len(flat_buffers)
         if not isinstance(changes, (list, tuple)):
             changes = list(changes)   # one-shot iterables: materialize once
         flat_buffers += changes
+        queue = engines[d].queue
+        if queue and changes:
+            flat_buffers += [change['buffer'] for change in queue]
+            n_queued[d] = len(queue)
         per_doc_idx[d] = (k, len(flat_buffers))
         doc_counts[d] = len(flat_buffers) - k
     if set(map(type, flat_buffers)) - {bytes}:
@@ -4211,20 +4323,15 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     sub.mark('gate.shape')
 
     flags_all = rows['flags']
-    seq_sel = (flags_all >= 3) & (flags_all <= 6)
-    make_sel = (flags_all >= 7) & (flags_all <= 10)
-    seq_make_sel = flags_all >= 11      # makes inside sequences (11-14)
+    seq_sel, make_sel, seq_make_sel = _row_kinds(flags_all)
     nested_sel = (flags_all <= 2) & (rows['obj'] != 0)
     if seq_sel.any() or make_sel.any() or nested_sel.any() or \
             seq_make_sel.any():
-        # RGA application is order-sensitive, and buffer order is all it
-        # needs: a document on the chain or DAG-ordered (concurrent
-        # writers' branches, a merge change) applies in buffer order and
-        # keeps its sequence ops here. A document NEITHER gate took needs
-        # the general causal gate, whose applied order can differ from
-        # buffer order: that routes the whole call to the exact path
-        if (~fast_mask[doc_of]).any():
-            return None
+        # RGA application is order-sensitive. A document on the chain or
+        # DAG-ordered (concurrent writers' branches, a merge change)
+        # applies in buffer order; a document NEITHER gate took goes to the
+        # general causal gate below, and where that applies its changes in
+        # another order, gate.order brings its op rows into that order
         if offchain_dag:
             # documents the DAG gate committed that hold sequence ops
             dag_seq_docs = int(dag_ok[np.unique(change_doc[rows['doc'][
@@ -4326,8 +4433,9 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         except Exception:
             return None
 
-    # From here on the batch is committed to turbo (counted as such)
-    fleet.metrics.turbo_calls += 1
+    # From here on no shape sends the batch to the exact path but one
+    # (an op inside an object whose make stays queued, below): why its
+    # documents left the chain path is counted here, the call further down
     fleet.metrics.offchain_native += offchain_native
     fleet.metrics.offchain_heads += offchain_heads
     fleet.metrics.offchain_seq += offchain_seq
@@ -4340,8 +4448,13 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # failure restores all of them: the whole turbo call is atomic (the
     # exact path gets per-doc atomicity from fleet.pending instead).
     ready = fast_mask[doc_of]    # fancy-indexed: a fresh, writable array
-    staged = []                  # general-path: (engine, applied, queue)
+    staged = []                  # general-path: (engine, applied at, queue)
     backups = []                 # (engine, clock, heads, queue)
+    # where the general gate applies a document's changes in another order
+    # than the buffer's, the sort key that brings its changes into it
+    rank = None
+    waiting = []                 # the changes left in a queue, by index
+    heldback = drained = 0       # changes of the call queued; of a queue applied
 
     def restore_all():
         for engine, clock, heads, queue in backups:
@@ -4349,17 +4462,21 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
 
     offchain = np.flatnonzero(~fast_mask & has_changes).tolist()
     sub.mark('gate.general', docs=len(offchain))
+    if offchain:
+        link = batch_meta.chain_links(starts_all[has_changes])
     for d in offchain:
         engine = engines[d]
         start, stop = per_doc_idx[d]
-        backups.append((engine, dict(engine.clock), list(engine.heads),
-                        list(engine.queue)))
+        clock, heads = dict(engine.clock), list(engine.heads)
+        backups.append((engine, clock, heads, engine.queue))
+        if n_queued[d]:
+            engine.queue = []    # its changes are the tail of the run
         try:
             with _span('gate.meta', doc=d, changes=stop - start):
-                metas = [batch_meta.meta(i) for i in range(start, stop)]
+                run = batch_meta.segments(start, stop, link, clock)
             with _span('gate.drain', doc=d, changes=stop - start):
-                applied, queue = engine._drain_queue(
-                    metas, lambda change: None)
+                order, left = _gate_segments(engine, clock, heads, *run,
+                                             engine._applied_hashes)
         except Exception as exc:
             restore_all()
             # Gate errors are doc-scoped by construction (the drain loop
@@ -4372,12 +4489,53 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
             if isinstance(exc, ValueError):
                 raise InvalidChange(str(exc), doc_index=d) from exc
             raise
-        staged.append((engine, applied, queue))
-        for change in applied:
-            ready[change['_change_index']] = True
+        bounds = run[1]
+        at = [i for k in order
+              for i in range(start + bounds[k], start + bounds[k + 1])]
+        queue = [entry for k in left for entry in batch_meta.metas(
+            start + bounds[k], start + bounds[k + 1])]
+        staged.append((engine, at, queue))
+        ready[at] = True
+        if order != sorted(order):
+            # a drained tail applies after the change that frees it,
+            # wherever either stands in the buffer: the k-th change applied
+            # takes the k-th place among those applied
+            if rank is None:
+                rank = np.arange(n_changes)
+            rank[at] = sorted(at)
+        of_queue = stop - int(n_queued[d])
+        drained += sum(i >= of_queue for i in at)
+        still = [i for k in left
+                 for i in range(start + bounds[k], start + bounds[k + 1])]
+        heldback += sum(i < of_queue for i in still)
+        waiting += still
+    fast_queued = np.flatnonzero(fast_mask & (n_queued > 0)).tolist()
+    drained += int(n_queued[fast_queued].sum())
+    ps.note(heldback_changes=heldback, drained_changes=drained)
+
+    sub.mark('gate.order')
+    if rank is not None:
+        # Everything below reads a document's op rows in applied order:
+        # the sequence dispatch and the register batch apply a row's ops as
+        # they stand, and the grid lays them out by rank within the document
+        order = np.argsort(rank[rows['doc']], kind='stable')
+        rows = _rows_in_order(rows, order)
+        decoded_gid = decoded_gid[order]
+        vlen_all = rows['vlen']
+        seq_sel, make_sel, seq_make_sel = _row_kinds(rows['flags'])
 
     sub.mark('gate.validate')
     keep = ready[rows['doc']]
+    if waiting and _op_in_queued_object(
+            rows, keep, (make_sel | seq_make_sel) &
+            np.isin(rows['doc'], waiting), change_doc):
+        # an applied op inside an object whose make is held back: no
+        # causal history gives that; the exact path has the error for it
+        restore_all()
+        return None
+    fleet.metrics.turbo_calls += 1
+    fleet.metrics.heldback_changes += heldback
+    fleet.metrics.drained_changes += drained
     # Validation from the native rows: duplicate opIds *within* the
     # applied batch are detectable per doc without decoding op objects.
     kept_change = rows['doc'][keep]      # native 'doc' is the change index
@@ -4576,17 +4734,26 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
             for gi in np.flatnonzero(gd == d).tolist():
                 clock[nat_actors[int(ga[gi])]] = int(g_last[gi])
             engine.clock = clock
+    for d in fast_queued:
+        # on the chain or DAG-ordered WITH its held-back changes: all of
+        # the run was applied, the queue's tail with it
+        engines[d].queue = []
     sub.mark('commit.staged', docs=len(staged))
-    for engine, applied, queue in staged:
-        # Slow/staged docs: the exact per-doc tail loop (counted — this
-        # is the fallback path the columnar commit replaces for fast
-        # docs).
+    for engine, at, queue in staged:
+        # Slow/staged docs: the per-doc tail loop (counted — this is the
+        # fallback path the columnar commit replaces for fast docs). The
+        # applied changes join the log in applied order, with ONE lazy
+        # record of the hash graph for all of them, as a seam segment's
         fleet.metrics.turbo_commit_fallback_docs += 1
-        for change in applied:
-            engine.changes.append(change['buffer'])
-            engine._defer_record(change)
-            engine.max_op = max(engine.max_op,
-                                change['startOp'] + len(change['ops']) - 1)
+        if at:
+            log = engine.changes
+            engine._deferred.append((len(log), batch_meta, at))
+            log.extend([flat_buffers[i] for i in at])
+            if fleet._hash_index is not None:
+                fleet._hash_index.stage_rows(
+                    np.full(len(at), engine.slot, dtype=np.int64),
+                    hash32[at])
+            engine.max_op = max(engine.max_op, int(last_op[at].max()))
             engine.stale = True
             engine.binary_doc = None
         engine.queue = queue
@@ -4594,6 +4761,8 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
             # Queue entries from this pass carry only headers; flag the
             # mirror so the exact path re-decodes them before draining
             engine.stale = True
+    fleet.metrics.heldback_docs += sum(1 for engine in engines
+                                       if engine.queue)
 
     sub.mark('commit.handles')
     for handle in handles:
@@ -4993,6 +5162,116 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     dispatch_seq_rows()
     fleet.metrics.device_ops += int(keep.sum())
     return result
+
+
+def _row_kinds(flags):
+    """Of the parser's op rows, by their flags: (sequence element ops,
+    makes at map keys, makes inside sequences)."""
+    return ((flags >= 3) & (flags <= 6), (flags >= 7) & (flags <= 10),
+            flags >= 11)
+
+
+def _gate_segments(engine, clock, heads, hashes, bounds, deps, actors, seqs,
+                   again, applied):
+    """The reference's causal gate run to its fixed point (new.js:1550-1586
+    and 1825-1841; `HashGraph._causal_gate` / `_drain_queue` are the host
+    oracle's) over one document's run cut into chain segments
+    (`_TurboMetaBatch.segments`): segment k is the changes
+    `bounds[k]:bounds[k + 1]` of the run, `deps[k]`, `actors[k]` and
+    `seqs[k]` are its first change's. The changes after the first each
+    follow the one before them and nothing else, with its actor and the
+    next seq, and none of them was seen before, so the reference applies
+    them in the pass that applies the first, right behind it, or queues
+    them with it: a pass over the segments is the reference's pass over
+    the changes. `clock` and `heads` are the document's; `applied()` gives
+    the hashes of every change applied before this call, and is asked only
+    for a dependency that is no change of the run, and, where `again` says
+    a change may be delivered again, for each change's own hash and every
+    dependency this call has not applied.
+
+    Returns (the segments applied, in the order applied; the segments left
+    in the queue, in the run's order) and advances `engine.heads` and
+    `engine.clock` where something was applied. Raises the reference's
+    ValueError for a ready change whose seq is not its actor's next."""
+    heads = set(heads)
+    clock = dict(clock)
+    known = None
+    done = set()                 # the hashes this call applied
+    in_run = set(hashes)
+    order = []
+    left = range(len(bounds) - 1)
+    while True:
+        waiting = []
+        before = len(order)
+        for k in left:
+            lo, hi = bounds[k], bounds[k + 1]
+            if again:
+                if known is None:
+                    known = applied()
+                if hashes[lo] in known or hashes[lo] in done:
+                    continue     # delivered again: applied once
+            for dep in deps[k]:
+                if dep in done:
+                    continue
+                if dep in in_run and not again:
+                    waiting.append(k)    # a change of the run, not yet
+                    break                # applied and in no history
+                if known is None:
+                    known = applied()
+                if dep not in known:
+                    waiting.append(k)
+                    break
+            else:
+                actor, seq = actors[k], seqs[k]
+                expected = clock.get(actor, 0) + 1
+                if seq < expected:
+                    raise ValueError(f'Reuse of sequence number {seq} '
+                                     f'for actor {actor}')
+                if seq > expected:
+                    raise ValueError(f'Skipped sequence number {expected} '
+                                     f'for actor {actor}')
+                clock[actor] = seq + hi - lo - 1
+                done.update(hashes[lo:hi])
+                heads.difference_update(deps[k])
+                heads.add(hashes[hi - 1])
+                order.append(k)
+        left = waiting
+        if len(order) == before or not left:
+            break
+    if order:
+        engine.heads = sorted(heads)
+        engine.clock = clock
+    return order, left
+
+
+def _rows_in_order(rows, order):
+    """The parser's op rows taken in `order` (a permutation of them): every
+    per-row column, and the ragged pred lists regrouped to match. The value
+    arena is left behind: its offsets are the running sum of `vlen` in
+    PARSE order, and gate.decode has read it by then."""
+    out = {name: column[order] for name, column in rows.items()
+           if name not in ('pred_off', 'pred', 'vblob')}
+    counts = np.diff(rows['pred_off'])[order]
+    off = np.zeros(len(order) + 1, dtype=np.int64)
+    np.cumsum(counts, out=off[1:])
+    out['pred_off'] = off
+    out['pred'] = rows['pred'][
+        np.repeat(rows['pred_off'][:-1][order] - off[:-1], counts) +
+        np.arange(off[-1])]
+    return out
+
+
+def _op_in_queued_object(rows, keep, queued, change_doc):
+    """True where a kept op row lies inside an object that one of the
+    `queued` make rows of its document (rows of changes left in the queue)
+    would create."""
+    if not queued.any():
+        return False
+    row_doc = change_doc[rows['doc']]
+    made = set(zip(row_doc[queued].tolist(), rows['packed'][queued].tolist()))
+    inside = keep & (rows['obj'] != 0)
+    return any(pair in made for pair in zip(row_doc[inside].tolist(),
+                                            rows['obj'][inside].tolist()))
 
 
 def _validate_turbo_preds(fleet, engines, rows, keep, seq_sel, seq_make_sel,

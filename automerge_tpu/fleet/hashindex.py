@@ -722,15 +722,17 @@ def probe_peer_sets(peer_sets, hash_lists):
 
 # ---- fleet wiring ----------------------------------------------------
 
-def engine_hash_population(engine):
+def engine_hash_population(engine, since=None):
     """Every APPLIED change hash (hex) of a backend engine, WITHOUT
     building the hash-graph query dicts: materialized graph keys, then
     deferred records served from their cheapest lane — the native
     extractor's hash array for a parked prefix, the turbo parser's
     hash32 lanes for pending seam segments — with a per-change header
     decode only for records that have neither. Queued (causally
-    premature) changes are excluded, matching get_change_by_hash."""
-    out = list(engine.change_index_by_hash.keys())
+    premature) changes are excluded, matching get_change_by_hash.
+    `since`: only the deferred records from that one on (what a caller
+    that keeps the population has not seen yet)."""
+    out = list(engine.change_index_by_hash.keys()) if since is None else []
     pending = getattr(engine, '_doc_pending', None)
     if pending is not None:
         # fills _doc_hashes via the native extractor when available;
@@ -739,12 +741,21 @@ def engine_hash_population(engine):
         engine._materialize_doc()
     doc_hashes = getattr(engine, '_doc_hashes', None)
     doc_decoded = getattr(engine, '_doc_decoded', None)
-    for entry in engine._deferred:
+    for entry in engine._deferred[since or 0:]:
         if len(entry) == 3:
             _index, batch, i = entry
             idxs = i if isinstance(i, (list, tuple, range)) else [i]
             hash_of = getattr(batch, 'hash_hex', None)
             eng_ref = getattr(batch, 'engine', None)
+            lanes = getattr(batch, 'm', None)
+            if lanes is not None and len(idxs) > 1:
+                # the turbo parser's hash lanes: one hex of the run
+                rows = lanes['hash32'][idxs.start:idxs.stop] \
+                    if isinstance(idxs, range) and idxs.step == 1 \
+                    else lanes['hash32'][np.asarray(idxs, dtype=np.int64)]
+                blob = rows.tobytes().hex()
+                out.extend(blob[k:k + 64] for k in range(0, len(blob), 64))
+                continue
             for j in idxs:
                 j = int(j)
                 if eng_ref is engine and doc_hashes is not None and \

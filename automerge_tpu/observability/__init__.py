@@ -12,7 +12,10 @@ phase, and what happened around it. Four layers, one package:
   `offchain_native` / `offchain_heads` / `offchain_seq`, how many of
   those the native DAG gate took back, `offchain_dag`, and how many of
   THOSE hold sequence ops, `dag_seq_docs`; `seq_multiwriter_rows`, the
-  rows of a sequence dispatch whose op list holds two actors or more),
+  rows of a sequence dispatch whose op list holds two actors or more;
+  `heldback_changes` / `drained_changes` / `heldback_docs`, the changes
+  a turbo call queued, applied out of a queue, and the documents it left
+  with one),
   `register_dispatch_source`/`dispatch_counts` and
   `register_health_source`/`health_counts` system-wide roll-ups, and
   `trace`, the operator's one entry to a profiler capture: it turns the

@@ -112,6 +112,13 @@ class Metrics:
                                  # hold sequence ops: concurrent writers
                                  # on a Text or list, applied on the device
                                  # in buffer order
+        # held-back changes on the turbo path: a change whose dependency
+        # has not arrived waits in its document's queue and is parsed and
+        # gated again with what the next call brings for the document
+        'heldback_changes',      # changes a call brought and queued
+        'drained_changes',       # changes a call applied out of a queue
+        'heldback_docs',         # documents of a call whose queue is not
+                                 # empty after it
         # the sequence engine (fleet/backend.py _dispatch_seq)
         'seq_ops',               # real sequence ops dispatched
         'seq_op_cells',          # rows x width of the op columns handed to

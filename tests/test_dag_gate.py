@@ -357,7 +357,11 @@ def test_a_dependency_outside_batch_and_heads_queues_then_drains(monkeypatch):
     assert got[0]['queue'] > 0 and got[0]['missing'] == [missing]
     # the missing change arrives: the queue drains, and it all adds up
     fleet, got = _refused([[without], [[log[gap]]]], monkeypatch)
-    assert fleet.metrics.offchain_dag == 0
+    # ... on the turbo path (PR 37): the held-back changes are gated again
+    # behind the change that frees them, and that run is DAG-ordered
+    assert fleet.metrics.fallbacks == 0 and fleet.metrics.exact_calls == 0
+    assert fleet.metrics.turbo_calls == 2 and fleet.metrics.offchain_dag == 1
+    assert fleet.metrics.heldback_changes == fleet.metrics.drained_changes > 0
     (doc,) = _host_run([[log]])
     assert got[0]['queue'] == 0 and got[0]['missing'] == []
     assert got[0]['heads'] == host.get_heads(doc)

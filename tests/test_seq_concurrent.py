@@ -7,8 +7,8 @@ is held to the host backend (`backend/op_set.py`: text, patches, `save()`
 bytes) and to the benchmark's plain reference (`reference_text.Rga`, which
 imports nothing of the program), in both buffer orders, and asserts the
 routing: `turbo_calls` >= 1, `fallbacks` 0, `exact_calls` 0, no row
-inexact. One case keeps the exit that remains: a document NEITHER gate
-accepts still takes the whole call to the exact path, and is still exact.
+inexact. One case holds the exit that went with PR 37: a document NEITHER
+gate accepts passes the general gate and stays on the device, exact.
 """
 
 import os
@@ -420,13 +420,14 @@ def test_rounds_in_one_call_of_many_documents():
 
 
 # ---------------------------------------------------------------------------
-# the exit that remains
+# the exit that went (PR 37): out of order, and still on the device
 # ---------------------------------------------------------------------------
 
-def test_out_of_order_document_still_takes_the_exact_path():
+def test_out_of_order_document_stays_on_the_device_path():
     """A call with sequence ops in which one document is out of order (a
-    change before the change it depends on: neither gate accepts it) takes
-    the exact path, whole, and is still exact."""
+    change before the change it depends on: neither gate accepts it) stays
+    on the turbo path, whole: the general gate applies that document's
+    changes in a causal order, its op rows follow, and it is exact."""
     rng = random.Random(81)
     rooms = [Room(2), Room(2)]
     fleet = DocFleet(doc_capacity=2, key_capacity=8)
@@ -442,8 +443,9 @@ def test_out_of_order_document_still_takes_the_exact_path():
     before = fleet.metrics.snapshot()
     handles, _ = apply_changes_docs(handles, [good, bad], mirror=False)
     delta = fleet.metrics.delta(before)
-    assert delta['turbo_calls'] == 0 and delta['fallbacks'] == 1
-    assert delta['exact_calls'] == 2
+    assert delta['turbo_calls'] == 1 and delta['fallbacks'] == 0
+    assert delta['exact_calls'] == 0
+    assert delta['turbo_commit_fallback_docs'] == 1     # the general gate
     views = materialize_docs(handles)
     for d, (room, applied) in enumerate(zip(rooms, (good, bad))):
         oracle = host_state(room.base + applied)
@@ -451,6 +453,9 @@ def test_out_of_order_document_still_takes_the_exact_path():
         assert fleet_backend.get_patch(handles[d]) == host.get_patch(oracle)
         assert bytes(fleet_backend.save(handles[d])) == \
             bytes(host.save(oracle))
+    assert fleet.metrics.fallbacks == 0 and fleet.metrics.exact_calls == 0
+    for st in fleet.seq_pools.pools.values():
+        assert not np.asarray(st.inexact).any()
 
 
 @pytest.mark.parametrize('fault', ['dangling_pred', 'seq_gap'])

@@ -217,7 +217,7 @@ def out_of_order_log(doc, n=3):
 
 
 GATE = ['gate.chain', 'gate.dag', 'gate.shape', 'gate.decode',
-        'gate.general', 'gate.validate']
+        'gate.general', 'gate.order', 'gate.validate']
 COMMIT = ['commit.columnar', 'commit.staged', 'commit.handles']
 
 
