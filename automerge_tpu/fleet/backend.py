@@ -2066,11 +2066,11 @@ class _FlatEngine(HashGraph):
     # _doc_hashes/_doc_maxops carry the native extractor's per-change
     # hashes/maxOps after a native materialize (in place of the decoded
     # dicts the Python path keeps in _doc_decoded).
-    # _seen: the applied changes' hashes kept for the turbo path's
-    # general gate (see _applied_hashes); unset until that gate visits.
+    # _history: the applied changes' hashes kept for the turbo path's
+    # general gate (see _history_index); unset until that gate asks.
     __slots__ = ('fleet', 'slot', 'mirror', 'seq_objects', 'map_objects',
                  '_doc_decoded', '_log', '_defer', '_doc_hashes',
-                 '_doc_maxops', '_seen')
+                 '_doc_maxops', '_history')
 
     def __init__(self, fleet, slot):
         # fleet/slot FIRST: every col-backed property setter below (and
@@ -2483,27 +2483,27 @@ class _FlatEngine(HashGraph):
             self.fleet.metrics.graph_builds += 1
         super()._ensure_graph()
 
-    def _applied_hashes(self):
-        """The hash (hex) of every applied change, as a set: what the
-        causal gate asks of history, without the query dicts (one string a
-        change instead of a dozen containers). Built on the first call from
-        the cheapest lane each record has (hashindex.engine_hash_population:
-        the native extractor's array for a loaded history, the parser's
-        hash lanes for the turbo commits) and kept up from the deferred
-        log as it grows; built again where something else consumed or
-        replaced that log (a read that materialized the graph, the exact
-        path, a park)."""
-        from .hashindex import engine_hash_population
-        seen = getattr(self, '_seen', None)
+    def _history_index(self):
+        """The hash of every applied change as a `hashindex.HistoryIndex`:
+        what the turbo path's general gate asks of history, without the
+        query dicts and without a Python object a hash. Built on the first
+        call from the cheapest byte lane each record has
+        (`hashindex.engine_hash_rows`) and kept up from the deferred log as
+        it grows; built again where something else consumed or replaced
+        that log (a read that materialized the graph, the exact path, a
+        park)."""
+        from .hashindex import HistoryIndex, engine_hash_rows
+        held = getattr(self, '_history', None)
         deferred = self._deferred            # folds the pending segments
         n_graph = len(self.change_index_by_hash)
-        if seen is not None and seen[1] is deferred and seen[3] == n_graph:
-            hashes = seen[0]
-            hashes.update(engine_hash_population(self, since=seen[2]))
+        if held is not None and held[1] is deferred and held[3] == n_graph:
+            index = held[0]
+            index.extend(engine_hash_rows(self, since=held[2]))
         else:
-            hashes = set(engine_hash_population(self))
-        self._seen = (hashes, deferred, len(deferred), n_graph)
-        return hashes
+            index = HistoryIndex()
+            index.extend(engine_hash_rows(self))
+        self._history = (index, deferred, len(deferred), n_graph)
+        return index
 
     # Frontier-index maintenance (fleet/hashindex.py): every path that
     # lands an APPLIED change on this engine stages its hash — the
@@ -3955,10 +3955,9 @@ class _LazyHandle(dict):
 
 class _TurboMetaBatch:
     """Raw per-change metadata from the native parser, with lazy hex/dict
-    materialization: the fast path touches only numpy arrays; the general
-    gate (documents that are neither one chain nor DAG-ordered) reads a
-    run cut into chain segments, and dicts are built per change only for
-    the changes it queues and for deferred hash-graph resolution."""
+    materialization: the fast path and the native gates touch only numpy
+    arrays; dicts are built per change only for the changes the general
+    gate leaves in a queue and for deferred hash-graph resolution."""
 
     __slots__ = ('m', 'actors', 'buffers')
 
@@ -3980,78 +3979,29 @@ class _TurboMetaBatch:
         off = self.m['msg_off']
         return self.m['msg_blob'][off[i]:off[i + 1]].decode('utf8')
 
-    def metas(self, start, stop):
-        """What a queue entry carries of each change of a run (its buffer:
-        the exact path decodes a queued change again before it drains it,
-        and the turbo path parses it again). The hashes and dependencies of
-        the run are hexed once and cut, not change by change."""
+    def metas(self, indexes):
+        """What a queue entry carries of each change in `indexes` (an
+        int64 array; with its buffer: the exact path decodes a queued
+        change again before it drains it, and the turbo path parses it
+        again). Their hashes are hexed in one piece and cut."""
         m = self.m
-        hashes = m['hash32'][start:stop].tobytes().hex()
-        off = m['deps_off'][start:stop + 1].tolist()
-        deps = m['deps_blob'][32 * off[0]:32 * off[-1]].hex()
-        cut = [64 * (j - off[0]) for j in off]
+        hashes = m['hash32'][indexes].tobytes().hex()
+        blob = m['deps_blob']
+        deps = [blob[32 * lo:32 * hi].hex() for lo, hi in zip(
+            m['deps_off'][indexes].tolist(),
+            m['deps_off'][indexes + 1].tolist())]
         actors = self.actors
+        buffers = self.buffers
         return [{'actor': actors[actor], 'seq': seq, 'startOp': start_op,
-                 'deps': [deps[at:at + 64]
-                          for at in range(cut[k], cut[k + 1], 64)],
+                 'deps': [dep] if len(dep) == 64 else
+                 [dep[at:at + 64] for at in range(0, len(dep), 64)],
                  'hash': hashes[64 * k:64 * k + 64],
-                 'buffer': self.buffers[start + k], 'ops': range(nops),
-                 '_change_index': start + k}
-                for k, (actor, seq, start_op, nops) in enumerate(zip(
-                    m['actor'][start:stop].tolist(),
-                    m['seq'][start:stop].tolist(),
-                    m['startOp'][start:stop].tolist(),
-                    m['nops'][start:stop].tolist()))]
-
-    def chain_links(self, run_starts):
-        """Per change: does it continue the change before it in the buffer
-        (its one dependency is that change's hash, its actor the same, its
-        seq the next)? False at every `run_starts` (a document's first).
-        One pass of compares over the parser's lanes."""
-        m = self.m
-        n = len(m['seq'])
-        link = np.zeros(n, dtype=bool)
-        if n > 1:
-            off = m['deps_off']
-            deps = np.frombuffer(m['deps_blob'], dtype=np.uint8)
-            deps = deps[:len(deps) - len(deps) % 32].reshape(-1, 32)
-            one = np.flatnonzero(off[2:] - off[1:-1] == 1) + 1
-            one = one[(deps[off[one]] == m['hash32'][one - 1]).all(axis=1)]
-            link[one] = (m['actor'][one] == m['actor'][one - 1]) & \
-                (m['seq'][one] == m['seq'][one - 1] + 1)
-        link[run_starts] = False
-        return link
-
-    def segments(self, start, stop, link, clock):
-        """The run [start, stop) of one document as the general gate takes
-        it (`_gate_segments`): its hashes (hex), the bounds of its chain
-        segments (places in the run; a segment is a change and every
-        change after it that `link` ties to the one before), of each
-        segment's first change the dependencies, the actor and the seq,
-        and `again`: a change of the run may have been applied or may
-        stand in it twice (a hash twice in the run; a seq that `clock`,
-        the document's, has reached: a change whose seq is past its
-        actor's is in no history). Every change is then a segment of its
-        own, and the gate asks of each whether it is delivered again."""
-        m = self.m
-        actors = self.actors
-        blob = m['hash32'][start:stop].tobytes().hex()
-        hashes = [blob[at:at + 64] for at in range(0, len(blob), 64)]
-        ids = m['actor'][start:stop].tolist()
-        seqs = m['seq'][start:stop].tolist()
-        reached = {a: clock.get(actors[a], 0) for a in set(ids)}
-        again = len(set(hashes)) != len(hashes) or \
-            any(seq <= reached[a] for a, seq in zip(ids, seqs))
-        bounds = list(range(stop - start)) if again else \
-            np.flatnonzero(~link[start:stop]).tolist()
-        off = m['deps_off'][start:stop + 1].tolist()
-        blob = m['deps_blob'][32 * off[0]:32 * off[-1]].hex()
-        deps = [[blob[at:at + 64] for at in range(
-            64 * (off[k] - off[0]), 64 * (off[k + 1] - off[0]), 64)]
-            for k in bounds]
-        return (hashes, bounds + [stop - start], deps,
-                [actors[ids[k]] for k in bounds],
-                [seqs[k] for k in bounds], again)
+                 'buffer': buffers[i], 'ops': range(nops)}
+                for k, (i, actor, seq, start_op, nops, dep) in enumerate(zip(
+                    indexes.tolist(), m['actor'][indexes].tolist(),
+                    m['seq'][indexes].tolist(),
+                    m['startOp'][indexes].tolist(),
+                    m['nops'][indexes].tolist(), deps))]
 
     def resolve(self, i):
         """(hash, deps, actor, changes_meta entry) for HashGraph._ensure_graph."""
@@ -4101,14 +4051,16 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     applied order, so the sequence dispatch, the register batch and the
     grid see every document's ops as they were applied. Neither a queue
     nor a document off both native gates sends the call to the exact path.
-    The general gate runs the reference's fixed point over a document's
-    run cut into chain segments (`_gate_segments`: a change and the changes
-    that each follow only the one before them are applied or queued
-    together, as the reference does change by change), asks history through
-    `_FlatEngine._applied_hashes` (a set of hashes, no query dicts) only
-    for a dependency that is no change of the run, and the staged commit leaves one lazy hash-graph record a document, as a
-    seam segment does: a document that passes the general gate every other
-    call builds no graph for it.
+    The general gate runs the reference's fixed point in native code, for
+    all of a call's off-chain documents in one call (`native.general_gate`,
+    change by change as `HashGraph._drain_queue` runs it), and asks history
+    of `_FlatEngine._history_index` (the applied hashes as bytes under an
+    exact table, no query dicts) only what neither the run nor the heads
+    answer; the staged commit leaves one lazy hash-graph record a document,
+    as a seam segment does: a document that passes the general gate every
+    other call builds no graph for it. A document whose current heads are
+    no 32-byte hashes cannot be put to a native gate and sends the call to
+    the exact path.
 
     Phase attribution: when spans are enabled the call tiles into
     contiguous `turbo_setup` / `turbo_parse` / `turbo_gate` /
@@ -4117,9 +4069,10 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     `seam.untraced_ms_per_step` reads), with the native parse / device
     dispatch sub-spans nested inside. `turbo_gate` and `turbo_commit`
     are tiled in turn by a second sequence: `gate.chain` / `gate.dag`
-    / `gate.shape` / `gate.decode` / `gate.general` (per document that
-    reaches it a `gate.meta` and a `gate.drain` span) / `gate.order` /
-    `gate.validate`, and `commit.columnar` / `commit.staged` /
+    / `gate.shape` / `gate.decode` / `gate.general` (`docs`, the documents
+    that reach it, and `history_probes`, what it asked of their history
+    indexes) / `gate.order` / `gate.validate`, and `commit.columnar` /
+    `commit.staged` /
     `commit.handles` — named without the `turbo_` prefix, so readers that
     sum `turbo_*` count each millisecond once. `turbo_gate` carries why
     documents left the chain path (`offchain_native` / `offchain_heads` /
@@ -4299,19 +4252,21 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # kernel refuses goes to the general gate untouched.
     chain_mask = fast_mask
     offchain_dag = dag_seq_docs = 0
-    cand = ~chain_mask & has_changes & seq_ok
+    off_chain = ~chain_mask & has_changes
+    # a frontier the columns cannot hold (several heads), as bytes for the
+    # native gates, the general one below too
+    multi_heads = {}
+    for d in np.flatnonzero(off_chain & (head_n_d == -1)).tolist():
+        heads = engines[d].heads
+        try:
+            blob = bytes.fromhex(''.join(heads))
+        except (TypeError, ValueError):
+            blob = b''
+        if len(blob) != 32 * len(heads):
+            return None   # heads that are no hashes: no native gate's
+        multi_heads[d] = blob
+    cand = off_chain & seq_ok
     if cand.any():
-        multi_heads = {}
-        for d in np.flatnonzero(cand & (head_n_d == -1)).tolist():
-            heads = engines[d].heads
-            try:
-                blob = bytes.fromhex(''.join(heads))
-            except (TypeError, ValueError):
-                blob = b''
-            if len(blob) == 32 * len(heads):
-                multi_heads[d] = blob
-            else:
-                cand[d] = False   # heads that are no hashes: general gate
         dag = native.dag_gate(doc_off, hash32, nmeta['deps_off'],
                               nmeta['deps_blob'], head32_d, head_n_d,
                               multi_heads, cand)
@@ -4449,66 +4404,79 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # exact path gets per-doc atomicity from fleet.pending instead).
     ready = fast_mask[doc_of]    # fancy-indexed: a fresh, writable array
     staged = []                  # general-path: (engine, applied at, queue)
-    backups = []                 # (engine, clock, heads, queue)
+    backups = []                 # (engine, clock, heads)
     # where the general gate applies a document's changes in another order
     # than the buffer's, the sort key that brings its changes into it
     rank = None
     waiting = []                 # the changes left in a queue, by index
     heldback = drained = 0       # changes of the call queued; of a queue applied
+    history_probes = 0           # what the general gate asked of history
 
     def restore_all():
-        for engine, clock, heads, queue in backups:
-            engine.clock, engine.heads, engine.queue = clock, heads, queue
+        for engine, clock, heads in backups:
+            engine.clock, engine.heads = clock, heads
 
-    offchain = np.flatnonzero(~fast_mask & has_changes).tolist()
+    off_both = ~fast_mask & has_changes
+    offchain = np.flatnonzero(off_both)
     sub.mark('gate.general', docs=len(offchain))
-    if offchain:
-        link = batch_meta.chain_links(starts_all[has_changes])
-    for d in offchain:
-        engine = engines[d]
-        start, stop = per_doc_idx[d]
-        clock, heads = dict(engine.clock), list(engine.heads)
-        backups.append((engine, clock, heads, engine.queue))
-        if n_queued[d]:
-            engine.queue = []    # its changes are the tail of the run
-        try:
-            with _span('gate.meta', doc=d, changes=stop - start):
-                run = batch_meta.segments(start, stop, link, clock)
-            with _span('gate.drain', doc=d, changes=stop - start):
-                order, left = _gate_segments(engine, clock, heads, *run,
-                                             engine._applied_hashes)
-        except Exception as exc:
-            restore_all()
-            # Gate errors are doc-scoped by construction (the drain loop
-            # runs one doc's changes): type them so a quarantining caller
-            # can reject slot d and retry the batch without it
-            if isinstance(exc, AutomergeError):
-                if exc.doc_index is None:
-                    exc.doc_index = d
-                raise
-            if isinstance(exc, ValueError):
-                raise InvalidChange(str(exc), doc_index=d) from exc
-            raise
-        bounds = run[1]
-        at = [i for k in order
-              for i in range(start + bounds[k], start + bounds[k + 1])]
-        queue = [entry for k in left for entry in batch_meta.metas(
-            start + bounds[k], start + bounds[k + 1])]
-        staged.append((engine, at, queue))
-        ready[at] = True
-        if order != sorted(order):
+    if len(offchain):
+        gated = native.general_gate(
+            doc_off, nmeta['actor'], seqs, hash32, nmeta['deps_off'],
+            nmeta['deps_blob'], head32_d, head_n_d, multi_heads, off_both,
+            g_doc, g_actor, base, lambda d: engines[d]._history_index())
+        if gated is None:
+            return None
+        (applied, app_off, left, left_off, new_heads, nh_off, g_seq, errors,
+         history_probes) = gated
+        if errors:
+            # the reference's error, of the first document that has one;
+            # nothing is committed yet. Gate errors are doc-scoped by
+            # construction: typed so that a quarantining caller can reject
+            # slot d and retry the batch without it
+            d, i, expected = errors[0]
+            seq, actor = int(seqs[i]), nat_actors[int(nmeta['actor'][i])]
+            raise InvalidChange(
+                f'Reuse of sequence number {seq} for actor {actor}'
+                if seq < expected else
+                f'Skipped sequence number {expected} for actor {actor}',
+                doc_index=d)
+        ready[applied] = True
+        if (applied[1:] < applied[:-1]).any():
             # a drained tail applies after the change that frees it,
             # wherever either stands in the buffer: the k-th change applied
-            # takes the k-th place among those applied
-            if rank is None:
-                rank = np.arange(n_changes)
-            rank[at] = sorted(at)
-        of_queue = stop - int(n_queued[d])
-        drained += sum(i >= of_queue for i in at)
-        still = [i for k in left
-                 for i in range(start + bounds[k], start + bounds[k + 1])]
-        heldback += sum(i < of_queue for i in still)
-        waiting += still
+            # takes the k-th place among those applied (documents' changes
+            # stand apart, so one sort orders each document's)
+            rank = np.arange(n_changes)
+            rank[applied] = np.sort(applied)
+        of_queue = starts_all + doc_counts - n_queued
+        drained = int((applied >= of_queue[doc_of[applied]]).sum())
+        heldback = int((left < of_queue[doc_of[left]]).sum())
+        waiting = left.tolist()
+        queued = batch_meta.metas(left)
+        applied = applied.tolist()
+        app_off, left_off = app_off.tolist(), left_off.tolist()
+        nh_off = nh_off.tolist()
+        heads_hex = new_heads.tobytes().hex()
+        # the groups whose actor's seq the gate moved, a document's together
+        moved = np.flatnonzero(g_seq != base)
+        moved_actor = [nat_actors[a] for a in g_actor[moved].tolist()]
+        moved_seq = g_seq[moved].tolist()
+        moved_off = np.searchsorted(g_doc[moved],
+                                    np.arange(len(handles) + 1)).tolist()
+        for d in offchain.tolist():
+            engine = engines[d]
+            at = applied[app_off[d]:app_off[d + 1]]
+            if at:
+                clock, heads = engine.clock, engine.heads
+                backups.append((engine, clock, heads))
+                engine.heads = [heads_hex[64 * j:64 * j + 64]
+                                for j in range(nh_off[d], nh_off[d + 1])]
+                lo, hi = moved_off[d], moved_off[d + 1]
+                engine.clock = {**clock, **dict(zip(moved_actor[lo:hi],
+                                                    moved_seq[lo:hi]))}
+            staged.append((engine, at,
+                           queued[left_off[d]:left_off[d + 1]]))
+    sub.note(history_probes=history_probes)
     fast_queued = np.flatnonzero(fast_mask & (n_queued > 0)).tolist()
     drained += int(n_queued[fast_queued].sum())
     ps.note(heldback_changes=heldback, drained_changes=drained)
@@ -4536,6 +4504,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     fleet.metrics.turbo_calls += 1
     fleet.metrics.heldback_changes += heldback
     fleet.metrics.drained_changes += drained
+    fleet.metrics.history_probes += history_probes
     # Validation from the native rows: duplicate opIds *within* the
     # applied batch are detectable per doc without decoding op objects.
     kept_change = rows['doc'][keep]      # native 'doc' is the change index
@@ -5169,79 +5138,6 @@ def _row_kinds(flags):
     makes at map keys, makes inside sequences)."""
     return ((flags >= 3) & (flags <= 6), (flags >= 7) & (flags <= 10),
             flags >= 11)
-
-
-def _gate_segments(engine, clock, heads, hashes, bounds, deps, actors, seqs,
-                   again, applied):
-    """The reference's causal gate run to its fixed point (new.js:1550-1586
-    and 1825-1841; `HashGraph._causal_gate` / `_drain_queue` are the host
-    oracle's) over one document's run cut into chain segments
-    (`_TurboMetaBatch.segments`): segment k is the changes
-    `bounds[k]:bounds[k + 1]` of the run, `deps[k]`, `actors[k]` and
-    `seqs[k]` are its first change's. The changes after the first each
-    follow the one before them and nothing else, with its actor and the
-    next seq, and none of them was seen before, so the reference applies
-    them in the pass that applies the first, right behind it, or queues
-    them with it: a pass over the segments is the reference's pass over
-    the changes. `clock` and `heads` are the document's; `applied()` gives
-    the hashes of every change applied before this call, and is asked only
-    for a dependency that is no change of the run, and, where `again` says
-    a change may be delivered again, for each change's own hash and every
-    dependency this call has not applied.
-
-    Returns (the segments applied, in the order applied; the segments left
-    in the queue, in the run's order) and advances `engine.heads` and
-    `engine.clock` where something was applied. Raises the reference's
-    ValueError for a ready change whose seq is not its actor's next."""
-    heads = set(heads)
-    clock = dict(clock)
-    known = None
-    done = set()                 # the hashes this call applied
-    in_run = set(hashes)
-    order = []
-    left = range(len(bounds) - 1)
-    while True:
-        waiting = []
-        before = len(order)
-        for k in left:
-            lo, hi = bounds[k], bounds[k + 1]
-            if again:
-                if known is None:
-                    known = applied()
-                if hashes[lo] in known or hashes[lo] in done:
-                    continue     # delivered again: applied once
-            for dep in deps[k]:
-                if dep in done:
-                    continue
-                if dep in in_run and not again:
-                    waiting.append(k)    # a change of the run, not yet
-                    break                # applied and in no history
-                if known is None:
-                    known = applied()
-                if dep not in known:
-                    waiting.append(k)
-                    break
-            else:
-                actor, seq = actors[k], seqs[k]
-                expected = clock.get(actor, 0) + 1
-                if seq < expected:
-                    raise ValueError(f'Reuse of sequence number {seq} '
-                                     f'for actor {actor}')
-                if seq > expected:
-                    raise ValueError(f'Skipped sequence number {expected} '
-                                     f'for actor {actor}')
-                clock[actor] = seq + hi - lo - 1
-                done.update(hashes[lo:hi])
-                heads.difference_update(deps[k])
-                heads.add(hashes[hi - 1])
-                order.append(k)
-        left = waiting
-        if len(order) == before or not left:
-            break
-    if order:
-        engine.heads = sorted(heads)
-        engine.clock = clock
-    return order, left
 
 
 def _rows_in_order(rows, order):

@@ -58,8 +58,8 @@ import jax.numpy as jnp
 __all__ = ['HashIndex', 'FleetFrontierIndex', 'PeerSentSet',
            'flush_peer_sets', 'probe_peer_sets', 'release_sent_hashes',
            'release_sync_state', 'frontier_compare', 'hashes_to_rows',
-           'engine_hash_population', 'dispatch_count', 'probe_window',
-           'set_probe_window']
+           'engine_hash_rows', 'HistoryIndex', 'dispatch_count',
+           'probe_window', 'set_probe_window']
 
 _GOLD = np.uint32(0x9E3779B9)     # Fibonacci-hash mix for the space id
 
@@ -722,55 +722,99 @@ def probe_peer_sets(peer_sets, hash_lists):
 
 # ---- fleet wiring ----------------------------------------------------
 
-def engine_hash_population(engine, since=None):
-    """Every APPLIED change hash (hex) of a backend engine, WITHOUT
-    building the hash-graph query dicts: materialized graph keys, then
-    deferred records served from their cheapest lane — the native
-    extractor's hash array for a parked prefix, the turbo parser's
-    hash32 lanes for pending seam segments — with a per-change header
-    decode only for records that have neither. Queued (causally
-    premature) changes are excluded, matching get_change_by_hash.
-    `since`: only the deferred records from that one on (what a caller
-    that keeps the population has not seen yet)."""
-    out = list(engine.change_index_by_hash.keys()) if since is None else []
-    pending = getattr(engine, '_doc_pending', None)
-    if pending is not None:
-        # fills _doc_hashes via the native extractor when available;
-        # today's sync rounds materialize these docs anyway (the graph
-        # walk in get_change_hashes), so this forces nothing new
-        engine._materialize_doc()
-    doc_hashes = getattr(engine, '_doc_hashes', None)
-    doc_decoded = getattr(engine, '_doc_decoded', None)
-    for entry in engine._deferred[since or 0:]:
-        if len(entry) == 3:
-            _index, batch, i = entry
-            idxs = i if isinstance(i, (list, tuple, range)) else [i]
-            hash_of = getattr(batch, 'hash_hex', None)
-            eng_ref = getattr(batch, 'engine', None)
-            lanes = getattr(batch, 'm', None)
-            if lanes is not None and len(idxs) > 1:
-                # the turbo parser's hash lanes: one hex of the run
-                rows = lanes['hash32'][idxs.start:idxs.stop] \
-                    if isinstance(idxs, range) and idxs.step == 1 \
-                    else lanes['hash32'][np.asarray(idxs, dtype=np.int64)]
-                blob = rows.tobytes().hex()
-                out.extend(blob[k:k + 64] for k in range(0, len(blob), 64))
+def engine_hash_rows(engine, since=None):
+    """Every APPLIED change hash of a backend engine as [n, 32] uint8
+    rows, WITHOUT building the hash-graph query dicts: materialized graph
+    keys, then deferred records served from their cheapest byte lane — the
+    turbo parser's hash32 rows as they are for pending seam segments and
+    staged commits, one ``bytes.fromhex`` over the native extractor's hash
+    list for a parked prefix — with a per-change header decode only for
+    records that have neither. Queued (causally premature) changes are
+    excluded, matching get_change_by_hash. `since`: only the deferred
+    records from that one on (what a caller that keeps the population has
+    not seen yet)."""
+    parts = []   # [k, 32] uint8 arrays and lists of hex strings, in order
+    records = engine._deferred
+    if since is None:
+        if engine.change_index_by_hash:
+            parts.append(list(engine.change_index_by_hash))
+    else:
+        records = records[since:]
+    for entry in records:
+        if len(entry) != 3:
+            parts.append([entry[1]])
+            continue
+        _index, batch, idxs = entry
+        if not isinstance(idxs, (list, tuple, range)):
+            idxs = [idxs]
+        lanes = getattr(batch, 'm', None)
+        if lanes is not None:
+            # the turbo parser's hash lanes, as they are (a seam segment's
+            # run of them, a staged commit's applied changes)
+            parts.append(lanes['hash32'][idxs.start:idxs.stop]
+                         if type(idxs) is range and idxs.step == 1
+                         else lanes['hash32'][np.asarray(idxs,
+                                                         dtype=np.int64)])
+            continue
+        if getattr(batch, 'engine', None) is engine:
+            # a parked prefix: the native extractor's hashes, else the
+            # decoded changes' (the materialize fills one of the two;
+            # today's sync rounds materialize these docs anyway — the
+            # graph walk in get_change_hashes — so this forces nothing new)
+            engine._materialize_doc()
+            doc_hashes = getattr(engine, '_doc_hashes', None)
+            doc_decoded = getattr(engine, '_doc_decoded', None)
+            if doc_hashes is not None and type(idxs) is range and \
+                    idxs.step == 1 and idxs.stop <= len(doc_hashes):
+                parts.append(doc_hashes[idxs.start:idxs.stop])
                 continue
-            for j in idxs:
-                j = int(j)
-                if eng_ref is engine and doc_hashes is not None and \
-                        j < len(doc_hashes):
-                    out.append(doc_hashes[j])
-                elif eng_ref is engine and doc_decoded is not None and \
-                        j < len(doc_decoded):
-                    out.append(doc_decoded[j]['hash'])
-                elif hash_of is not None:
-                    out.append(hash_of(j))
-                else:
-                    out.append(batch.resolve(j)[0])
-        else:
-            out.append(entry[1])
-    return out
+            if doc_decoded is not None:
+                parts.append([doc_decoded[int(j)]['hash'] for j in idxs])
+                continue
+        parts.append([batch.resolve(int(j))[0] for j in idxs])
+    parts = [hashes_to_rows(part) for part in parts if len(part)]
+    if len(parts) == 1:
+        return parts[0]
+    return np.concatenate(parts) if parts else \
+        np.zeros((0, 32), dtype=np.uint8)
+
+
+class HistoryIndex:
+    """Exact membership over ONE document's applied change hashes, on the
+    host, for the turbo path's general causal gate (native.general_gate
+    reads it in place): the hashes as ``rows[:n]``, [., 32] uint8, and
+    over them an open-addressing table of row numbers (int32, -1 free, a
+    power of two between 1.5 and 3 entries a hash; the first 8 bytes of a
+    hash pick the slot, the 32-byte compare confirms). ``extend`` only
+    takes rows in; the gate enters ``rows[entered:n]`` into the table
+    before it asks, so a document is fed when it is asked about. Both are
+    numpy arrays this object owns, so dropping it frees them; rows grow a
+    sixteenth at a time: at most 34 + 12 bytes a hash."""
+
+    __slots__ = ('rows', 'n', 'table', 'entered')
+
+    def __init__(self):
+        self.rows = np.empty((0, 32), dtype=np.uint8)
+        self.n = self.entered = 0
+        self.table = np.full(16, -1, dtype=np.int32)
+
+    @property
+    def nbytes(self):
+        return self.rows.nbytes + self.table.nbytes
+
+    def extend(self, rows):
+        """Take ``rows`` ([k, 32] uint8) into the population."""
+        first, n = self.n, self.n + len(rows)
+        if n > len(self.rows):
+            grown = np.empty((n + max(n >> 4, 32), 32), dtype=np.uint8)
+            grown[:first] = self.rows[:first]
+            self.rows = grown
+        self.rows[first:n] = rows
+        self.n = n
+        if 3 * n > 2 * len(self.table):
+            self.table = np.full(_pow2(n + (n + 1) // 2, 16), -1,
+                                 dtype=np.int32)
+            self.entered = 0
 
 
 class FleetFrontierIndex:
@@ -779,7 +823,7 @@ class FleetFrontierIndex:
     side (no dispatch on the commit fast path), and the next probe
     flushes the backlog in one insert dispatch. Registration backfills a
     doc's existing history once (cheap lanes, see
-    ``engine_hash_population``); slot frees release the space
+    ``engine_hash_rows``); slot frees release the space
     (reclaimed at the next migration — tombstone-free)."""
 
     def __init__(self, fleet, device_min=None, capacity=1024):
@@ -803,10 +847,10 @@ class FleetFrontierIndex:
             return None
         sid = self.table.new_space()
         self._spaces[slot] = sid
-        hashes = engine_hash_population(engine)
+        rows = engine_hash_rows(engine)
         _stats.inc('hashindex_backfills')
-        if hashes:
-            self.table.insert(sid, hashes_to_rows(hashes))
+        if len(rows):
+            self.table.insert(sid, rows)
         return sid
 
     def registered(self, engine):

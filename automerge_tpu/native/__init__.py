@@ -18,9 +18,19 @@ entry releases it inside C++ after gathering buffer pointers), which is
 what lets fleet.backend's pipelined turbo path overlap the parse of
 sub-batch k+1 with the device dispatch of sub-batch k.
 
-A compiled binary carries an ABI stamp (``am_abi_version``); a stale .so
-that cannot be rebuilt fails loudly at import instead of silently running
-an old single-threaded codec (see tools/build_native.sh).
+The turbo path's causal gates run here too, over the parser's columns and
+with the GIL released: ``turbo_gate`` (am_turbo_gate: documents that are
+one chain), ``dag_gate`` (am_dag_gate: documents whose batch is causally
+ordered) and ``general_gate`` (am_general_gate: the reference's gate run
+to its fixed point, change by change, for the documents the other two
+refuse — changes out of order, held back, delivered again — asking a
+document's history of a byte index the engine keeps,
+``fleet.hashindex.HistoryIndex``). These three have no Python form: the
+turbo path returns None without the codec.
+
+A compiled binary carries an ABI stamp (``am_abi_version``, now 5); a
+stale .so that cannot be rebuilt fails loudly at import instead of
+silently running an old single-threaded codec (see tools/build_native.sh).
 """
 
 import ctypes
@@ -41,7 +51,7 @@ from ..observability.spans import span as _span
 # Bumped in lockstep with codec.cpp's am_abi_version whenever the C
 # surface changes shape. A mismatch means the cached .so predates this
 # wrapper (or vice versa) and MUST NOT be used.
-_ABI_VERSION = 4
+_ABI_VERSION = 5
 
 
 class NativeAbiMismatch(RuntimeError):
@@ -797,6 +807,20 @@ def turbo_gate(doc_off, actor, seq, hash32, deps_off, deps_blob,
             g_doc[:k], g_actor[:k], g_first[:k], g_last[:k])
 
 
+def _multi_head_columns(multi_heads, n_docs):
+    """The frontiers the head32 column cannot hold, ragged: ``multi_heads``
+    (batch position -> the concatenated 32-byte hashes) as offsets per
+    document and one blob."""
+    mh_off = np.zeros(n_docs + 1, dtype=np.int64)
+    for d, blob in multi_heads.items():
+        mh_off[d + 1] = len(blob) // 32
+    np.cumsum(mh_off, out=mh_off)
+    mh_blob = np.frombuffer(
+        b''.join(multi_heads[d] for d in sorted(multi_heads)) or b'\0',
+        dtype=np.uint8)
+    return mh_off, mh_blob
+
+
 def dag_gate(doc_off, hash32, deps_off, deps_blob, head32, head_n,
              multi_heads, cand):
     """Batched causal gate for documents off the chain that are still
@@ -848,13 +872,7 @@ def dag_gate(doc_off, hash32, deps_off, deps_blob, head32, head_n,
             head32.size != 32 * n_docs or \
             not len(head_n) == len(cand) == n_docs:
         raise ValueError('dag_gate: columns disagree with doc_off')
-    mh_off = np.zeros(n_docs + 1, dtype=np.int64)
-    for d, blob in multi_heads.items():
-        mh_off[d + 1] = len(blob) // 32
-    np.cumsum(mh_off, out=mh_off)
-    mh_blob = np.frombuffer(
-        b''.join(multi_heads[d] for d in sorted(multi_heads)) or b'\0',
-        dtype=np.uint8)
+    mh_off, mh_blob = _multi_head_columns(multi_heads, n_docs)
     dag_ok = np.zeros(max(n_docs, 1), dtype=np.uint8)
     nh_off = np.zeros(n_docs + 1, dtype=np.int64)
     nh_cap = n_changes + n_docs + int(mh_off[-1])
@@ -871,6 +889,157 @@ def dag_gate(doc_off, hash32, deps_off, deps_blob, head32, head_n,
         return None
     # copied: the view would keep the worst-case buffer alive
     return dag_ok[:n_docs].astype(bool), nh_off, nh[:int(nh_off[-1])].copy()
+
+
+def _ragged(buf, starts, counts):
+    """``buf[starts[d]:starts[d] + counts[d]]`` of every d end to end, and
+    the offsets of the pieces."""
+    off = np.zeros(len(counts) + 1, dtype=np.int64)
+    np.cumsum(counts, out=off[1:])
+    return buf[np.repeat(starts - off[:-1], counts) +
+               np.arange(off[-1])], off
+
+
+def general_gate(doc_off, actor, seq, hash32, deps_off, deps_blob, head32,
+                 head_n, multi_heads, cand, g_doc, g_actor, g_base, history):
+    """The reference's causal gate run to its fixed point, change by
+    change as `HashGraph._drain_queue` runs it, for every document ``cand``
+    marks, in native code over the parser's columns with the GIL released
+    (codec.cpp am_general_gate; documents fan out over the pool): the
+    documents neither `turbo_gate` nor `dag_gate` takes, whose run (what
+    the call brings, the held-back changes behind it) holds changes out
+    of order, changes whose dependency has not arrived, or changes
+    delivered again.
+
+    The columns are `dag_gate`'s with the parser's ``actor`` and ``seq``
+    lanes; ``g_doc`` / ``g_actor`` are the per-(document, actor) groups
+    `turbo_gate` emits and ``g_base`` each group's seq in the document's
+    clock. History is a document's current heads and its history index:
+    ``history(d)`` gives document d's `fleet.hashindex.HistoryIndex` (its
+    ``rows``, ``n`` of them, its ``table`` and how many rows that holds,
+    ``entered``; the gate enters the rest before it asks) and is called
+    only for a document whose run asks what neither the run nor the heads
+    answer (a dependency that is neither; the own hash of a change whose
+    seq its actor's clock has reached), so no index is built or fed for a
+    document that does not ask. Precondition: a document's current heads
+    are among its applied hashes (the engine keeps them so), since a
+    change whose hash is a current head is taken as applied without a
+    question to the index.
+
+    Returns None when the codec is unavailable or the columns are
+    malformed, else ``(applied, app_off, left, left_off, nh, nh_off, g_seq,
+    errors, probes)``: per document d the changes applied
+    (``applied[app_off[d]:app_off[d + 1]]``, change numbers in APPLIED
+    order) and the changes left waiting (in the run's order; a change
+    delivered again is in neither), the new heads (ragged ``[n, 32]``
+    uint8 rows in bytewise = hex order), per group the actor's seq after
+    the run, ``errors`` a list of ``(d, change, expected)`` for each
+    document the reference's gate raises on (a ready change whose seq is
+    not ``expected``, its actor's next; nothing of such a document is in
+    the other outputs), and the number of questions put to history
+    indexes. Nothing is mutated."""
+    lib = _load()
+    if lib is None:
+        return None
+    i64, ptr = ctypes.c_int64, ctypes.c_void_p
+    if not hasattr(lib, '_general_gate_ready'):
+        lib.am_general_gate.argtypes = [ptr] * 14 + [i64] + [ptr] * 5 + \
+            [i64] * 3 + [ptr] * 11
+        lib.am_general_gate.restype = i64
+        lib._general_gate_ready = True
+    n_docs = len(doc_off) - 1
+    doc_off = np.ascontiguousarray(doc_off, dtype=np.int64)
+    n_changes = int(doc_off[-1])
+    actor = np.ascontiguousarray(actor, dtype=np.int32)
+    seq = np.ascontiguousarray(seq, dtype=np.int64)
+    hash32 = np.ascontiguousarray(hash32, dtype=np.uint8)
+    deps_off = np.ascontiguousarray(deps_off, dtype=np.int64)
+    deps_arr = _deps_array(deps_blob)
+    head32 = np.ascontiguousarray(head32, dtype=np.uint8)
+    head_n = np.ascontiguousarray(head_n, dtype=np.int32)
+    cand = np.ascontiguousarray(cand, dtype=np.uint8)
+    g_doc = np.ascontiguousarray(g_doc, dtype=np.int32)
+    g_actor = np.ascontiguousarray(g_actor, dtype=np.int32)
+    g_base = np.ascontiguousarray(g_base, dtype=np.int64)
+    n_groups = len(g_doc)
+    if hash32.size != 32 * n_changes or len(deps_off) != n_changes + 1 or \
+            deps_arr.size < 32 * int(deps_off[-1]) or \
+            head32.size != 32 * n_docs or \
+            not len(head_n) == len(cand) == n_docs or \
+            not len(actor) == len(seq) == n_changes or \
+            not len(g_actor) == len(g_base) == n_groups:
+        raise ValueError('general_gate: columns disagree with doc_off')
+    n_actors = int(actor.max()) + 1 if n_changes else 1
+    mh_off, mh_blob = _multi_head_columns(multi_heads, n_docs)
+    hx_rows = np.zeros(max(n_docs, 1), dtype=np.uint64)
+    hx_table = np.zeros(max(n_docs, 1), dtype=np.uint64)
+    hx_size = np.zeros(max(n_docs, 1), dtype=np.int64)
+    hx_first = np.zeros(max(n_docs, 1), dtype=np.int64)
+    hx_n = np.zeros(max(n_docs, 1), dtype=np.int64)
+    state = np.zeros(max(n_docs, 1), dtype=np.uint8)
+    applied = np.empty(max(n_changes, 1), dtype=np.int64)
+    left = np.empty(max(n_changes, 1), dtype=np.int64)
+    n_applied = np.zeros(max(n_docs, 1), dtype=np.int64)
+    n_left = np.zeros(max(n_docs, 1), dtype=np.int64)
+    # a document's new heads from hash doc_off[d] + d + mh_off[d] on: room
+    # for its changes, its one columnar head and the ragged heads before
+    nh_at = doc_off[:-1] + np.arange(n_docs) + mh_off[:-1]
+    nh = np.empty((n_changes + n_docs + int(mh_off[-1]) + 1, 32),
+                  dtype=np.uint8)
+    nh_n = np.zeros(max(n_docs, 1), dtype=np.int64)
+    g_seq = g_base.copy()
+    err_change = np.full(max(n_docs, 1), -1, dtype=np.int64)
+    err_expected = np.zeros(max(n_docs, 1), dtype=np.int64)
+    probes = np.zeros(max(n_docs, 1), dtype=np.int64)
+    # (an empty column's address is never read)
+    columns = [a.ctypes.data for a in (
+        doc_off, actor, seq, hash32, deps_off, deps_arr, head32, head_n,
+        mh_off, mh_blob)]
+    groups = [g_doc.ctypes.data, g_actor.ctypes.data, g_base.ctypes.data,
+              n_groups, hx_rows.ctypes.data, hx_table.ctypes.data,
+              hx_size.ctypes.data, hx_first.ctypes.data, hx_n.ctypes.data,
+              n_docs, n_changes, n_actors]
+    outputs = [a.ctypes.data for a in (
+        state, applied, n_applied, left, n_left, nh, nh_n, g_seq,
+        err_change, err_expected, probes)]
+
+    def gate(marks):
+        return lib.am_general_gate(*columns, marks.ctypes.data, *groups,
+                                   *outputs)
+
+    asking = gate(cand)
+    if asking > 0:
+        # the documents whose run asks history: their indexes, then they
+        # alone again (the outputs of the others stand where they are)
+        again = np.flatnonzero(state[:n_docs] == 2)
+        held = [history(int(d)) for d in again]   # alive across the call
+        for d, index in zip(again.tolist(), held):
+            rows, table = index.rows, index.table
+            if rows.dtype != np.uint8 or rows.ndim != 2 or \
+                    rows.shape[1] != 32 or not rows.flags.c_contiguous or \
+                    table.dtype != np.int32 or table.ndim != 1 or \
+                    not table.flags.c_contiguous or \
+                    not 0 <= index.entered <= index.n <= len(rows):
+                raise ValueError('general_gate: a history index out of '
+                                 'shape')
+            hx_rows[d], hx_table[d] = rows.ctypes.data, table.ctypes.data
+            hx_size[d], hx_first[d], hx_n[d] = \
+                len(table), index.entered, index.n
+        marks = np.zeros(n_docs, dtype=np.uint8)
+        marks[again] = 1
+        asking = gate(marks)
+        if asking == 0:
+            for index in held:
+                index.entered = index.n
+    if asking != 0:
+        return None
+    applied, app_off = _ragged(applied, doc_off[:-1], n_applied[:n_docs])
+    left, left_off = _ragged(left, doc_off[:-1], n_left[:n_docs])
+    nh, nh_off = _ragged(nh, nh_at, nh_n[:n_docs])
+    errors = [(d, int(err_change[d]), int(err_expected[d]))
+              for d in np.flatnonzero(state[:n_docs] == 3).tolist()]
+    return (applied, app_off, left, left_off, nh, nh_off, g_seq, errors,
+            int(probes.sum()))
 
 
 def parse_documents(buffers):

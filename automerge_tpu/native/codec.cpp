@@ -1746,7 +1746,7 @@ int64_t am_ingest_changes_list(PyObject *buffers, int with_meta,
 // Monotone ABI stamp, bumped on any C-surface change. The Python wrapper
 // refuses to run against a binary whose stamp mismatches (a stale .so
 // would otherwise silently run the old single-threaded codec).
-int64_t am_abi_version() { return 4; }
+int64_t am_abi_version() { return 5; }
 
 int64_t am_pool_configure(int n) { return NativePool::inst().configure(n); }
 
@@ -1986,6 +1986,62 @@ int64_t am_turbo_gate(const int64_t *doc_off, const int32_t *actor,
   return n_groups;
 }
 
+// ---- a document's hashes of one call, under a table ------------------------
+//
+// What am_dag_gate and am_general_gate look hashes up in: one document's
+// n changes of the call (entry e < n is change lo + e) and behind them its
+// k current heads (entry e >= n is old head e - n; head_n 1 reads the
+// head32 row, -1 the ragged mh_off / mh_blob, 32 bytes a head), under an
+// open-addressing table (SHA-256: the first 8 bytes pick the slot, the
+// 32-byte compare confirms). The kernel enters what it wants found.
+static int64_t old_heads(int64_t d, const uint8_t *head32,
+                         const int32_t *head_n, const int64_t *mh_off,
+                         const uint8_t *mh_blob, const uint8_t **base) {
+  if (head_n[d] == 1) { *base = head32 + d * 32; return 1; }
+  if (head_n[d] == -1) {
+    *base = mh_blob + mh_off[d] * 32;
+    return mh_off[d + 1] - mh_off[d];
+  }
+  *base = nullptr;
+  return 0;
+}
+
+struct RunTable {
+  std::vector<int32_t> slots;
+  size_t mask = 0;
+  const uint8_t *hash32 = nullptr, *oh = nullptr;
+  int64_t lo = 0, n = 0, k = 0;
+
+  void reset(int64_t d, const int64_t *doc_off, const uint8_t *hashes,
+             const uint8_t *head32, const int32_t *head_n,
+             const int64_t *mh_off, const uint8_t *mh_blob) {
+    hash32 = hashes;
+    lo = doc_off[d];
+    n = doc_off[d + 1] - lo;
+    k = old_heads(d, head32, head_n, mh_off, mh_blob, &oh);
+    size_t size = 16;
+    while (size < size_t(2 * (n + k))) size <<= 1;
+    mask = size - 1;
+    slots.assign(size, -1);
+  }
+  const uint8_t *at(int32_t e) const {
+    return e < n ? hash32 + (lo + e) * 32 : oh + (e - n) * 32;
+  }
+  // the slot holding `h`, or the free slot where it would go
+  size_t probe(const uint8_t *h) const {
+    uint64_t key;
+    memcpy(&key, h, 8);
+    size_t s = size_t(key) & mask;
+    while (slots[s] >= 0 && memcmp(at(slots[s]), h, 32) != 0)
+      s = (s + 1) & mask;
+    return s;
+  }
+};
+
+static bool hash_before(const uint8_t *a, const uint8_t *b) {
+  return memcmp(a, b, 32) < 0;
+}
+
 // ---- batched DAG gate -----------------------------------------------------
 //
 // The causal gate for the documents am_turbo_gate refused that are still
@@ -2007,9 +2063,8 @@ int64_t am_turbo_gate(const int64_t *doc_off, const int32_t *actor,
 // Nothing is mutated; a document refused here goes on to the Python gate
 // untouched.
 //
-// Per document one open-addressing table over the hashes (SHA-256: the
-// first 8 bytes pick the slot, the 32-byte compare confirms) and a
-// referenced flag per change and per old head. Documents are independent
+// Per document one RunTable over the hashes and a referenced flag per
+// change and per old head. Documents are independent
 // and fan out over the pool in slices of about equal change counts; the
 // frontier is gathered serially afterwards (a flag scan).
 //
@@ -2032,17 +2087,8 @@ int64_t am_dag_gate(const int64_t *doc_off, const uint8_t *hash32,
       return -1;
     dag_ok[d] = 0;
   }
-  // old heads of doc d live at [d + mh_off[d], ...): room for the one
-  // columnar head plus every multi-head entry before it
-  auto old_heads = [&](int64_t d, const uint8_t **base) -> int64_t {
-    if (head_n[d] == 1) { *base = head32 + d * 32; return 1; }
-    if (head_n[d] == -1) {
-      *base = mh_blob + mh_off[d] * 32;
-      return mh_off[d + 1] - mh_off[d];
-    }
-    *base = nullptr;
-    return 0;
-  };
+  // the referenced flags of doc d's old heads live at [d + mh_off[d], ...):
+  // room for the one columnar head plus every multi-head entry before it
   std::vector<uint8_t> ref_c(size_t(n_changes), 0);
   std::vector<uint8_t> ref_h(size_t(n_docs + mh_off[n_docs]), 0);
   int threads = NativePool::inst().threads();
@@ -2055,46 +2101,28 @@ int64_t am_dag_gate(const int64_t *doc_off, const uint8_t *hash32,
     const int64_t *d_hi = t + 1 == n_slices ? doc_off + n_docs :
         std::lower_bound(doc_off, doc_off + n_docs,
                          n_changes * int64_t(t + 1) / n_slices);
-    std::vector<int32_t> table;
+    RunTable table;
     for (int64_t d = d_lo - doc_off; d < d_hi - doc_off; d++) {
       if (!cand[d]) continue;
-      int64_t lo = doc_off[d], hi = doc_off[d + 1], n = hi - lo;
-      const uint8_t *oh;
-      int64_t k = old_heads(d, &oh);
+      table.reset(d, doc_off, hash32, head32, head_n, mh_off, mh_blob);
+      int64_t lo = table.lo, n = table.n;
       uint8_t *rh = ref_h.data() + d + mh_off[d];
-      size_t size = 16;
-      while (size < size_t(2 * (n + k))) size <<= 1;
-      size_t mask = size - 1;
-      table.assign(size, -1);
-      // entry e < n is change lo + e, e >= n is old head e - n
-      auto at = [&](int32_t e) {
-        return e < n ? hash32 + (lo + e) * 32 : oh + (e - n) * 32;
-      };
-      // the slot holding `h`, or the free slot where it would go
-      auto probe = [&](const uint8_t *h) {
-        uint64_t key;
-        memcpy(&key, h, 8);
-        size_t s = size_t(key) & mask;
-        while (table[s] >= 0 && memcmp(at(table[s]), h, 32) != 0)
-          s = (s + 1) & mask;
-        return s;
-      };
       bool ok = true;
-      for (int64_t j = 0; j < k && ok; j++) {
-        size_t s = probe(oh + j * 32);
-        if (table[s] >= 0) ok = false;   // a frontier naming a hash twice
-        table[s] = int32_t(n + j);
+      for (int64_t j = 0; j < table.k && ok; j++) {
+        size_t s = table.probe(table.oh + j * 32);
+        if (table.slots[s] >= 0) ok = false;   // a frontier naming a hash twice
+        table.slots[s] = int32_t(n + j);
       }
-      for (int64_t i = lo; i < hi && ok; i++) {
+      for (int64_t i = lo; i < lo + n && ok; i++) {
         for (int64_t j = deps_off[i]; j < deps_off[i + 1]; j++) {
-          int32_t e = table[probe(deps_blob + j * 32)];
+          int32_t e = table.slots[table.probe(deps_blob + j * 32)];
           if (e < 0) { ok = false; break; }   // neither batch nor heads
           if (e < n) ref_c[size_t(lo + e)] = 1; else rh[e - n] = 1;
         }
         if (!ok) break;
-        size_t s = probe(hash32 + i * 32);
-        if (table[s] >= 0) ok = false;        // seen before: a duplicate
-        table[s] = int32_t(i - lo);
+        size_t s = table.probe(hash32 + i * 32);
+        if (table.slots[s] >= 0) ok = false;  // seen before: a duplicate
+        table.slots[s] = int32_t(i - lo);
       }
       dag_ok[d] = ok;
     }
@@ -2107,22 +2135,292 @@ int64_t am_dag_gate(const int64_t *doc_off, const uint8_t *hash32,
       taken++;
       heads.clear();
       const uint8_t *oh;
-      int64_t k = old_heads(d, &oh);
+      int64_t k = old_heads(d, head32, head_n, mh_off, mh_blob, &oh);
       const uint8_t *rh = ref_h.data() + d + mh_off[d];
       for (int64_t j = 0; j < k; j++)
         if (!rh[j]) heads.push_back(oh + j * 32);
       for (int64_t i = doc_off[d]; i < doc_off[d + 1]; i++)
         if (!ref_c[size_t(i)]) heads.push_back(hash32 + i * 32);
       if (out + int64_t(heads.size()) > nh_cap) return -1;
-      std::sort(heads.begin(), heads.end(),
-                [](const uint8_t *a, const uint8_t *b) {
-                  return memcmp(a, b, 32) < 0;
-                });
+      std::sort(heads.begin(), heads.end(), hash_before);
       for (const uint8_t *h : heads) memcpy(nh_blob + 32 * out++, h, 32);
     }
     nh_off[d + 1] = out;
   }
   return taken;
+}
+
+// ---- history index --------------------------------------------------------
+//
+// One document's applied change hashes as the caller keeps them: `rows`,
+// [n, 32] bytes, and over them an open-addressing table of row numbers
+// (-1 free; `size` a power of two the caller keeps under two thirds full;
+// the first 8 bytes of a hash pick the slot, the 32-byte compare confirms:
+// a prefix is a slot, never an answer). Both arrays are the caller's;
+// nothing here outlives the call.
+
+static inline bool history_holds(const uint8_t *rows, const int32_t *table,
+                                 int64_t size, const uint8_t *h) {
+  uint64_t key;
+  memcpy(&key, h, 8);
+  size_t mask = size_t(size) - 1, s = size_t(key) & mask;
+  while (table[s] >= 0) {
+    if (memcmp(rows + int64_t(table[s]) * 32, h, 32) == 0) return true;
+    s = (s + 1) & mask;
+  }
+  return false;
+}
+
+// Enter rows [first, n) into the table (a hash already there keeps its
+// first row).
+static void history_enter(const uint8_t *rows, int64_t first, int64_t n,
+                          int32_t *table, int64_t size) {
+  size_t mask = size_t(size) - 1;
+  for (int64_t r = first; r < n; r++) {
+    const uint8_t *h = rows + r * 32;
+    uint64_t key;
+    memcpy(&key, h, 8);
+    size_t s = size_t(key) & mask;
+    while (table[s] >= 0 && memcmp(rows + int64_t(table[s]) * 32, h, 32) != 0)
+      s = (s + 1) & mask;
+    if (table[s] < 0) table[s] = int32_t(r);
+  }
+}
+
+// ---- batched general causal gate -------------------------------------------
+//
+// The reference's causal gate run to its fixed point (new.js:1550-1586 and
+// 1825-1841; HashGraph._causal_gate / _drain_queue are the host oracle's)
+// for every candidate document of a call, over the parser's columns. A
+// document's run is its changes of the call with its held-back changes
+// behind them. Change by change, as the reference:
+//   - a pass walks the waiting changes in the run's order; a change whose
+//     hash history holds or this call has applied is delivered again and
+//     dropped; one with a dependency neither applied by this call nor held
+//     by history waits; a ready change whose seq is not its actor's next
+//     is the reference's error (the document stops there: state 3, the
+//     change and the seq expected are reported and the caller raises);
+//     else it is applied, which makes later changes of the same pass
+//     ready. Passes repeat until one applies nothing or nothing waits.
+//   - the applied order is part of the result.
+// History is the document's current heads (head_n / head32 / mh_* as
+// am_dag_gate reads them) and its history index (hx_rows / hx_table /
+// hx_size per document, addresses of the caller's arrays, 0 where none is
+// given; rows hx_first[d] to hx_n[d] are not in the table yet and are
+// entered first, the one write to anything of the caller's: the index is
+// fed when the gate asks). The index is asked only what the run and the
+// heads cannot answer: a dependency that is neither, and the own hash of
+// a change whose seq its actor's clock has reached (a change past its
+// actor's clock is in no history). A document that has such a question
+// and no index is left alone with state 2: the caller builds or feeds its
+// index and calls again with that document as the candidate. probes[d]
+// counts the questions put.
+//
+// The clock comes as the per-(document, actor) groups am_turbo_gate emits
+// (g_doc ascending) with g_base the actor's seq in the document's clock.
+//
+// Outputs, all at places fixed by the document so that a second call for
+// some documents overwrites theirs alone: state[d] (1 gated); applied /
+// left from doc_off[d] on, n_applied[d] / n_left[d] of them (change
+// numbers; applied in applied order, left in the run's order); the new
+// heads in bytewise order from hash doc_off[d] + d + mh_off[d] of nh_blob
+// on, nh_n[d] of them (old heads less every applied change's
+// dependencies, plus the applied hashes no applied change names); g_seq
+// per group, the actor's seq after the run. No heads, clock or queue is
+// mutated. Documents are independent and fan out over the pool as in
+// am_dag_gate. Returns the number of documents left with state 2, or -1
+// on malformed columns.
+int64_t am_general_gate(const int64_t *doc_off, const int32_t *actor,
+                        const int64_t *seq, const uint8_t *hash32,
+                        const int64_t *deps_off, const uint8_t *deps_blob,
+                        const uint8_t *head32, const int32_t *head_n,
+                        const int64_t *mh_off, const uint8_t *mh_blob,
+                        const uint8_t *cand, const int32_t *g_doc,
+                        const int32_t *g_actor, const int64_t *g_base,
+                        int64_t n_groups, const uint64_t *hx_rows,
+                        const uint64_t *hx_table, const int64_t *hx_size,
+                        const int64_t *hx_first, const int64_t *hx_n,
+                        int64_t n_docs, int64_t n_changes, int64_t n_actors,
+                        uint8_t *state, int64_t *applied, int64_t *n_applied,
+                        int64_t *left, int64_t *n_left, uint8_t *nh_blob,
+                        int64_t *nh_n, int64_t *g_seq, int64_t *err_change,
+                        int64_t *err_expected, int64_t *probes) {
+  if (n_docs < 0 || n_changes < 0 || n_groups < 0 || n_actors < 0) return -1;
+  for (int64_t d = 0; d < n_docs; d++) {
+    if (doc_off[d] > doc_off[d + 1] || doc_off[d] < 0 ||
+        doc_off[d + 1] > n_changes || mh_off[d] > mh_off[d + 1] ||
+        mh_off[d] < 0)
+      return -1;
+    if (cand[d] && hx_table[d] &&
+        (hx_size[d] < 2 || (hx_size[d] & (hx_size[d] - 1)) ||
+         hx_first[d] < 0 || hx_n[d] < hx_first[d] || hx_n[d] >= hx_size[d]))
+      return -1;
+  }
+  for (int64_t g = 0; g < n_groups; g++)
+    if (g_doc[g] < 0 || g_doc[g] >= n_docs || g_actor[g] < 0 ||
+        g_actor[g] >= n_actors || (g && g_doc[g] < g_doc[g - 1]))
+      return -1;
+  std::atomic<bool> malformed(false);
+  int64_t n_slices = int64_t(slice_count(uint64_t(n_docs),
+                                         NativePool::inst().threads()));
+  if (n_slices < 1) n_slices = 1;
+  NativePool::inst().run(int(n_slices), [&](int t, int) {
+    const int64_t *d_lo = std::lower_bound(
+        doc_off, doc_off + n_docs, n_changes * int64_t(t) / n_slices);
+    const int64_t *d_hi = t + 1 == n_slices ? doc_off + n_docs :
+        std::lower_bound(doc_off, doc_off + n_docs,
+                         n_changes * int64_t(t + 1) / n_slices);
+    RunTable table;
+    std::vector<int32_t> canon, dep_ent, waiting, still;
+    std::vector<int32_t> a_group(size_t(n_actors), -1);
+    std::vector<uint8_t> hist, done, ref_c, ref_h;
+    std::vector<int64_t> clock;
+    std::vector<const uint8_t *> heads;
+    // one document, its groups [g0, g0 + n_g) entered in a_group; false
+    // on a change of no group of its document
+    auto gate_one = [&](int64_t d, int64_t g0, int64_t n_g) {
+      table.reset(d, doc_off, hash32, head32, head_n, mh_off, mh_blob);
+      int64_t lo = table.lo, n = table.n, hi = lo + n, k = table.k;
+      const uint8_t *oh = table.oh;
+      const uint8_t *rows = reinterpret_cast<const uint8_t *>(hx_rows[d]);
+      int32_t *index = reinterpret_cast<int32_t *>(hx_table[d]);
+      if (index) history_enter(rows, hx_first[d], hx_n[d], index, hx_size[d]);
+      int64_t asked = 0;
+      bool unanswered = false;
+      // does history (less the heads, which the run's table holds) hold h
+      auto history = [&](const uint8_t *h) {
+        if (!index) { unanswered = true; return false; }
+        asked++;
+        return history_holds(rows, index, hx_size[d], h);
+      };
+      for (int64_t j = 0; j < k; j++) {
+        size_t s = table.probe(oh + j * 32);
+        if (table.slots[s] < 0) table.slots[s] = int32_t(n + j);
+      }
+      // canon[i]: the first change of the run with change i's hash;
+      // hist[i]: history holds that hash (asked once, of the first)
+      canon.assign(size_t(n), 0);
+      hist.assign(size_t(n), 0);
+      for (int64_t i = 0; i < n && !unanswered; i++) {
+        int32_t a = actor[lo + i];
+        if (a < 0 || a >= n_actors || a_group[size_t(a)] < 0) return false;
+        size_t s = table.probe(hash32 + (lo + i) * 32);
+        int32_t e = table.slots[s];
+        canon[size_t(i)] = int32_t(i);
+        if (e < 0) {
+          table.slots[s] = int32_t(i);
+          if (seq[lo + i] <= g_base[g0 + a_group[size_t(a)]])
+            hist[size_t(i)] = history(hash32 + (lo + i) * 32);
+        } else if (e >= n) {
+          hist[size_t(i)] = 1;  // a current head, delivered again
+        } else {
+          canon[size_t(i)] = e;
+        }
+      }
+      // dep_ent[j]: what dependency j waits for. >= 0: that change of the
+      // run applied; -1: nothing, history holds it; -2: it is nowhere;
+      // <= -3: nothing, it is old head -3 - dep_ent[j]
+      int64_t dep0 = deps_off[lo];
+      dep_ent.assign(size_t(deps_off[hi] - dep0), -2);
+      for (int64_t i = 0; i < n && !unanswered; i++) {
+        if (hist[size_t(canon[size_t(i)])]) continue;  // dropped unread
+        for (int64_t j = deps_off[lo + i]; j < deps_off[lo + i + 1]; j++) {
+          int32_t e = table.slots[table.probe(deps_blob + j * 32)];
+          if (e < 0)
+            dep_ent[size_t(j - dep0)] =
+                history(deps_blob + j * 32) ? -1 : -2;
+          else if (e >= n)
+            dep_ent[size_t(j - dep0)] = int32_t(-3 - (e - n));
+          else
+            dep_ent[size_t(j - dep0)] = hist[size_t(e)] ? -1 : e;
+        }
+      }
+      if (unanswered) { state[d] = 2; return true; }
+      probes[d] = asked;
+      clock.assign(g_base + g0, g_base + g0 + n_g);
+      done.assign(size_t(n), 0);
+      ref_c.assign(size_t(n), 0);
+      ref_h.assign(size_t(k), 0);
+      waiting.resize(size_t(n));
+      for (int64_t i = 0; i < n; i++) waiting[size_t(i)] = int32_t(i);
+      int64_t n_app = 0;
+      while (true) {
+        still.clear();
+        int64_t before = n_app;
+        for (int32_t i : waiting) {
+          int32_t c = canon[size_t(i)];
+          if (hist[size_t(c)] || done[size_t(c)]) continue;
+          bool ready = true;
+          for (int64_t j = deps_off[lo + i]; j < deps_off[lo + i + 1]; j++) {
+            int32_t e = dep_ent[size_t(j - dep0)];
+            if (e == -2 || (e >= 0 && !done[size_t(e)])) {
+              ready = false;
+              break;
+            }
+          }
+          if (!ready) { still.push_back(i); continue; }
+          int64_t &reached = clock[size_t(a_group[size_t(actor[lo + i])])];
+          if (seq[lo + i] != reached + 1) {
+            state[d] = 3;
+            err_change[d] = lo + i;
+            err_expected[d] = reached + 1;
+            return true;
+          }
+          reached = seq[lo + i];
+          done[size_t(c)] = 1;
+          applied[lo + n_app++] = lo + i;
+          for (int64_t j = deps_off[lo + i]; j < deps_off[lo + i + 1]; j++) {
+            int32_t e = dep_ent[size_t(j - dep0)];
+            if (e >= 0) ref_c[size_t(e)] = 1;
+            else if (e <= -3) ref_h[size_t(-3 - e)] = 1;
+          }
+        }
+        waiting.swap(still);
+        if (n_app == before || waiting.empty()) break;
+      }
+      n_applied[d] = n_app;
+      n_left[d] = int64_t(waiting.size());
+      for (size_t w = 0; w < waiting.size(); w++)
+        left[lo + int64_t(w)] = lo + waiting[w];
+      for (int64_t g = 0; g < n_g; g++) g_seq[g0 + g] = clock[size_t(g)];
+      heads.clear();
+      for (int64_t j = 0; j < k; j++)
+        if (!ref_h[size_t(j)]) heads.push_back(oh + j * 32);
+      for (int64_t a = 0; a < n_app; a++) {
+        int64_t i = applied[lo + a] - lo;
+        if (!ref_c[size_t(canon[size_t(i)])])
+          heads.push_back(hash32 + (lo + i) * 32);
+      }
+      std::sort(heads.begin(), heads.end(), hash_before);
+      // two old heads that are one hash stand once
+      heads.erase(std::unique(heads.begin(), heads.end(),
+                              [](const uint8_t *a, const uint8_t *b) {
+                                return memcmp(a, b, 32) == 0;
+                              }), heads.end());
+      uint8_t *out = nh_blob + (lo + d + mh_off[d]) * 32;
+      for (const uint8_t *h : heads) { memcpy(out, h, 32); out += 32; }
+      nh_n[d] = int64_t(heads.size());
+      state[d] = 1;
+      return true;
+    };
+    for (int64_t d = d_lo - doc_off; d < d_hi - doc_off; d++) {
+      if (!cand[d]) continue;
+      const int32_t *g_lo = std::lower_bound(g_doc, g_doc + n_groups,
+                                             int32_t(d));
+      const int32_t *g_hi = std::upper_bound(g_lo, g_doc + n_groups,
+                                             int32_t(d));
+      for (const int32_t *g = g_lo; g < g_hi; g++)
+        a_group[size_t(g_actor[g - g_doc])] = int32_t(g - g_lo);
+      if (!gate_one(d, g_lo - g_doc, g_hi - g_lo)) malformed = true;
+      for (const int32_t *g = g_lo; g < g_hi; g++)
+        a_group[size_t(g_actor[g - g_doc])] = -1;
+    }
+  });
+  if (malformed) return -1;
+  int64_t unanswered = 0;
+  for (int64_t d = 0; d < n_docs; d++)
+    if (cand[d] && state[d] == 2) unanswered++;
+  return unanswered;
 }
 
 // Copy sequence-op columns captured by am_ingest_changes(with_seq=1).

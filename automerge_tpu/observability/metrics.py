@@ -119,6 +119,10 @@ class Metrics:
         'drained_changes',       # changes a call applied out of a queue
         'heldback_docs',         # documents of a call whose queue is not
                                  # empty after it
+        'history_probes',        # what the general gate asked of history
+                                 # indexes: dependencies neither the run
+                                 # nor the heads meet, own hashes of
+                                 # changes their actor's clock has reached
         # the sequence engine (fleet/backend.py _dispatch_seq)
         'seq_ops',               # real sequence ops dispatched
         'seq_op_cells',          # rows x width of the op columns handed to

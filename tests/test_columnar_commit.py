@@ -299,23 +299,26 @@ class TestColumnarDocState:
         rollback shape) must clear the tail lanes — a stale lane would
         hand the gate a phantom seq base and fast-commit a change the
         causal gate should queue."""
+        from automerge_tpu.errors import InvalidChange
         A, B = 'aa' * 16, 'bb' * 16
         fleet = DocFleet(doc_capacity=1, key_capacity=8)
         handles = init_docs(1, fleet)
+        a1 = _change(A, 1, 1, [], 'k', 1)
+        handles, _ = apply_changes_docs(handles, [[a1]], mirror=False)
         impl = handles[0]['state']._impl
         impl.clock = {A: 1, B: 1}
         impl.clock = {A: 1}              # rollback-shaped shrink
         assert (fleet.doc_cols.ck_actor[impl.slot, 1:] == -1).all()
         assert impl.clock == {A: 1}
-        # behavioral pin: B seq=2 arriving now is NOT causally ready
-        # (B:1 was rolled back) — it must queue, never fast-commit
-        a1 = _change(A, 1, 1, [], 'k', 1)
-        impl.heads = [decode_change(a1)['hash']]
-        impl._changes = [a1]
+        # behavioral pin: B seq=2 arriving now does NOT extend the clock
+        # (B:1 was rolled back) — the reference's error for a skipped
+        # seq, never a fast commit
         b2 = _change(B, 2, 2, impl.heads, 'k', 2)
-        handles, _ = apply_changes_docs(handles, [[b2]], mirror=False)
-        assert len(handles[0]['state'].queue) == 1
+        with pytest.raises(InvalidChange,
+                           match='Skipped sequence number 1 for actor ' + B):
+            apply_changes_docs(handles, [[b2]], mirror=False)
         assert len(handles[0]['state'].changes) == 1
+        assert handles[0]['state'].clock == {A: 1}
 
     def test_freed_engine_is_severed_from_columns(self):
         """A raw engine reference leaked across free must fail LOUDLY

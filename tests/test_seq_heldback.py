@@ -544,7 +544,7 @@ def test_an_op_inside_an_object_whose_make_is_held_back_takes_the_exact_path():
 @pytest.mark.parametrize('loaded', [False, True])
 def test_the_general_gate_asks_history_without_building_the_graph(loaded):
     """Call after call through the general gate and not one hash-graph
-    build: the gate's view of history (`_applied_hashes`) is kept up from
+    build: the gate's view of history (`_history_index`) is kept up from
     the deferred log, on the chain, DAG-ordered and staged commits alike,
     and is the graph's own key set when something does read the graph."""
     from automerge_tpu.fleet import loader
@@ -571,18 +571,30 @@ def test_the_general_gate_asks_history_without_building_the_graph(loaded):
     impl = handles[0]['state']._impl
     assert fleet.metrics.turbo_commit_fallback_docs == 4
     assert fleet.metrics.offchain_dag >= 2
+    # the dependents of a withheld change name a hash that is in neither
+    # the run nor the heads: the gate asked, and the index answered
+    assert fleet.metrics.history_probes > 0
     assert fleet.metrics.graph_builds == 0 and not impl.change_index_by_hash
-    seen = set(impl._applied_hashes())
+    seen = population(impl._history_index())
     assert fleet.metrics.graph_builds == 0
     graph = set(handles[0]['state'].change_index_by_hash)   # builds it
     assert fleet.metrics.graph_builds == 1
-    assert seen == graph == set(impl._applied_hashes())
+    assert seen == graph == population(impl._history_index())
     assert len(graph) == len(room.base) + 20 + sent - len(impl.queue) \
         if not loaded else len(graph) > sent - len(impl.queue)
 
 
+def population(index):
+    """The hashes a history index holds, as the graph's keys are (hex);
+    every one of them once, the table as many as the gate has entered."""
+    hashes = [row.tobytes().hex() for row in index.rows[:index.n]]
+    assert len(set(hashes)) == len(hashes)
+    assert int((index.table >= 0).sum()) == index.entered <= index.n
+    return set(hashes)
+
+
 # ---------------------------------------------------------------------------
-# the general gate over chain segments against the host oracle's gate
+# the native general gate against the host oracle's gate
 # ---------------------------------------------------------------------------
 
 def _a_delivery(rng):
@@ -642,78 +654,386 @@ def _a_delivery(rng):
     return actors, known, sorted(heads), clock, run
 
 
-def _lanes(actors, run):
-    """The parser's per-change lanes for `run`, as _TurboMetaBatch reads
-    them."""
-    n = len(run)
+def _columns(actors, runs):
+    """The parser's per-change columns for `runs`, a document each, as
+    `native.general_gate` reads them."""
+    changes = [c for run in runs for c in run]
+    n = len(changes)
     deps_off = np.zeros(n + 1, dtype=np.int64)
-    deps_off[1:] = np.cumsum([len(c['deps']) for c in run])
-    lanes = {
+    deps_off[1:] = np.cumsum([len(c['deps']) for c in changes])
+    return {
+        'doc_off': np.cumsum([0] + [len(run) for run in runs]),
         'hash32': np.frombuffer(
-            b''.join(bytes.fromhex(c['hash']) for c in run),
+            b''.join(bytes.fromhex(c['hash']) for c in changes),
             dtype=np.uint8).reshape(n, 32),
         'deps_off': deps_off,
-        'deps_blob': b''.join(bytes.fromhex(d) for c in run
+        'deps_blob': b''.join(bytes.fromhex(d) for c in changes
                               for d in c['deps']),
-        'actor': np.array([actors.index(c['actor']) for c in run],
+        'actor': np.array([actors.index(c['actor']) for c in changes],
                           dtype=np.int32),
-        'seq': np.array([c['seq'] for c in run], dtype=np.int64),
-        'startOp': np.arange(1, n + 1, dtype=np.int64),
-        'nops': np.ones(n, dtype=np.int64)}
-    return fleet_backend._TurboMetaBatch(lanes, actors, [b''] * n)
+        'seq': np.array([c['seq'] for c in changes], dtype=np.int64)}
+
+
+def _index_of(hashes):
+    from automerge_tpu.fleet.hashindex import HistoryIndex, hashes_to_rows
+    index = HistoryIndex()
+    index.extend(hashes_to_rows(list(hashes)))
+    return index
+
+
+def _native_gate(actors, docs, cand=None):
+    """`native.general_gate` over `docs`, per document (known hashes or a
+    history index, heads, clock, run): per document the oracle's shape of
+    an answer, (applied, left, heads, clock) with the changes as places in
+    the run, or the reference's error text; and the questions put to
+    history."""
+    cols = _columns(actors, [run for _known, _heads, _clock, run in docs])
+    n_docs = len(docs)
+    head32 = np.zeros((n_docs, 32), dtype=np.uint8)
+    head_n = np.zeros(n_docs, dtype=np.int32)
+    multi = {}
+    for d, (_known, heads, _clock, _run) in enumerate(docs):
+        if len(heads) == 1:
+            head32[d] = np.frombuffer(bytes.fromhex(heads[0]), dtype=np.uint8)
+            head_n[d] = 1
+        elif heads:
+            head_n[d] = -1
+            multi[d] = bytes.fromhex(''.join(heads))
+    groups = native.turbo_gate(
+        cols['doc_off'], cols['actor'], cols['seq'], cols['hash32'],
+        cols['deps_off'], cols['deps_blob'], head32, head_n)
+    g_doc, g_actor = groups[3], groups[4]
+    base = np.array([docs[d][2].get(actors[a], 0)
+                     for d, a in zip(g_doc.tolist(), g_actor.tolist())],
+                    dtype=np.int64)
+    asked = []
+
+    def history(d):
+        asked.append(d)
+        index = docs[d][0]
+        return _index_of(index) if isinstance(index, dict) else index
+
+    out = native.general_gate(
+        cols['doc_off'], cols['actor'], cols['seq'], cols['hash32'],
+        cols['deps_off'], cols['deps_blob'], head32, head_n, multi,
+        np.ones(n_docs, dtype=np.uint8) if cand is None else cand,
+        g_doc, g_actor, base, history)
+    applied, app_off, left, left_off, nh, nh_off, g_seq, errors, probes = out
+    assert (probes > 0) == bool(asked) and len(set(asked)) == len(asked)
+    failed = {d: (i, expected) for d, i, expected in errors}
+    answers = []
+    for d, (_known, _heads, clock, run) in enumerate(docs):
+        lo = int(cols['doc_off'][d])
+        if d in failed:
+            i, expected = failed[d]
+            seq, actor = run[i - lo]['seq'], run[i - lo]['actor']
+            answers.append(
+                f'Reuse of sequence number {seq} for actor {actor}'
+                if seq < expected else
+                f'Skipped sequence number {expected} for actor {actor}')
+            continue
+        after = dict(clock)
+        for g in np.flatnonzero(g_doc == d).tolist():
+            if g_seq[g] != base[g]:
+                after[actors[g_actor[g]]] = int(g_seq[g])
+        answers.append((
+            (applied[app_off[d]:app_off[d + 1]] - lo).tolist(),
+            (left[left_off[d]:left_off[d + 1]] - lo).tolist(),
+            [nh[j].tobytes().hex() for j in range(nh_off[d], nh_off[d + 1])],
+            after))
+    return answers, probes
+
+
+def _oracle_gate(known, heads, clock, run):
+    """`HashGraph._drain_queue` on the same delivery."""
+    from automerge_tpu.backend.hash_graph import HashGraph
+    oracle = HashGraph()
+    oracle.heads, oracle.clock = list(heads), dict(clock)
+    oracle.change_index_by_hash = known
+    try:
+        applied, queue = oracle._drain_queue(
+            [dict(c, at=i) for i, c in enumerate(run)], lambda c: None)
+        return ([c['at'] for c in applied], [c['at'] for c in queue],
+                oracle.heads, oracle.clock)
+    except ValueError as exc:
+        return str(exc)
 
 
 @pytest.mark.parametrize('seed', range(12))
 def test_the_gate_over_chain_segments_is_the_host_oracles_gate(seed):
-    """`_gate_segments` over `_TurboMetaBatch.segments` against
-    `HashGraph._drain_queue`: the changes applied and their order, the
-    queue and its order, heads, clock, and the error's text, over seeded
-    deliveries; the run stands behind another document's changes in the
-    batch, as a document's run does."""
+    """`native.general_gate` against `HashGraph._drain_queue`: the changes
+    applied and their order, the queue and its order, heads, clock, and
+    the error's text, over seeded deliveries; the run stands behind another
+    document's changes in the batch, as a document's run does. (The name is
+    PR 37's, when the gate ran in Python over chain segments.)"""
     import hashlib
-    import types
-    from automerge_tpu.backend.hash_graph import HashGraph
     rng = random.Random(seed)
-    errors = chains = queued = reordered = history = 0
+    errors = queued = reordered = history = 0
     for _ in range(500):
         actors, known, heads, clock, run = _a_delivery(rng)
         if not run:
             continue
-        oracle = HashGraph()
-        oracle.heads, oracle.clock = list(heads), dict(clock)
-        oracle.change_index_by_hash = known
-        try:
-            applied, queue = oracle._drain_queue(
-                [dict(c, at=i) for i, c in enumerate(run)], lambda c: None)
-            want = ([c['at'] for c in applied], [c['at'] for c in queue],
-                    oracle.heads, oracle.clock)
-        except ValueError as exc:
-            want = str(exc)
+        want = _oracle_gate(known, heads, clock, run)
         pad = rng.randint(0, 3)
         other = [{'hash': hashlib.sha256(bytes([i])).hexdigest(), 'deps': [],
                   'actor': actors[0], 'seq': i + 1} for i in range(pad)]
-        batch = _lanes(actors, other + run)
-        link = batch.chain_links(np.array([0, pad] if pad else [0]))
-        engine = types.SimpleNamespace(heads=list(heads), clock=dict(clock))
-        asked = []
-        run_ = batch.segments(pad, pad + len(run), link, clock)
-        try:
-            order, left = fleet_backend._gate_segments(
-                engine, clock, heads, *run_,
-                lambda: asked.append(1) or known)
-            bounds = run_[1]
-            got = ([i for k in order for i in range(bounds[k], bounds[k + 1])],
-                   [i for k in left for i in range(bounds[k], bounds[k + 1])],
-                   engine.heads, engine.clock)
-        except ValueError as exc:
-            got = str(exc)
+        (_, got), probes = _native_gate(
+            actors, [({}, [], {}, other), (known, heads, clock, run)],
+            cand=np.array([0, 1], dtype=np.uint8))
         assert got == want, (seed, run)
         errors += isinstance(want, str)
-        chains += not run_[5] and len(run_[1]) - 1 < len(run)
-        history += bool(asked)
+        history += bool(probes)
         if not isinstance(want, str):
             queued += bool(want[1])
             reordered += want[0] != sorted(want[0])
     # the deliveries hold what the gate has to tell apart
-    assert errors and chains > 100 and queued > 100 and reordered > 50
+    assert errors and queued > 100 and reordered > 50
     assert 0 < history < 500     # history is asked only where it is needed
+
+
+@pytest.mark.parametrize('seed', [5, 2147483659])
+def test_many_documents_in_one_native_call_are_each_the_oracles(seed):
+    """Documents are independent: four hundred deliveries gated by ONE
+    native call (the gate fans them over the pool) read, document by
+    document, as the oracle gating each alone, and the same at one pool
+    thread and at four."""
+    rng = random.Random(seed)
+    actors = [f'{a:02x}' * 16 for a in range(4)]
+    docs = []
+    while len(docs) < 400:
+        _actors, known, heads, clock, run = _a_delivery(rng)
+        if run:
+            docs.append((known, heads, clock, run))
+    want = [_oracle_gate(*doc) for doc in docs]
+    assert sum(isinstance(w, str) for w in want) >= 1
+    assert sum(not isinstance(w, str) and bool(w[1]) for w in want) >= 50
+    was = native.set_native_threads(1)
+    try:
+        alone, probes = _native_gate(actors, docs)
+        native.set_native_threads(4)
+        fanned, probes_fanned = _native_gate(actors, docs)
+    finally:
+        native.set_native_threads(was)
+    assert alone == want and fanned == want
+    assert probes == probes_fanned > 0
+
+
+def _x(prefix, rest):
+    """A hash (hex): 8 bytes of `prefix`, 24 of `rest`."""
+    return f'{prefix:02x}' * 8 + f'{rest:02x}' * 24
+
+
+def _c(hash_, deps, actor=0, seq=1):
+    return {'hash': hash_, 'deps': list(deps), 'actor': f'{actor:02x}' * 16,
+            'seq': seq}
+
+
+HAND_BUILT = {
+    # name: (history, heads, clock by actor number, run,
+    #        applied, left, new heads, questions put to history)
+    'a-prefix-is-a-slot-of-the-index-never-an-answer': (
+        [_x(7, 1), _x(7, 2)], [_x(7, 2)], {0: 2},
+        [_c(_x(9, 1), [_x(7, 1)], 1, 1), _c(_x(9, 2), [_x(7, 3)], 2, 1)],
+        [0], [1], [_x(7, 2), _x(9, 1)], 2),
+    'a-prefix-is-a-slot-of-the-runs-table-never-an-answer': (
+        [], [], {},
+        [_c(_x(5, 2), [_x(5, 9)], 1, 1), _c(_x(5, 3), [_x(5, 1)], 2, 1),
+         _c(_x(5, 1), [], 0, 1)],
+        [2, 1], [0], [_x(5, 3)], 1),
+    'a-hash-delivered-twice-in-one-run-is-applied-once': (
+        [], [], {},
+        [_c(_x(1, 1), []), _c(_x(1, 2), [_x(1, 1)], 0, 2), _c(_x(1, 1), []),
+         _c(_x(1, 2), [_x(1, 1)], 0, 2)],
+        [0, 1], [], [_x(1, 2)], 0),
+    'a-dependency-met-only-by-history': (
+        [_x(3, 1), _x(3, 2), _x(3, 3)], [_x(3, 3)], {0: 3},
+        [_c(_x(4, 1), [_x(3, 1)], 1, 1)],
+        [0], [], [_x(3, 3), _x(4, 1)], 1),
+    'a-change-of-the-history-delivered-again-with-what-follows-it': (
+        [_x(3, 1), _x(3, 2)], [_x(3, 2)], {0: 2},
+        [_c(_x(3, 1), [], 0, 1), _c(_x(6, 1), [_x(3, 1)], 1, 1)],
+        [1], [], [_x(3, 2), _x(6, 1)], 1),
+    'a-head-delivered-again-asks-nothing': (
+        [_x(3, 1), _x(3, 2)], [_x(3, 2)], {0: 2},
+        [_c(_x(3, 2), [_x(3, 1)], 0, 2), _c(_x(3, 4), [_x(3, 2)], 0, 3)],
+        [1], [], [_x(3, 4)], 0),
+    'what-waits-for-a-change-that-waits-stays-in-the-runs-order': (
+        [], [], {},
+        [_c(_x(2, 3), [_x(2, 2)], 0, 3), _c(_x(2, 2), [_x(2, 1)], 0, 2),
+         _c(_x(8, 1), [], 1, 1)],
+        [2], [0, 1], [_x(8, 1)], 1),
+}
+
+
+@pytest.mark.parametrize('case', sorted(HAND_BUILT))
+def test_the_general_gate_on_hand_built_runs(case):
+    history, heads, clock, run, applied, left, new_heads, asked = \
+        HAND_BUILT[case]
+    actors = [f'{a:02x}' * 16 for a in range(3)]
+    clock = {actors[a]: seq for a, seq in clock.items()}
+    known = {h: i for i, h in enumerate(history)}
+    want = _oracle_gate(known, sorted(heads), clock, run)
+    assert want[:3] == (applied, left, sorted(new_heads))
+    (got,), probes = _native_gate(actors, [(known, sorted(heads), clock, run)])
+    assert got == want and probes == asked
+
+
+def test_the_history_index_across_its_growth():
+    """Fed a few hashes at a time past several of its table's and its
+    rows' growths, the index holds every hash once, finds each and no
+    other, and stays under 48 bytes a hash."""
+    import hashlib
+    from automerge_tpu.fleet.hashindex import HistoryIndex, hashes_to_rows
+    rng = random.Random(7)
+    hashes = [hashlib.sha256(bytes([i % 251, i // 251])).hexdigest()
+              for i in range(6000)]
+    index = HistoryIndex()
+    tables, fed = set(), 0
+    while fed < len(hashes):
+        k = rng.randint(1, 97)
+        index.extend(hashes_to_rows(hashes[fed:fed + k] + hashes[:fed][-2:]))
+        fed += k
+        tables.add(len(index.table))
+        assert 3 * index.n <= 2 * len(index.table)
+        if rng.random() < .2:
+            # asked now and then, as a document is: the gate enters what
+            # has come since, beside what the table held
+            _native_gate(['aa' * 16], [(index, [], {}, [_c(_x(1, 1), [_x(1, 2)],
+                                                          0xaa)])])
+            assert index.entered == index.n
+    assert len(tables) > 5
+    held = {row.tobytes().hex() for row in index.rows[:index.n]}
+    assert held == set(hashes)
+    assert index.nbytes < 48 * index.n
+    # every hash a dependency: met where the index holds it
+    absent = [hashlib.sha256(b'no' + bytes([i])).hexdigest()
+              for i in range(50)]
+    asks = rng.sample(hashes, 150) + absent
+    rng.shuffle(asks)
+    actors = ['aa' * 16]
+    run = [_c(hashlib.sha256(b'run' + bytes([i])).hexdigest(), [dep], 0xaa,
+              seq=i + 1) for i, dep in enumerate(asks)]
+    # seqs run on only while every change is applied: one actor a change
+    actors = [f'{i:04x}' * 8 for i in range(len(run))]
+    run = [dict(c, actor=actors[i], seq=1) for i, c in enumerate(run)]
+    (got,), probes = _native_gate(actors, [(index, [], {}, run)])
+    assert got[0] == [i for i, dep in enumerate(asks) if dep in held]
+    assert got[1] == [i for i, dep in enumerate(asks) if dep not in held]
+    assert probes == len(asks)
+    # what was fed twice stands twice in the rows and once in the table
+    assert index.entered == index.n > len(hashes)
+    assert int((index.table >= 0).sum()) == len(hashes)
+
+
+def test_the_history_index_holds_sixty_thousand_hashes_in_48_bytes_each():
+    from automerge_tpu.fleet.hashindex import HistoryIndex
+    rng = np.random.default_rng(11)
+    rows = rng.integers(0, 256, size=(60_000, 32), dtype=np.uint8)
+    at_once, grown = HistoryIndex(), HistoryIndex()
+    at_once.extend(rows)
+    for lo in range(0, len(rows), 31):
+        grown.extend(rows[lo:lo + 31])
+    for index in (at_once, grown):
+        assert index.nbytes / index.n < 48
+        assert (index.rows[:index.n] == rows).all()
+        # asked of all 60,000: the one that is there is found, and its
+        # neighbour by the first 31 bytes is not
+        near = rows[59_999].copy()
+        near[31] ^= 1
+        run = [_c(_x(1, 1), [rows[59_999].tobytes().hex()], 0xaa),
+               _c(_x(1, 2), [near.tobytes().hex()], 0xbb)]
+        (got,), probes = _native_gate(['aa' * 16, 'bb' * 16],
+                                      [(index, [], {}, run)])
+        assert got[:2] == ([0], [1]) and probes == 2
+        assert index.entered == index.n == 60_000 == \
+            int((index.table >= 0).sum())
+
+
+@pytest.mark.parametrize('between', ['park', 'graph_read'])
+def test_the_history_index_is_built_again_where_the_log_was_replaced(between):
+    """A document that asked history and then was parked, or had its graph
+    read, asks again: the index is built anew from what the history has
+    become, and the gate answers as the oracle."""
+    rng = random.Random(29)
+    room = Room(2)
+    rounds_ = some_rounds(room, rng, 4, k=5)
+    held = Held([room.base])
+    gone = rounds_[0][0][1]
+    held.call([less(rounds_[0], [gone])], general=1)
+    held.call([[gone] + less(rounds_[1], [])], general=1)
+    impl = held.handles[0]['state']._impl
+    first = impl._history[0]
+    assert held.fleet.metrics.history_probes > 0 and first.n > 0
+    if between == 'park':
+        assert fleet_backend.park_docs(held.handles) == 1
+    else:
+        assert held.handles[0]['state'].change_index_by_hash
+    asked = held.fleet.metrics.history_probes
+    gone = rounds_[2][1][2]
+    held.call([less(rounds_[2], [gone])], general=1)
+    assert held.fleet.metrics.history_probes > asked
+    assert impl._history[0] is not first
+    # (the oracle's checks read the graph after every call, so the next
+    # question builds it again, too) it holds the log's hashes, each once
+    assert population(impl._history_index()) == \
+        {hash_of(b) for b in impl.changes}
+    held.call([[gone] + less(rounds_[3], [])], general=1)
+    held.hold_reads()
+
+
+@pytest.mark.parametrize('seq,text', [
+    (4, 'Skipped sequence number 3 for actor '),
+    (2, 'Reuse of sequence number 2 for actor ')],
+    ids=['skipped', 'reused'])
+def test_a_seq_error_of_the_native_gate_has_the_references_text(seq, text):
+    """A ready change whose seq is not its actor's next, in the second of
+    three documents: the reference's text, typed, with the document's
+    place in the call; no document is touched."""
+    rng = random.Random(31)
+    rooms = [Room(2), Room(2), Room(2)]
+    rounds_ = [some_rounds(room, rng, 1, k=3)[0] for room in rooms]
+    fleet = DocFleet(doc_capacity=4, key_capacity=8)
+    handles = init_docs(3, fleet)
+    handles, _ = apply_changes_docs(handles, [r.base for r in rooms],
+                                    mirror=False)
+    was = [(fleet_backend.get_heads(h), dict(h['state'].clock))
+           for h in handles]
+    second = decode_change(bytes(rounds_[1][0][1]))
+    assert second['seq'] == 3
+    bad = encode_change({**second, 'seq': seq, 'hash': None})
+    per_doc = [less(r, []) for r in rounds_]
+    per_doc[0] = per_doc[0][::-1]             # the general gate's, and fine
+    per_doc[1] = [bad if bytes(b) == bytes(rounds_[1][0][1]) else b
+                  for b in per_doc[1]][::-1]
+    with pytest.raises(InvalidChange) as raised:
+        apply_changes_docs(handles, per_doc, mirror=False)
+    assert str(raised.value) == text + second['actor']
+    assert raised.value.doc_index == 1
+    assert [(fleet_backend.get_heads(h), dict(h['state'].clock))
+            for h in handles] == was
+    assert fleet.metrics.fallbacks == 0 and fleet.metrics.exact_calls == 0
+    with pytest.raises(ValueError, match=text):
+        host.apply_changes(host.apply_changes(host.init(), rooms[1].base)[0],
+                           per_doc[1])
+
+
+def test_heads_that_are_no_hashes_send_the_call_to_the_exact_path():
+    """A frontier the native gates cannot read as 32-byte hashes: the
+    turbo call ends before it counts or writes anything, and the exact
+    path answers."""
+    rng = random.Random(37)
+    room = Room(2)
+    round_ = some_rounds(room, rng, 1, k=3)[0]
+    fleet = DocFleet(doc_capacity=2, key_capacity=8)
+    handles = init_docs(1, fleet)
+    handles, _ = apply_changes_docs(handles, [room.base], mirror=False)
+    impl = handles[0]['state']._impl
+    impl.heads = impl.heads + ['not-a-hash']
+    before = fleet.metrics.snapshot()
+    try:
+        apply_changes_docs(handles, [less(round_, [])[::-1]], mirror=False)
+    except Exception:
+        pass                  # whatever the exact path makes of such heads
+    moved = fleet.metrics.delta(before)
+    assert moved['turbo_calls'] == 0 and moved['fallbacks'] == 1
+    assert moved['offchain_native'] == moved['history_probes'] == 0

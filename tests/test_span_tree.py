@@ -225,7 +225,7 @@ COMMIT = ['commit.columnar', 'commit.staged', 'commit.handles']
 @pytest.mark.parametrize('make_log,off_chain,dag', [
     (two_headed_log, True, True),       # off the chain, DAG-ordered
     (linear_log, False, False),
-    (out_of_order_log, True, False),    # the Python gate, per document
+    (out_of_order_log, True, False),    # the general gate, one native call
 ])
 def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain,
                                                      dag):
@@ -259,7 +259,7 @@ def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain,
 
     refused = n_docs if off_chain else 0    # by the chain check
     taken = n_docs if dag else 0            # of those, by the DAG gate
-    off = refused - taken                   # what reaches the Python gate
+    off = refused - taken                   # what reaches the general gate
     reasons = {k: v for k, v in named['turbo_gate'][0]['attrs'].items()
                if k.startswith('offchain_')}
     assert reasons == {'offchain_native': refused, 'offchain_heads': 0,
@@ -268,15 +268,17 @@ def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain,
             fleet.metrics.offchain_seq, fleet.metrics.offchain_dag) == \
         (refused, 0, 0, taken)
     assert fleet.metrics.turbo_commit_fallback_docs == off
-    assert named['gate.general'][0]['attrs'] == {'docs': off}
+    # the general gate is one native call for all its documents: the phase
+    # says how many reached it and what it asked of their history indexes
+    # (here nothing: every dependency is a change of the run), and opens no
+    # span a document
+    assert named['gate.general'][0]['attrs'] == {'docs': off,
+                                                 'history_probes': 0}
+    assert fleet.metrics.history_probes == 0
     assert named['commit.staged'][0]['attrs'] == {'docs': off}
     general = named['gate.general'][0]
-    for name in ('gate.meta', 'gate.drain'):
-        per_document = named.get(name, [])
-        assert [s['attrs']['doc'] for s in per_document] == list(range(off))
-        assert all(s['parent'] == general['id'] and
-                   s['attrs']['changes'] == len(per_doc[0])
-                   for s in per_document)
+    assert not [s for s in spans if s['parent'] == general['id']]
+    assert 'gate.meta' not in named and 'gate.drain' not in named
     if not off:
         # nothing staged: the phase is there and as good as empty
         staged = named['commit.staged'][0]
@@ -298,12 +300,37 @@ def test_a_skipped_seq_is_its_own_reason_and_a_raise_closes_every_phase():
     assert gate['attrs'] == {'offchain_native': 0, 'offchain_heads': 0,
                              'offchain_seq': 1, 'offchain_dag': 0}
     assert fleet.metrics.offchain_seq == 1
-    # the general gate raised inside gate.drain: each open phase closed
-    assert named['gate.drain'][0]['error'] == 'ValueError'   # typed above it
-    assert named['gate.general'][0]['t1_ns'] <= gate['t1_ns']
+    # the general gate raised inside gate.general: each open phase closed,
+    # the error typed where it is raised and named on the call's root
+    general = named['gate.general'][0]
+    assert general['attrs'] == {'docs': 1}     # raised before it could note
+    assert general['parent'] == gate['id']
+    assert general['t1_ns'] <= gate['t1_ns']
+    assert 'gate.order' not in named and 'turbo_commit' not in named
     assert named['apply_batch'][0]['error'] == 'InvalidChange'
     with observability.span('probe') as probe:
         assert probe.parent is None
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+def test_what_the_general_gate_asks_of_history_is_noted_on_its_phase():
+    """A linear log less its second change: the third names a hash that is
+    neither a change of the run nor a head, which only the document's
+    history index can answer (no); the phase carries the count, and a
+    document on the chain beside it adds nothing to it."""
+    fleet = DocFleet(doc_capacity=2, key_capacity=8)
+    handles = init_docs(2, fleet)
+    log = linear_log(0)
+    obs_spans.enable(capacity=64)
+    handles, _ = apply_changes_docs(handles, [log[:1] + log[2:], linear_log(1)],
+                                    mirror=False)
+    observability.disable()
+    named = by_name(observability.iter_spans())
+    assert named['gate.general'][0]['attrs'] == {'docs': 1,
+                                                 'history_probes': 1}
+    assert fleet.metrics.history_probes == 1
+    assert named['turbo_gate'][0]['attrs']['heldback_changes'] == 4
+    assert len(handles[0]['state'].queue) == 4
 
 
 # ---------------------------------------------------------------------------

@@ -41,7 +41,7 @@ less what its child spans cover (``spans.self_times`` over the ``id`` /
 breakdown the ROADMAP's parse/merge-overlap work needs (cf. the
 differential-merge phase analysis in PAPERS.md "Fast Updates on
 Read-Optimized Databases"). Spans nest (native_parse inside turbo_parse,
-gate.drain inside gate.general inside turbo_gate), so the wall column
+gate.general inside turbo_gate inside apply_batch), so the wall column
 counts a millisecond once per level; the self column counts it once, in
 the narrowest span that held it, and sums to the traced wall of each
 thread. A collection is a ``gc`` span under the phase it interrupted.
