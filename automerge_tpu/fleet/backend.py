@@ -52,7 +52,8 @@ from ..observability import (Counters, Metrics, register_health_source,
                              register_mem_source)
 from ..observability import hist as _hist
 from ..observability import recorder as _flight
-from ..observability.spans import (span as _span, span_seq as _span_seq,
+from ..observability.spans import (on as _spans_on, span as _span,
+                                   span_seq as _span_seq,
                                    spanned as _spanned)
 
 # live fleets for the memory-watermark tier (see _fleet_bytes below,
@@ -4074,7 +4075,29 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     indexes) / `gate.order` / `gate.validate`, and `commit.columnar` /
     `commit.staged` /
     `commit.handles` — named without the `turbo_` prefix, so readers that
-    sum `turbo_*` count each millisecond once. `turbo_gate` carries why
+    sum `turbo_*` count each millisecond once. The same sequence tiles the
+    other phases (PR 39; a sub-phase opens AT its parent's mark and the
+    last closes where the parent does): `turbo_setup` is `setup.engines`
+    (handles to engines, one fleet) / `setup.buffers` (the flat buffer
+    list, queued changes' buffers behind a document's own: `queued`);
+    `turbo_stage` is `stage.flush` / `stage.actors` (applied actors
+    interned, the remaps, `actor_map`) / `stage.values` (value and flag
+    columns, makes registered, payloads interned) / `stage.root` (root
+    rows' slots, keys interned, the op index fed) / `stage.grid` (only
+    with root rows: capacity, the laid-out columns, the kill lanes);
+    `turbo_dispatch` is `dispatch.enqueue` (the grid kernel's or the
+    register engine's call) / `dispatch.note` (the winner mirror's
+    check); the sequence rows' `stage.seq_rows` (`rows`, `objects`: ids
+    remapped, device rows resolved, the op tuple stacked) /
+    `stage.seq_dispatch` (`_dispatch_seq`) follow under whichever of the
+    two phases runs them: the name says which work, the parent where it
+    ran. `stage.root` and `stage.grid`, over 40 % of `turbo_stage` in the
+    bulk cell, are split once more by a third sequence: `root.rows` /
+    `root.keys` / `root.index`, `grid.lanes` / `grid.columns` /
+    `grid.kills`. The first `seq.enqueue` or `dispatch.enqueue` of a call
+    is where the device gets its work: the benchmark splits a call there
+    (`seam.pre_enqueue_ms_per_step`, `seam.post_enqueue_ms_per_step`).
+    `turbo_gate` carries why
     documents left the chain path (`offchain_native` / `offchain_heads` /
     `offchain_seq`), how many of them the DAG gate took back
     (`offchain_dag`; of those, `dag_seq_docs` hold sequence ops), how
@@ -4084,17 +4107,19 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     queue); the three reasons less `offchain_dag` is what reached
     `gate.general`."""
     ps = _span_seq()
-    sub = _span_seq()   # the sub-phases of turbo_gate, then turbo_commit
-    ps.mark('turbo_setup', docs=len(handles))
+    # the sub-phases of whichever turbo_* phase runs: each opens at its
+    # parent's mark and the last closes where the parent does (`at=`)
+    sub = _span_seq()
+    part = _span_seq()  # the parts of a sub-phase split once more
+    sub.mark('setup.engines', at=ps.mark('turbo_setup', docs=len(handles)))
     try:
         return _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
-                                          parsed)
+                                          part, parsed)
     finally:
-        sub.done()
-        ps.done()
+        ps.done(at=sub.done(at=part.done()))
 
 
-def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
+def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
                                parsed=None):
     from .. import native
     from .tensor_doc import OpBatch, MAX_ACTORS as _MA
@@ -4111,6 +4136,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     fleet = engines[0].fleet
     if any(e.fleet is not fleet for e in engines):
         return None
+    sub.mark('setup.buffers')
     flat_buffers = []
     per_doc_idx = [None] * len(handles)   # (start, stop) contiguous runs
     # zeros, not empty: a per_doc_changes shorter than handles must leave
@@ -4153,7 +4179,9 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         return None
     # doc_ids=None: the zero-copy list entry (C walks the bytes objects
     # in place — no blob join, no length array; buffer i IS doc i here)
-    ps.mark('turbo_parse', changes=n_changes)
+    if _spans_on():
+        sub.note(queued=int(n_queued.sum()))
+    ps.mark('turbo_parse', at=sub.done(), changes=n_changes)
     if parsed is not None and parsed[0] == n_changes:
         out = parsed[1]   # prefetched on a background thread (pipelined)
     else:
@@ -4163,8 +4191,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         return None     # ops outside the fleet subset, or corrupt chunk
     rows, nat_keys, nat_actors, nmeta = out
     batch_meta = _TurboMetaBatch(nmeta, nat_actors, flat_buffers)
-    ps.mark('turbo_gate')
-    sub.mark('gate.chain')
+    sub.mark('gate.chain', at=ps.mark('turbo_gate'))
 
     # ---- Batched linear-chain validation: ONE native call ----
     # A doc is on the chain iff every change deps on exactly the
@@ -4548,9 +4575,8 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         fleet.metrics.bytes_ingested += int(buf_len[ready].sum())
 
     # Phase 2 — infallible: record logs, queues, staleness
-    sub.done()
-    ps.mark('turbo_commit', ready=n_ready)
-    sub.mark('commit.columnar')
+    sub.mark('commit.columnar',
+             at=ps.mark('turbo_commit', at=sub.done(), ready=n_ready))
     start_op = nmeta['startOp']
     nops = nmeta['nops']
     last_op = start_op + nops - 1
@@ -4756,18 +4782,20 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
             out_handles.append(lazy)
     result = out_handles, [None] * len(handles)
     kept = int(keep.sum())
-    sub.done()
+    at = sub.done()
     if not kept:
+        ps.done(at=at)
         return result            # everything queued: no device work
 
     # Land any lazily-enqueued earlier changes first: the register engine
     # is order-sensitive (pred kills), and even the LWW grid's counter
     # reset bases on the pre-batch winner
-    ps.mark('turbo_stage', kept=kept)
+    sub.mark('stage.flush', at=ps.mark('turbo_stage', at=at, kept=kept))
     fleet.flush()
 
     # Device batch: remap the native parser's key/actor numbering into the
     # fleet tables (interning only keys that actually land on the device)
+    sub.mark('stage.actors')
     applied_actor_ids = np.unique(nmeta['actor'][ready])
     perm = fleet.actors.insert_many([nat_actors[int(a)]
                                      for a in applied_actor_ids])
@@ -4794,6 +4822,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # the boxed link value (value-table interned by equality — slots
     # share it) memoize per packed id; only the per-slot seq-row
     # allocation and engine registration stay per doc.
+    sub.mark('stage.values')
     kept_vals_all = rows['value'].astype(np.int32, copy=True)
     kept_flags_all = rows['flags'].copy()
     _typ_lut = {7: 'text', 8: 'list', 9: 'map', 10: 'table',
@@ -4877,6 +4906,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         """Kept sequence rows -> one SeqState dispatch (fleet numbering)."""
         if not keep_seq.any():
             return
+        sub.mark('stage.seq_rows', at=part.done())
         from .sequence import INC, INSERT, SET, DEL, PAD, SEQ_PRED_LANES
         sflags = rows['flags'][keep_seq]
         svtype = rows['vtype'][keep_seq]
@@ -4919,6 +4949,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         # void-view compare (doc < 2^31, packed objectId < 2^31)
         combo = (sdoc << 32) | sobj
         uniq, inv = np.unique(combo, return_inverse=True)
+        sub.note(rows=n_seq, objects=len(uniq))
         urow = np.empty(len(uniq), dtype=np.int64)
         oid_memo = {}
         for i, cv in enumerate(uniq.tolist()):
@@ -4979,11 +5010,14 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
                      'datatype': decoded.get('datatype')})
                 seq_memo[mk] = vid
             svalue[i] = vid
-        fleet._dispatch_seq(np.stack(
+        seq_ops = np.stack(
             [srow, skind, sref, spacked, svalue,
              *(pred_lanes[:, d] for d in range(D)),
-             hflag.astype(np.int64)], axis=1))
+             hflag.astype(np.int64)], axis=1)
+        sub.mark('stage.seq_dispatch')
+        fleet._dispatch_seq(seq_ops)
 
+    part.mark('root.rows', at=sub.mark('stage.root'))
     n_kept_root = int(keep_root.sum())
     doc_arr = change_doc[rows['doc'][keep_root]].astype(np.int32)
     slots = slot_of_doc.astype(np.int32)[doc_arr]
@@ -4991,6 +5025,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # Key interning: root keys as bare strings; nested map/table cells as
     # composite (objectId, key) — shared with the register ingest
     from .ingest import intern_composite_keys
+    part.mark('root.keys')
     key = intern_composite_keys(rows['obj'][keep_root],
                                 rows['key'][keep_root], nat_keys,
                                 nat_actors, fleet.keys)
@@ -5000,6 +5035,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
     # Feed the dangling-pred oracle: kept map-key rows that create op
     # rows (sets incl. makes folded to flags 1 with non-TOMBSTONE
     # values, and incs — never dels)
+    part.mark('root.index')
     _f = kept_flags_all[keep_root]
     _v = kept_vals_all[keep_root]
     _idx_sel = ((_f == 1) & (_v != TOMBSTONE)) | (_f == 2)
@@ -5009,6 +5045,8 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         from .registers import (apply_register_batch_donated,
                                 rows_to_register_batch)
         if n_kept_root:
+            part.mark('grid.lanes',
+                      at=sub.mark('stage.grid', at=part.done()))
             # Slice the kept rows' pred segments and remap their actor bits
             pred_counts = np.diff(rows['pred_off'])
             entry_keep = np.repeat(keep_root, pred_counts)
@@ -5032,12 +5070,14 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
             fleet._ensure_reg_capacity(n_docs=fleet.n_slots,
                                        n_keys=len(fleet.keys))
             n_cap = fleet.reg_state.reg.shape[0]
+            part.mark('grid.columns')
             reg_batch = rows_to_register_batch(
                 slots.astype(np.int64), kept_flags_all[keep_root], key,
                 packed, kept_vals_all[keep_root], off_kept, preds_kept,
                 n_docs=n_cap, d_preds=fleet.d_preds,
                 force_overflow=bad_rows)
-            ps.mark('turbo_dispatch')
+            at = ps.mark('turbo_dispatch', at=sub.done(at=part.done()))
+            sub.mark('dispatch.enqueue', at=at)
             fleet.reg_state, _stats = apply_register_batch_donated(
                 fleet.reg_state, fleet._shard_docs(reg_batch))
             fleet.metrics.dispatches += 1
@@ -5046,6 +5086,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         return result
 
     if n_kept_root:
+        part.mark('grid.lanes', at=sub.mark('stage.grid', at=part.done()))
         n_slots = fleet.n_slots
         # Fused staging: size the device state FIRST and scatter the op
         # columns straight into capacity-shaped arrays — the old
@@ -5073,6 +5114,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         run_lens = np.diff(np.r_[run_starts, n_root])
         pos = np.arange(n_root) - np.repeat(run_starts, run_lens)
         max_ops = max(int(run_lens.max()) if n_root else 0, 1)
+        part.mark('grid.columns')
         shape = (n_cap, max_ops)
         grid_cols = {name: np.zeros(shape, dtype=np.int32)
                      for name in ('key_id', 'packed', 'value')}
@@ -5089,6 +5131,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
         batch = OpBatch(grid_cols['key_id'], grid_cols['packed'],
                         grid_cols['value'], is_set, is_inc, valid)
 
+        part.mark('grid.kills')
         kills = None
         kill_doc = kill_key_f = kill_packed_f = ()
         pred_counts = np.diff(rows['pred_off'])
@@ -5113,8 +5156,10 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub,
                 (np.int32, np.int32))
             kills = (kk_arr, kp_arr)
 
-        ps.mark('turbo_dispatch')
+        at = ps.mark('turbo_dispatch', at=sub.done(at=part.done()))
+        sub.mark('dispatch.enqueue', at=at)
         fleet._dispatch_grid(batch, kills)
+        sub.mark('dispatch.note')
         # Counter-attribution check (see _note_grid_batch): advance the
         # host winner mirror with this batch's set and kill rows and
         # verify each inc's pred against the post-batch winner

@@ -24,13 +24,23 @@ phase, and what happened around it. Four layers, one package:
   phases on one clock.
 - **Host-phase spans** (spans.py): `span(name, **attrs)` — near-zero
   overhead while disabled, a bounded ring while enabled — instrumented
-  at every hot seam (native parse, SHA, turbo gate/stage/commit and
-  their `gate.*` / `commit.*` sub-phases, device dispatch, mirror
+  at every hot seam (native parse, SHA, the six `turbo_*` phases of a
+  turbo call and the sub-phases that tile them: `setup.engines` /
+  `setup.buffers`, `gate.*`, `commit.*`, `stage.flush` / `.actors` /
+  `.values` / `.root` / `.grid` / `.seq_rows` / `.seq_dispatch`,
+  `dispatch.enqueue` / `dispatch.note`, with `stage.root` and
+  `stage.grid` split once more into `root.*` and `grid.*`; device
+  dispatch, mirror
   rebuild, actor remap, journal append/commit/fsync, checkpoint,
   compaction, recovery replay, Bloom build/probe, sync encode/decode).
   Spans are a tree (`id`, `parent`, `root`; `self_times` gives each
-  span's time outside its children), every collection is a `gc` span
-  under the phase it interrupted, and while enabled each span is also a
+  span's time outside its children), a root span's record carries
+  `thread_cpu_ns`, the CPU time its thread ran inside it (`dur_ns` less
+  it is the time off the CPU: what tells a call that ran slowly from one
+  that did not run; None under a root, where the clock, a system call, is
+  not read), every
+  collection is a `gc` span under the phase it interrupted, and while
+  enabled each span is also a
   `jax.profiler.TraceAnnotation`, so a profiler capture shows it in
   `/host:CPU` beside the device planes. `export_chrome_trace` writes the
   ring as Perfetto-loadable JSON on `perf_counter_ns` (its own clock).
@@ -62,7 +72,7 @@ And the tenant telemetry plane on top (ISSUE-10):
   merged by `tools/obs_report.py --stitch`.
 
 `enable()`/`disable()` flip spans + histograms together (what tracing
-costs on the chip is unresolved: PERF.md, PR 27); the flight recorder's
+costs on the chip: PERF.md, PRs 27 and 39); the flight recorder's
 event ring and the SLO accounting stay on either way (the latter has
 its own switch: `DocService(slo=False)`). `tools/obs_report.py` renders
 a phase-attribution report from an exported trace or a forensic dump.

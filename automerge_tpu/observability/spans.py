@@ -6,9 +6,25 @@ of an instrumented seam is one module-flag check and two empty method
 calls, which is why the hot paths (turbo apply, journal commit, Bloom
 build) can stay instrumented permanently instead of behind copy-pasted
 ``if`` guards. When ON (``enable()``), every span close records
-``(name, t0_ns, t1_ns, thread, attrs, error, id, parent, root)`` into a
-bounded ring — old spans fall off the end, so a long-running fleet never
-grows memory.
+``(name, t0_ns, t1_ns, thread, attrs, error, thread_cpu_ns, id, parent,
+root)`` into a bounded ring — old spans fall off the end, so a
+long-running fleet never grows memory.
+
+Beside the wall clock the begin and the end of a ROOT span (a call of
+``apply_changes_docs``, a service tick, a recovery: a span nothing encloses)
+read the calling thread's CPU clock (``time.thread_time_ns``): the record's
+``thread_cpu_ns`` is what the thread RAN between the two, so ``dur_ns -
+thread_cpu_ns`` is the time it was off the CPU (descheduled by a shared
+host, waiting on a lock, a pool or the device) — what tells a call that ran
+slowly from one that did not run. It is None on every span under a root and
+on a ``record_span`` slice. Why not at every mark: that clock is a system
+call, and where system calls are intercepted (the chip's host: about 30 us
+a read against 0.3 on a plain kernel, in ticks of 10 ms; PERF.md, PR 39) a
+read at each of a turbo call's forty marks made the traced call a tenth
+longer and moved every phase's reading; two reads a call, outside every
+phase, move none, and a 10 ms tick says nothing about a phase of a
+millisecond anyway. Only while
+recording is on: off, no clock is read.
 
 Spans form a TREE: ``id`` is a process-wide counter, ``parent`` the id of
 the innermost span open on the same thread when this one opened (``None``
@@ -25,7 +41,12 @@ recovery): ``mark(name)`` closes the previous phase and opens the next at
 the SAME timestamp, so consecutive phases tile an interval with no
 unattributed gap — that contiguity is what lets the benchmark's
 ``seam.untraced_ms_per_step`` (benchmarks/metrics/) read the part of a
-seam call no phase accounts for.
+seam call no phase accounts for. ``mark()`` and ``done()`` return the
+instant they read (wall ns; None while off) and take one as ``at=``: a
+second sequence that tiles a phase of the first opens its first sub-phase
+and closes its last AT the parent's own marks, on one clock read for both
+(the turbo seam's ``gate.*`` / ``commit.*`` / ``stage.*`` / ``dispatch.*``
+/ ``setup.*`` under the six ``turbo_*``).
 
 Spans stay in THIS ring only; the flight recorder reads the ring's tail
 at dump time (recorder.dump_flight_record) rather than mirroring every
@@ -127,10 +148,21 @@ def clear():
         _total = 0
 
 
-def _record(name, t0, t1, attrs, error, ids, tid=None):
+def _cpu_now(is_root):
+    """The calling thread's CPU ns at an edge of a root span; None under a
+    root, where the clock (a system call) is not read."""
+    return time.thread_time_ns() if is_root else None
+
+
+def _cpu_between(cpu0, cpu1):
+    return None if cpu0 is None or cpu1 is None else cpu1 - cpu0
+
+
+def _record(name, t0, t1, cpu, attrs, error, ids, tid=None):
     global _idx, _total, _dropped_lifetime
     rec = (name, t0, t1,
-           threading.get_ident() if tid is None else tid, attrs, error) + ids
+           threading.get_ident() if tid is None else tid, attrs, error,
+           cpu) + ids
     with _lock:
         if not _cap:
             return
@@ -194,7 +226,8 @@ def record_span(name, t0_ns, t1_ns, tid=None, parent=None, **attrs):
         ids = (next(_ids), parent.id, parent.root)
     else:
         ids = _new_ids(_stack())
-    _record(name, t0_ns, t1_ns, attrs or None, None, ids, tid=tid)
+    # timed by another thread: what THAT thread ran is not ours to read
+    _record(name, t0_ns, t1_ns, None, attrs or None, None, ids, tid=tid)
 
 
 class _Node:
@@ -218,17 +251,18 @@ class Span(_Node):
     an end even when the guarded block raises). ``id``, ``parent`` and
     ``root`` are set once it is entered."""
 
-    __slots__ = ('_name', '_t0', '_attrs')
+    __slots__ = ('_name', '_t0', '_cpu0', '_attrs')
 
     def __init__(self, name, attrs):
         super().__init__()
         self._name = name
         self._attrs = attrs or None
-        self._t0 = 0
+        self._t0 = self._cpu0 = 0
 
     def __enter__(self):
         self._ids, self._annotation = _open(self._name)
         self._t0 = time.perf_counter_ns()
+        self._cpu0 = _cpu_now(self._ids[1] is None)
         return self
 
     def set(self, **attrs):
@@ -240,8 +274,10 @@ class Span(_Node):
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        cpu1 = _cpu_now(self._cpu0 is not None)
         _close(self._ids[0], self._annotation)
-        _record(self._name, self._t0, t1, self._attrs,
+        _record(self._name, self._t0, t1, _cpu_between(self._cpu0, cpu1),
+                self._attrs,
                 exc_type.__name__ if exc_type is not None else None,
                 self._ids)
         return False
@@ -281,29 +317,42 @@ class SpanSeq(_Node):
     opens the next at the same instant, so the phases tile the interval.
     The running phase is on the thread's stack of open spans like any
     other, so a sequence started inside a phase records that phase's
-    children. ``id``, ``parent`` and ``root`` are the running phase's."""
+    children. ``id``, ``parent`` and ``root`` are the running phase's.
+    ``mark()`` and ``done()`` return the instant they read (wall ns) and
+    take one as ``at=`` (from another sequence's mark): a sequence that
+    tiles a phase of another shares that phase's edges exactly."""
 
-    __slots__ = ('_name', '_t0', '_attrs')
+    __slots__ = ('_name', '_t0', '_cpu0', '_attrs')
 
     def __init__(self):
         super().__init__()
         self._name = None
-        self._t0 = 0
+        self._t0 = self._cpu0 = 0
         self._attrs = None
 
-    def _finish(self, t, error):
+    def _finish(self, at, cpu, error):
         _close(self._ids[0], self._annotation)
-        _record(self._name, self._t0, t, self._attrs, error, self._ids)
+        _record(self._name, self._t0, at, _cpu_between(self._cpu0, cpu),
+                self._attrs, error, self._ids)
         self._name = None
 
-    def mark(self, name, **attrs):
-        t = time.perf_counter_ns()
+    def _cpu_here(self):
+        """The CPU clock at a mark: the next phase takes the running one's
+        place in the tree, the first goes under what the thread has open."""
+        return _cpu_now(self._ids[1] is None if self._name is not None
+                        else not _stack())
+
+    def mark(self, name, at=None, **attrs):
+        if at is None:
+            at = time.perf_counter_ns()
+        cpu = self._cpu_here()
         if self._name is not None:
-            self._finish(t, None)
+            self._finish(at, cpu, None)
         self._ids, self._annotation = _open(name)
         self._name = name
-        self._t0 = t
+        self._t0, self._cpu0 = at, cpu
         self._attrs = attrs or None
+        return at
 
     def note(self, **attrs):
         """Attach attributes to the running phase (recorded when the next
@@ -314,23 +363,26 @@ class SpanSeq(_Node):
             self._attrs = {}
         self._attrs.update(attrs)
 
-    def done(self, error=None, **attrs):
+    def done(self, error=None, at=None, **attrs):
         if self._name is None:
-            return
+            return at
         self.note(**attrs)
-        self._finish(time.perf_counter_ns(), error)
+        if at is None:
+            at = time.perf_counter_ns()
+        self._finish(at, self._cpu_here(), error)
+        return at
 
 
 class _NullSeq:
     __slots__ = ()
 
-    def mark(self, name, **attrs):
+    def mark(self, name, at=None, **attrs):
         pass
 
     def note(self, **attrs):
         pass
 
-    def done(self, error=None, **attrs):
+    def done(self, error=None, at=None, **attrs):
         pass
 
 
@@ -369,8 +421,12 @@ def spanned(name):
 
 
 def iter_spans():
-    """Recorded spans, oldest first, as dicts. Copies the ring under the
-    lock, so it is safe against concurrent recording."""
+    """Recorded spans, oldest first, as dicts. ``thread_cpu_ns`` is the
+    CPU time the span's own thread ran inside it (a root span; None under
+    one and on a `record_span` slice); ``dur_ns`` less it is the time that
+    thread was off the CPU.
+    Copies the ring under the lock, so it is safe against concurrent
+    recording."""
     with _lock:
         if _total >= _cap:
             raw = _ring[_idx:] + _ring[:_idx]
@@ -380,9 +436,9 @@ def iter_spans():
     for rec in raw:
         if rec is None:
             continue
-        name, t0, t1, tid, attrs, error, sid, parent, root = rec
+        name, t0, t1, tid, attrs, error, cpu, sid, parent, root = rec
         d = {'name': name, 't0_ns': t0, 't1_ns': t1,
-             'dur_ns': t1 - t0, 'tid': tid,
+             'dur_ns': t1 - t0, 'thread_cpu_ns': cpu, 'tid': tid,
              'id': sid, 'parent': parent, 'root': root}
         if attrs:
             d['attrs'] = dict(attrs)
@@ -434,8 +490,8 @@ def export_chrome_trace(path=None, pid=1):
     ``time.perf_counter_ns`` microseconds (not the JAX profiler's clock);
     host spans from one process share that clock, so phases nest
     correctly. ``args`` carries each span's ``id``, ``parent`` and
-    ``root``. Returns the event list; writes ``{"traceEvents": [...]}``
-    to `path` when given."""
+    ``root``, and its ``thread_cpu_ns`` where it has one. Returns the
+    event list; writes ``{"traceEvents": [...]}`` to `path` when given."""
     events = []
     for rec in iter_spans():
         ev = {'ph': 'X', 'name': rec['name'], 'pid': pid,
@@ -446,6 +502,8 @@ def export_chrome_trace(path=None, pid=1):
         if rec.get('error'):
             args['error'] = rec['error']
         args.update(id=rec['id'], parent=rec['parent'], root=rec['root'])
+        if rec['thread_cpu_ns'] is not None:
+            args['thread_cpu_ns'] = rec['thread_cpu_ns']
         ev['args'] = args
         events.append(ev)
     dropped = spans_dropped()

@@ -318,7 +318,9 @@ def test_export_chrome_trace_format(tmp_path):
     assert events[-1]['dur'] >= 0 and 'ts' in events[-1]
     ring = observability.iter_spans()[-1]
     assert events[-1]['args'] == {'docs': 2, 'id': ring['id'],
-                                  'parent': None, 'root': ring['id']}
+                                  'parent': None, 'root': ring['id'],
+                                  'thread_cpu_ns': ring['thread_cpu_ns']}
+    assert isinstance(ring['thread_cpu_ns'], int)
     on_disk = json.loads(path.read_text())
     assert on_disk['traceEvents'] == events
     observability.disable()

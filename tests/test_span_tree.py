@@ -3,10 +3,16 @@ root; `self_times` takes children out of a span; the turbo seam's
 `turbo_gate` and `turbo_commit` are tiled by `gate.*` / `commit.*`
 sub-phases and say why documents left the chain path; collections are `gc`
 spans under what they interrupted; and nothing of it happens while spans
-are off."""
+are off. Since ISSUE-39 `turbo_stage`, `turbo_dispatch` and `turbo_setup`
+are tiled too (`stage.*`, `dispatch.*`, `setup.*`, exactly: a sub-phase
+opens AT its parent's mark), `stage.root` and `stage.grid` once more
+(`root.*`, `grid.*`), and a call's root span carries `thread_cpu_ns`. All
+of it is held structurally: order, parentage, shared instants, no clock
+budget."""
 
 import gc
 import threading
+import time
 
 import pytest
 
@@ -240,16 +246,10 @@ def test_sub_phases_tile_turbo_gate_and_turbo_commit(make_log, off_chain,
     assert observability.spans_dropped() == 0
     named = by_name(spans)
 
-    for parent_name, parts in (('turbo_gate', GATE),
-                               ('turbo_commit', COMMIT)):
-        (parent,) = named[parent_name]
-        subs = [named[name][0] for name in parts]
-        assert all(len(named[name]) == 1 for name in parts)
-        assert all(sub['parent'] == parent['id'] for sub in subs)
-        for before, after in zip(subs, subs[1:]):
-            assert after['t0_ns'] == before['t1_ns']
-        assert 0 <= subs[0]['t0_ns'] - parent['t0_ns'] < 50_000
-        assert 0 <= parent['t1_ns'] - subs[-1]['t1_ns'] < 50_000
+    # exactly, since ISSUE-39: a sub-phase opens at its parent's own mark
+    # (no gap to bound by a clock: ROADMAP D12)
+    tiles(named, 'turbo_gate', GATE)
+    tiles(named, 'turbo_commit', COMMIT)
 
     # every span of the call, on the calling thread, under its apply_batch
     (batch,) = named['apply_batch']
@@ -334,6 +334,325 @@ def test_what_the_general_gate_asks_of_history_is_noted_on_its_phase():
 
 
 # ---------------------------------------------------------------------------
+# the seam: turbo_setup, turbo_stage and turbo_dispatch tiled (ISSUE-39)
+# ---------------------------------------------------------------------------
+
+SETUP = ['setup.engines', 'setup.buffers']
+ROOT = ['root.rows', 'root.keys', 'root.index']
+GRID = ['grid.lanes', 'grid.columns', 'grid.kills']
+SEQ = ['stage.seq_rows', 'stage.seq_dispatch']
+STAGE = ['stage.flush', 'stage.actors', 'stage.values', 'stage.root']
+
+
+def text_logs(n_keystrokes=5):
+    """(the change that makes a Text at a root key, one-keystroke changes
+    on it): the first holds a root row AND sequence rows, the rest only
+    sequence rows."""
+    import automerge_tpu as am
+    doc = am.from_({'text': am.Text('ab')}, 'cc' * 16)
+    for i in range(n_keystrokes):
+        doc = am.change(doc, lambda d, i=i: d['text'].insert_at(i, 'x'))
+    log = [bytes(c) for c in am.get_all_changes(doc)]
+    return log[:1], log[1:]
+
+
+def map_only(fleet_kw):
+    fleet = DocFleet(doc_capacity=2, key_capacity=8, **fleet_kw)
+    return fleet, init_docs(2, fleet), [two_headed_log(0), linear_log(1)]
+
+
+def text_only(fleet_kw):
+    """A Text that is already there: the call brings keystrokes alone."""
+    fleet = DocFleet(doc_capacity=2, key_capacity=8, **fleet_kw)
+    make, keystrokes = text_logs()
+    handles, _ = apply_changes_docs(init_docs(2, fleet), [make, make],
+                                    mirror=False)
+    return fleet, handles, [keystrokes, keystrokes[:3]]
+
+
+def both(fleet_kw):
+    """One document makes a Text and types into it, one sets map keys."""
+    fleet = DocFleet(doc_capacity=2, key_capacity=8, **fleet_kw)
+    make, keystrokes = text_logs()
+    return fleet, init_docs(2, fleet), [make + keystrokes, linear_log(1)]
+
+
+def tiles(named, parent_name, parts):
+    """`parts`, each recorded once, are children of the one `parent_name`
+    span in this order, each opening at the instant the one before closes,
+    the first where the parent opens and the last where it closes."""
+    (parent,) = named[parent_name]
+    assert all(len(named.get(name, ())) == 1 for name in parts), \
+        (parent_name, {name: len(named.get(name, ())) for name in parts})
+    subs = [named[name][0] for name in parts]
+    assert all(sub['parent'] == parent['id'] for sub in subs), parent_name
+    assert all(sub['root'] == parent['root'] for sub in subs)
+    for before, after in zip(subs, subs[1:]):
+        assert after['t0_ns'] == before['t1_ns'], (before['name'],
+                                                   after['name'])
+    assert subs[0]['t0_ns'] == parent['t0_ns'], parent_name
+    assert subs[-1]['t1_ns'] == parent['t1_ns'], parent_name
+    # and nothing else is a child of the parent but what a part calls
+    others = {s['name'] for spans in named.values() for s in spans
+              if s['parent'] == parent['id']} - set(parts)
+    assert others <= {'gc'}, (parent_name, others)
+
+
+CALLS = pytest.mark.parametrize('make_call,root_rows,seq_rows', [
+    (map_only, True, False),
+    (text_only, False, True),
+    (both, True, True),
+])
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+@CALLS
+def test_sub_phases_tile_turbo_setup_stage_and_dispatch(
+        make_call, root_rows, seq_rows):
+    tiled_call(make_call, root_rows, seq_rows, {})
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+@CALLS
+def test_sub_phases_tile_a_call_on_the_register_engine(
+        make_call, root_rows, seq_rows):
+    """The same tiling where root rows go to `apply_register_batch_donated`
+    and not to the grid: no `dispatch.note`, no `grid.kills`. (A family of
+    its own: each compiles its engine's kernels, and `test_slow_audit`
+    budgets a family.)"""
+    tiled_call(make_call, root_rows, seq_rows, {'exact_device': True})
+
+
+def tiled_call(make_call, root_rows, seq_rows, fleet_kw):
+    fleet, handles, per_doc = make_call(fleet_kw)
+    obs_spans.enable(capacity=256)
+    apply_changes_docs(handles, per_doc, mirror=False)
+    observability.disable()
+    assert fleet.metrics.fallbacks == 0
+    spans = observability.iter_spans()
+    assert observability.spans_dropped() == 0
+    named = by_name(spans)
+
+    tiles(named, 'turbo_setup', SETUP)
+    assert named['setup.buffers'][0]['attrs'] == {'queued': 0}
+    tiles(named, 'turbo_gate', GATE)
+    tiles(named, 'turbo_commit', COMMIT)
+    # the work's name says which work it is, its parent where it ran:
+    # sequence rows are staged under turbo_stage in a call without root
+    # rows, and behind the grid's (or the registers') enqueue otherwise
+    grid = ['stage.grid'] if root_rows else []
+    tiles(named, 'turbo_stage',
+          STAGE + grid + (SEQ if seq_rows and not root_rows else []))
+    tiles(named, 'stage.root', ROOT)
+    if root_rows:
+        note = [] if fleet_kw else ['dispatch.note']
+        tiles(named, 'turbo_dispatch',
+              ['dispatch.enqueue'] + note + (SEQ if seq_rows else []))
+        tiles(named, 'stage.grid', GRID[:2] if fleet_kw else GRID)
+        assert named['turbo_dispatch'][0]['t0_ns'] == \
+            named['turbo_stage'][0]['t1_ns']
+    else:
+        assert 'turbo_dispatch' not in named and 'stage.grid' not in named
+    # the children the issue leaves where they were
+    (flush,) = named['fleet_flush']
+    assert flush['parent'] == named['stage.flush'][0]['id']
+    if root_rows and not fleet_kw:
+        assert named['dispatch_grid'][0]['parent'] == \
+            named['dispatch.enqueue'][0]['id']
+    if seq_rows:
+        (rows,) = named['stage.seq_rows']
+        n_ops = 7 if root_rows else 8      # keystrokes; the make's 'ab' too
+        assert rows['attrs'] == {'rows': n_ops,
+                                 'objects': 1 if root_rows else 2}
+        (dispatch,) = named['dispatch_seq']
+        assert dispatch['parent'] == named['stage.seq_dispatch'][0]['id']
+        assert [s['parent'] for s in named['seq.enqueue']] == \
+            [dispatch['id']]
+    else:
+        assert not set(SEQ) & set(named)
+    # the six phases tile the call's turbo part as before
+    phases = [named[name][0] for name in (
+        'turbo_setup', 'turbo_parse', 'turbo_gate', 'turbo_commit',
+        'turbo_stage') + (('turbo_dispatch',) if root_rows else ())]
+    for before, after in zip(phases, phases[1:]):
+        assert after['t0_ns'] == before['t1_ns']
+    (batch,) = named['apply_batch']
+    mine = [s for s in spans if s['tid'] == threading.get_ident()]
+    assert all(s['root'] == batch['id'] for s in mine)
+    # the CPU clock is read at the call's two ends (it is a system call:
+    # two reads a call, not forty) and inside no phase
+    assert isinstance(batch['thread_cpu_ns'], int)
+    assert all(s['thread_cpu_ns'] is None for s in mine if s is not batch)
+    assert obs_spans._open_spans.stack == []    # nothing is left open
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+def test_a_raise_inside_the_stage_closes_every_phase(monkeypatch):
+    fleet, handles, per_doc = both({})
+
+    def refuse(*_columns):
+        raise ZeroDivisionError('the op index is full')
+    monkeypatch.setattr(fleet, '_index_ops', refuse)
+    obs_spans.enable(capacity=256)
+    with pytest.raises(ZeroDivisionError):
+        apply_changes_docs(handles, per_doc, mirror=False)
+    observability.disable()
+    named = by_name(observability.iter_spans())
+    # raised inside root.index, inside stage.root, inside turbo_stage:
+    # all three closed at one instant, nothing after them opened, and the
+    # error is named on the call's root
+    tiles(named, 'stage.root', ROOT)
+    tiles(named, 'turbo_stage', STAGE)
+    assert not {'stage.grid', 'turbo_dispatch', 'dispatch.enqueue',
+                'stage.seq_rows'} & set(named)
+    assert named['apply_batch'][0]['error'] == 'ZeroDivisionError'
+    assert named['turbo_stage'][0]['root'] == named['apply_batch'][0]['id']
+    assert obs_spans._open_spans.stack == []
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+def test_a_call_that_queues_everything_ends_with_its_commit():
+    """No change is causally ready: nothing is staged or dispatched, and
+    `turbo_commit` closes where its last sub-phase does."""
+    fleet = DocFleet(doc_capacity=2, key_capacity=8)
+    handles = init_docs(2, fleet)
+    obs_spans.enable(capacity=64)
+    handles, _ = apply_changes_docs(
+        handles, [linear_log(0)[1:], linear_log(1)[2:]], mirror=False)
+    observability.disable()
+    named = by_name(observability.iter_spans())
+    assert [len(h['state'].queue) for h in handles] == [5, 4]
+    tiles(named, 'turbo_setup', SETUP)
+    tiles(named, 'turbo_gate', GATE)
+    tiles(named, 'turbo_commit', COMMIT)
+    assert not {'turbo_stage', 'turbo_dispatch', 'stage.flush'} & set(named)
+    assert obs_spans._open_spans.stack == []
+
+
+def test_a_sequence_that_tiles_a_phase_shares_its_marks():
+    obs_spans.enable(capacity=16)
+    outer, inner = observability.span_seq(), observability.span_seq()
+    inner.mark('p1.x', at=outer.mark('p1'))
+    inner.mark('p1.y')
+    inner.mark('p2.x', at=outer.mark('p2', at=inner.done()))
+    assert inner.done(at=None) is not None
+    assert outer.done(at=inner.done()) is not None     # inner: not running
+    named = by_name(observability.iter_spans())
+    tiles(named, 'p1', ['p1.x', 'p1.y'])
+    assert named['p2.x'][0]['t0_ns'] == named['p2'][0]['t0_ns'] == \
+        named['p1'][0]['t1_ns']
+    assert named['p2.x'][0]['parent'] == named['p2'][0]['id']
+    # off, marks hand nothing on and take anything
+    observability.disable()
+    off = observability.span_seq()
+    assert off.mark('q', at=None) is None and off.done(at=None) is None
+
+
+# ---------------------------------------------------------------------------
+# the thread's CPU clock beside the wall clock (ISSUE-39)
+# ---------------------------------------------------------------------------
+
+def one_span():
+    with observability.span('timed'):
+        pass
+
+
+def one_phase():
+    seq = observability.span_seq()
+    seq.mark('timed')
+    seq.done()
+
+
+def one_collection():
+    gc.collect()
+
+
+def one_slice():
+    observability.record_span('timed', 1, 2, tid=99)
+
+
+def under(depth, record):
+    """`record` run `depth` spans deep."""
+    def nested():
+        if depth:
+            with observability.span(f'level{depth}'):
+                under(depth - 1, record)()
+        else:
+            record()
+    return nested
+
+
+@pytest.mark.parametrize('record,name,has_clock', [
+    (one_span, 'timed', True), (one_phase, 'timed', True),
+    (one_collection, 'gc', True), (one_slice, 'timed', False),
+    # under a root the clock (a system call) is not read
+    (under(1, one_span), 'timed', False),
+    (under(1, one_phase), 'timed', False),
+    (under(2, one_collection), 'gc', False)])
+def test_thread_cpu_ns_is_on_a_root_span_and_on_nothing_under_it(
+        record, name, has_clock):
+    obs_spans.enable(capacity=8)
+    record()
+    (span,) = [s for s in observability.iter_spans()
+               if not s['name'].startswith('level')]
+    assert span['name'] == name
+    if has_clock:
+        assert isinstance(span['thread_cpu_ns'], int)
+        assert span['thread_cpu_ns'] >= 0
+    else:
+        assert span['thread_cpu_ns'] is None
+    (event,) = [e for e in observability.export_chrome_trace()
+                if e['name'] == name]
+    assert ('thread_cpu_ns' in event['args']) == has_clock
+
+
+def test_a_sleeping_span_burns_no_cpu():
+    """Wall time passes, the thread's CPU time does not, however loaded
+    the machine is: the difference is what a span spent off the CPU."""
+    obs_spans.enable(capacity=8)
+    with observability.span('asleep'):
+        time.sleep(0.02)
+    seq = observability.span_seq()
+    seq.mark('asleep')
+    time.sleep(0.02)
+    seq.done()
+    for span in observability.iter_spans():
+        assert span['dur_ns'] >= 20_000_000
+        assert span['thread_cpu_ns'] < span['dur_ns'] / 2
+
+
+
+def test_the_report_keeps_thread_cpu_apart_from_its_cpu_column(tmp_path):
+    """`tools/obs_report.py` calls summed durations "cpu": the thread's CPU
+    time has a column of its own, and a flight dump's spans carry it."""
+    import io
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), 'tools'))
+    import obs_report
+    obs_spans.enable(capacity=8)
+    with observability.span('asleep'):
+        time.sleep(0.02)
+    observability.record_span('slice', 1, 2, tid=99)
+    path = tmp_path / 'trace.json'
+    observability.export_chrome_trace(str(path))
+    out = io.StringIO()
+    obs_report.render_trace(str(path), out=out)
+    header, *rows = out.getvalue().splitlines()[1:]
+    assert header.split()[-2:] == ['thread_cpu', 'ms']
+    table = {row.split()[0]: row.split() for row in rows}
+    assert table['slice'][-1] == '-'
+    assert float(table['asleep'][2]) >= 20.0            # "cpu ms": duration
+    assert float(table['asleep'][-1]) < 10.0            # what the thread ran
+    dump = tmp_path / 'flight.json'
+    dump.write_text(__import__('json').dumps(
+        {'recent_spans': observability.iter_spans()}))
+    ran = obs_report.thread_cpu_ms(obs_report.load_events(str(dump)))
+    assert set(ran) == {'asleep'} and ran['asleep'] < 10.0
+
+
+# ---------------------------------------------------------------------------
 # off is off
 # ---------------------------------------------------------------------------
 
@@ -348,11 +667,22 @@ def test_with_spans_off_nothing_is_recorded_and_no_annotation_is_made(
     # neither the lazy import nor the class an earlier enable() found
     monkeypatch.setattr(obs_spans, '_import_annotation', refuse)
     monkeypatch.setattr(obs_spans, '_annotation', refuse)
+
+    def no_cpu_clock():
+        raise AssertionError('the CPU clock read with spans off')
+    monkeypatch.setattr(time, 'thread_time_ns', no_cpu_clock)
     assert obs_spans._on_gc not in gc.callbacks
     fleet = DocFleet(doc_capacity=2, key_capacity=8)
     handles = init_docs(2, fleet)
-    apply_changes_docs(handles, [two_headed_log(0), linear_log(1)],
-                       mirror=False)
+    handles, _ = apply_changes_docs(
+        handles, [two_headed_log(0), linear_log(1)], mirror=False)
+    # and a call that stages grid rows and sequence rows, and one that
+    # stages sequence rows alone
+    make, keystrokes = text_logs()
+    handles, _ = apply_changes_docs(handles, [make + keystrokes[:2], []],
+                                    mirror=False)
+    apply_changes_docs(handles, [keystrokes[2:], []], mirror=False)
+    assert fleet.metrics.fallbacks == 0 and fleet.metrics.seq_ops == 7
     gc.collect()
     assert observability.iter_spans() == []
     assert observability.span_count() == 0

@@ -45,6 +45,9 @@ gate.general inside turbo_gate inside apply_batch), so the wall column
 counts a millisecond once per level; the self column counts it once, in
 the narrowest span that held it, and sums to the traced wall of each
 thread. A collection is a ``gc`` span under the phase it interrupted.
+The last column, ``thread_cpu ms``, is the spans' own ``thread_cpu_ns``
+(what their thread ran; ``-`` where the export carries none): "cpu ms"
+is summed durations and says nothing about the CPU.
 
 Flight mode pretty-prints a forensic dump: trigger, per-doc errors
 (slot, durable id, stage, typed error), then the surrounding event ring.
@@ -112,7 +115,8 @@ def load_events(path, phases=('X',)):
                      'dur': s['dur_ns'] / 1000.0,
                      'tid': s.get('tid', 0) % 1_000_000,
                      'args': dict(s.get('attrs') or {}, id=s.get('id'),
-                                  parent=s.get('parent'))}
+                                  parent=s.get('parent'),
+                                  thread_cpu_ns=s.get('thread_cpu_ns'))}
                     for s in data['recent_spans']]
         else:
             data = []
@@ -177,21 +181,38 @@ def attribution(events):
     return rows, wall
 
 
+def thread_cpu_ms(events):
+    """{name: ms} of the spans' own `thread_cpu_ns` (what each span's
+    thread RAN inside it, spans.py), over the events that carry one. Not
+    the table's "cpu ms", which is summed DURATIONS: a span that slept,
+    or whose thread lost the core, is long there and short here."""
+    out = {}
+    for e in events:
+        ns = (e.get('args') or {}).get('thread_cpu_ns')
+        if ns is not None:
+            name = e.get('name', '?')
+            out[name] = out.get(name, 0.0) + ns / 1e6
+    return out
+
+
 def render_trace(path, out=None):
     events = load_events(path)
     rows, wall = attribution(events)
+    ran = thread_cpu_ms(events)
     print(f'# {path}: {len(events)} spans, wall {wall / 1000.0:.2f} ms',
           file=out)
     print(f'{"phase":<24}{"calls":>7}{"cpu ms":>10}{"wall ms":>10}'
           f'{"par":>6}{"mean ms":>10}{"max ms":>10}{"% wall":>8}'
-          f'{"self ms":>10}{"% self":>8}', file=out)
+          f'{"self ms":>10}{"% self":>8}{"thread_cpu ms":>15}', file=out)
     for name, n, tot, wall_n, mean, mx, pct, own in rows:
         par = tot / wall_n if wall_n else 1.0
+        on_cpu = f'{ran[name]:.3f}' if name in ran else '-'
         print(f'{name:<24}{n:>7}{tot / 1000.0:>10.3f}'
               f'{wall_n / 1000.0:>10.3f}{par:>6.2f}'
               f'{mean / 1000.0:>10.3f}{mx / 1000.0:>10.3f}{pct:>8.1f}'
               f'{own / 1000.0:>10.3f}'
-              f'{100.0 * own / wall if wall else 0.0:>8.1f}', file=out)
+              f'{100.0 * own / wall if wall else 0.0:>8.1f}'
+              f'{on_cpu:>15}', file=out)
     # Pool view: per-slice parse spans carry worker/chunk attrs; cpu/wall
     # over them is the measured pool parallelism, and occupancy relates
     # that to the configured lane count when the spans recorded it.
