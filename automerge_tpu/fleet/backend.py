@@ -64,7 +64,7 @@ from ..backend.op_set import OpSet
 from ..columnar import decode_change, OBJECT_TYPE
 from .tensor_doc import (ACTOR_BITS, CTR_LIMIT, FleetState, MAX_ACTORS,
                          TOMBSTONE, pack_op_id)
-from .ingest import KeyInterner
+from .ingest import KeyInterner, doc_runs, layout_doc_runs
 
 _FLAT_ACTIONS = ('set', 'del', 'inc')
 _SEQ_MAKE = ('makeText', 'makeList')
@@ -4093,7 +4093,8 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     two phases runs them: the name says which work, the parent where it
     ran. `stage.root` and `stage.grid`, over 40 % of `turbo_stage` in the
     bulk cell, are split once more by a third sequence: `root.rows` /
-    `root.keys` / `root.index`, `grid.lanes` / `grid.columns` /
+    `root.keys` / `root.index`, `grid.lanes` / `grid.columns` (`runs`,
+    the document runs laid out; `ragged`, those shorter than the longest) /
     `grid.kills`. The first `seq.enqueue` or `dispatch.enqueue` of a call
     is where the device gets its work: the benchmark splits a call there
     (`seam.pre_enqueue_ms_per_step`, `seam.post_enqueue_ms_per_step`).
@@ -5104,40 +5105,36 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
         vals_root = kept_vals_all[keep_root]
         flags_root = kept_flags_all[keep_root]
         del_sel = (flags_root == 1) & (vals_root == TOMBSTONE)
-        # Lane layout without the old argsort pass: kept root rows are
-        # already doc-contiguous (the parser emits rows in change order,
-        # changes in doc order), so each row's lane is its rank within
-        # its doc run — run boundaries + one repeat, no permutation.
-        n_root = len(slots)
-        run_starts = np.r_[0, np.flatnonzero(doc_arr[1:] != doc_arr[:-1])
-                           + 1] if n_root else np.zeros(0, dtype=np.int64)
-        run_lens = np.diff(np.r_[run_starts, n_root])
-        pos = np.arange(n_root) - np.repeat(run_starts, run_lens)
-        max_ops = max(int(run_lens.max()) if n_root else 0, 1)
-        part.mark('grid.columns')
-        shape = (n_cap, max_ops)
-        grid_cols = {name: np.zeros(shape, dtype=np.int32)
-                     for name in ('key_id', 'packed', 'value')}
-        is_set = np.zeros(shape, dtype=bool)
-        is_inc = np.zeros(shape, dtype=bool)
-        valid = np.zeros(shape, dtype=bool)
-        grid_cols['key_id'][slots, pos] = key
-        grid_cols['packed'][slots, pos] = packed
-        grid_cols['value'][slots, pos] = vals_root
+        # Lane layout by document runs: kept root rows are already
+        # doc-contiguous (the parser emits rows in change order, changes in
+        # doc order; gate.order reorders rows only inside a document, and a
+        # call that names a document twice left at gate.chain), so a
+        # document's rows are one run and fill the prefix of its grid row
+        # in their own order — a block a run, no index a row.
+        run_starts, run_lens = doc_runs(doc_arr)
+        max_ops = max(int(run_lens.max()), 1)
+        part.mark('grid.columns', runs=len(run_lens),
+                  ragged=int((run_lens < max_ops).sum()))
         flags_laid = np.where(del_sel, 0, flags_root)
-        is_set[slots, pos] = flags_laid == 1
-        is_inc[slots, pos] = flags_laid == 2
-        valid[slots, pos] = flags_laid != 0
-        batch = OpBatch(grid_cols['key_id'], grid_cols['packed'],
-                        grid_cols['value'], is_set, is_inc, valid)
+        key_id, packed_id, value_id, flags_grid = layout_doc_runs(
+            slots[run_starts], run_lens, max_ops, n_cap,
+            (key, packed, vals_root, flags_laid),
+            (np.int32, np.int32, np.int32, flags_laid.dtype))
+        batch = OpBatch(key_id, packed_id, value_id, flags_grid == 1,
+                        flags_grid == 2, flags_grid != 0)
 
         part.mark('grid.kills')
         kills = None
         kill_doc = kill_key_f = kill_packed_f = ()
-        pred_counts = np.diff(rows['pred_off'])
-        counts_root = pred_counts[keep_root]
-        off_root = rows['pred_off'][:-1][keep_root]
-        if del_sel.any():
+        inc_sel = flags_root == 2
+        has_inc, has_del = bool(inc_sel.any()), bool(del_sel.any())
+        if has_inc or has_del:
+            # the root rows' pred runs: the kill lanes and the incs'
+            # attribution read them, and nothing else does
+            pred_counts = np.diff(rows['pred_off'])
+            counts_root = pred_counts[keep_root]
+            off_root = rows['pred_off'][:-1][keep_root]
+        if has_del:
             from .ingest import build_kill_lanes, layout_doc_rows
             # full-batch del mask (keep_root-aligned del_sel scattered
             # back) selects the del rows' pred runs out of the
@@ -5164,11 +5161,10 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
         # host winner mirror with this batch's set and kill rows and
         # verify each inc's pred against the post-batch winner
         set_sel = (flags_root == 1) & ~del_sel
-        inc_sel = flags_root == 2
-        if set_sel.any() or inc_sel.any() or del_sel.any():
+        if set_sel.any() or has_inc or has_del:
             inc_preds = _max_pred_per_inc(
                 rows['pred'], off_root[inc_sel], counts_root[inc_sel],
-                actor_map)
+                actor_map) if has_inc else np.full(0, -1, dtype=np.int64)
             fleet._note_grid_batch(slots[set_sel], key[set_sel],
                                    packed[set_sel], slots[inc_sel],
                                    key[inc_sel], inc_preds,

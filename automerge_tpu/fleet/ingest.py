@@ -145,6 +145,38 @@ def layout_doc_rows(doc, n_docs, cols, dtypes):
     return out, (order, doc_sorted, pos)
 
 
+def doc_runs(doc):
+    """(starts, lengths) of the runs of equal values in a row column."""
+    n = len(doc)
+    if not n:
+        return np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64)
+    starts = np.r_[0, np.flatnonzero(doc[1:] != doc[:-1]) + 1]
+    return starts, np.diff(np.r_[starts, n])
+
+
+def layout_doc_runs(run_doc, run_lens, max_ops, n_docs, cols, dtypes):
+    """Lay out flat rows that stand in document runs into padded
+    [n_docs, max_ops] arrays, one run at a time: run r's rows fill the
+    prefix of row run_doc[r], in their own order. Each document holds one
+    run at most (no run_doc twice); that is `layout_doc_rows`' result with
+    no index per row. Where every run is max_ops long a column is a plain
+    reshape of its runs; else one mask of the runs' prefixes places them."""
+    n_runs = len(run_lens)
+    full = bool((run_lens == max_ops).all())
+    mask = None if full else np.arange(max_ops) < run_lens[:, None]
+    out = []
+    for col, dt in zip(cols, dtypes):
+        arr = np.zeros((n_docs, max_ops), dtype=dt)
+        if full:
+            arr[run_doc] = col.reshape(n_runs, max_ops)
+        else:
+            block = np.zeros((n_runs, max_ops), dtype=dt)
+            block[mask] = col
+            arr[run_doc] = block
+        out.append(arr)
+    return out
+
+
 def build_kill_lanes(del_doc, del_key, del_pred_counts, praw, actor_map,
                      on_bad_actor=None):
     """Shared delete kill-lane construction (used by the native flush and

@@ -6,7 +6,8 @@ spans under what they interrupted; and nothing of it happens while spans
 are off. Since ISSUE-39 `turbo_stage`, `turbo_dispatch` and `turbo_setup`
 are tiled too (`stage.*`, `dispatch.*`, `setup.*`, exactly: a sub-phase
 opens AT its parent's mark), `stage.root` and `stage.grid` once more
-(`root.*`, `grid.*`), and a call's root span carries `thread_cpu_ns`. All
+(`root.*`, `grid.*`; `grid.columns` notes the document runs it lays out
+since ISSUE-40), and a call's root span carries `thread_cpu_ns`. All
 of it is held structurally: order, parentage, shared instants, no clock
 budget."""
 
@@ -449,6 +450,12 @@ def tiled_call(make_call, root_rows, seq_rows, fleet_kw):
         tiles(named, 'turbo_dispatch',
               ['dispatch.enqueue'] + note + (SEQ if seq_rows else []))
         tiles(named, 'stage.grid', GRID[:2] if fleet_kw else GRID)
+        if not fleet_kw:
+            # the grid's columns are laid out a document run at a time
+            # (ISSUE-40): both documents have root rows, six each in
+            # `map_only`; in `both` the Text's make is one, the map's six
+            assert named['grid.columns'][0]['attrs'] == {
+                'runs': 2, 'ragged': 0 if make_call is map_only else 1}
         assert named['turbo_dispatch'][0]['t0_ns'] == \
             named['turbo_stage'][0]['t1_ns']
     else:
