@@ -6,6 +6,11 @@ a streaming two-pointer merge per document, all documents' ops land as padded
 [N, P] columns and per-key LWW resolution becomes a scatter-max of packed
 opIds over the [N, K] key grid. Counter accumulation is a scatter-add.
 
+A batch row is a document: row r is doc `rows[r]` (one slot a row; a
+padding row's lanes are all invalid), or, with no `rows`, row i is doc i:
+a batch at the fleet's capacity, the form a sharded fleet takes, whose
+row numbers are an iota the partitioner keeps on each device's rows.
+
 Everything is static-shape, fusion-friendly gather/scatter on the VPU; no
 data-dependent Python control flow, so the whole step is one `jit` region
 that XLA pipelines across the fleet.
@@ -18,15 +23,22 @@ from ..observability.perf import instrument_kernel
 from .tensor_doc import FleetState
 
 
-def _apply_op_batch_impl(state, ops):
+def _row_docs(state, rows, shape):
+    """The doc of every lane of `shape` [rows, lanes]: the batch row's
+    slot (`rows`), or the row's own index where rows is None."""
+    if rows is None:
+        rows = jnp.arange(state.winners.shape[0], dtype=jnp.int32)
+    return jnp.broadcast_to(rows[:, None], shape)
+
+
+def _apply_op_batch_impl(state, ops, rows=None):
     """Apply one OpBatch to the fleet. Returns (new_state, stats).
 
     `stats` is a per-fleet vector of ops applied (useful as a psum'd health
     metric when the fleet is sharded across hosts).
     """
-    n_docs, n_slots = state.winners.shape
-    doc_idx = jnp.arange(n_docs, dtype=jnp.int32)[:, None]
-    doc_idx = jnp.broadcast_to(doc_idx, ops.key_id.shape)
+    n_slots = state.winners.shape[1]
+    doc_idx = _row_docs(state, rows, ops.key_id.shape)
 
     # Padded/invalid lanes scatter into the scratch column (n_slots - 1)
     scratch = n_slots - 1
@@ -68,7 +80,7 @@ apply_op_batch = instrument_kernel(
     'apply_op_batch', jax.jit(_apply_op_batch_impl))
 
 
-def _apply_op_batch_noinc_impl(state, ops):
+def _apply_op_batch_noinc_impl(state, ops, rows=None):
     """Set-only batches (no inc lanes — the caller checks host-side):
     skips the counter machinery entirely. The counter grid passes
     through UNTOUCHED — with donation that is a buffer alias, so the
@@ -84,10 +96,8 @@ def _apply_op_batch_noinc_impl(state, ops):
     (or a bulk load installing counter cells) pins the fleet to the
     general kernel for good. Pinned against the general kernel by
     test_noinc_kernel_matches_general."""
-    n_docs, n_slots = state.winners.shape
-    doc_idx = jnp.arange(n_docs, dtype=jnp.int32)[:, None]
-    doc_idx = jnp.broadcast_to(doc_idx, ops.key_id.shape)
-    scratch = n_slots - 1
+    scratch = state.winners.shape[1] - 1
+    doc_idx = _row_docs(state, rows, ops.key_id.shape)
     set_mask = ops.is_set & ops.valid
     set_key = jnp.where(set_mask, ops.key_id, scratch)
     winners = state.winners.at[doc_idx, set_key].max(
@@ -105,9 +115,9 @@ apply_op_batch_noinc_donated = instrument_kernel(
     jax.jit(_apply_op_batch_noinc_impl, donate_argnums=(0,)))
 
 
-def _apply_op_batch_noinc_fresh_impl(ops, n_docs, n_keys):
+def _apply_op_batch_noinc_fresh_impl(ops, n_docs, n_keys, rows=None):
     return _apply_op_batch_noinc_impl(
-        FleetState.empty(n_docs, n_keys, xp=jnp), ops)
+        FleetState.empty(n_docs, n_keys, xp=jnp), ops, rows)
 
 
 apply_op_batch_noinc_fresh = instrument_kernel(
@@ -115,19 +125,21 @@ apply_op_batch_noinc_fresh = instrument_kernel(
     jax.jit(_apply_op_batch_noinc_fresh_impl, static_argnums=(1, 2)))
 
 
-def _apply_op_batch_kills_impl(state, ops, kill_key, kill_packed):
+def _apply_op_batch_kills_impl(state, ops, kill_key, kill_packed,
+                               rows=None):
     """Apply one OpBatch plus delete "kill lanes" with the reference's
     pred-scoped delete semantics (ref backend/new.js:1204-1217: a delete
     adds succ entries ONLY to the ops it preds; concurrent sets it never
     saw stay visible and resurrect the key).
 
-    kill_key/kill_packed are [N, Q] lanes: each carries the packed opId a
-    delete op preds (0 = unused lane) and the fleet key the delete
-    targets. A kill (1) clears the standing winner iff it holds exactly
-    that packed opId, and (2) masks any same-batch set lane carrying that
-    opId. Nothing else is touched — in particular a concurrent set with a
-    LOWER packed id than the delete wins the key afterwards, which the
-    old tombstone-scatter model got wrong (the delete's own opId beat it).
+    kill_key/kill_packed are [N, Q] lanes, row for row with the batch's:
+    each carries the packed opId a delete op preds (0 = unused lane) and
+    the fleet key the delete targets. A kill (1) clears the standing
+    winner iff it holds exactly that packed opId, and (2) masks any
+    same-batch set lane carrying that opId. Nothing else is touched — in
+    particular a concurrent set with a LOWER packed id than the delete
+    wins the key afterwards, which the old tombstone-scatter model got
+    wrong (the delete's own opId beat it).
 
     Causality makes this exact for single-winner semantics across
     batches: a delete can only pred ops its change causally saw, so an op
@@ -135,11 +147,9 @@ def _apply_op_batch_kills_impl(state, ops, kill_key, kill_packed):
     clearing to 0 and letting later scatter-max resurrect is precisely
     the reference's succNum == 0 visibility rule, projected onto the
     grid's Lamport-max single-winner view."""
-    n_docs, n_slots = state.winners.shape
-    scratch = n_slots - 1
+    scratch = state.winners.shape[1] - 1
     kvalid = kill_packed > 0
-    kdoc = jnp.broadcast_to(jnp.arange(n_docs, dtype=jnp.int32)[:, None],
-                            kill_key.shape)
+    kdoc = _row_docs(state, rows, kill_key.shape)
     kkey = jnp.where(kvalid, kill_key, scratch)
     standing = state.winners[kdoc, kkey]
     hit = kvalid & (standing == kill_packed)
@@ -165,7 +175,7 @@ def _apply_op_batch_kills_impl(state, ops, kill_key, kill_packed):
                    ops.packed) & (ops.packed > 0)
     masked = type(ops)(ops.key_id, ops.packed, ops.value,
                        ops.is_set & ~lane_killed, ops.is_inc, ops.valid)
-    return _apply_op_batch_impl(cleared, masked)
+    return _apply_op_batch_impl(cleared, masked, rows)
 
 
 apply_op_batch_kills = instrument_kernel(
@@ -192,7 +202,7 @@ apply_op_batch_donated = instrument_kernel(
     jax.jit(_apply_op_batch_impl, donate_argnums=(0,)))
 
 
-def _apply_op_batch_fresh_impl(ops, n_docs, n_keys):
+def _apply_op_batch_fresh_impl(ops, n_docs, n_keys, rows=None):
     """First dispatch of a FRESH fleet: the zero state is created inside
     the jit, so XLA fuses the fill with the scatter instead of running a
     separate whole-grid memset dispatch first — a fresh 10k-doc x 1k-key
@@ -201,7 +211,7 @@ def _apply_op_batch_fresh_impl(ops, n_docs, n_keys):
     Shapes are static args: one compile per capacity step, same as the
     growth path."""
     return _apply_op_batch_impl(FleetState.empty(n_docs, n_keys, xp=jnp),
-                                ops)
+                                ops, rows)
 
 
 apply_op_batch_fresh = instrument_kernel(
@@ -210,13 +220,13 @@ apply_op_batch_fresh = instrument_kernel(
 
 
 def _apply_op_batch_kills_fresh_impl(ops, kill_key, kill_packed, n_docs,
-                                     n_keys):
+                                     n_keys, rows=None):
     """Kills-aware variant of the fused fresh-state dispatch (kills
     against an all-zero grid cannot hit, but the lane masking of
     same-batch sets must still run)."""
     return _apply_op_batch_kills_impl(
         FleetState.empty(n_docs, n_keys, xp=jnp), ops, kill_key,
-        kill_packed)
+        kill_packed, rows)
 
 
 apply_op_batch_kills_fresh = instrument_kernel(
@@ -237,6 +247,19 @@ def _zero_doc_rows_impl(state, idx):
 zero_doc_rows_donated = instrument_kernel(
     'zero_doc_rows_donated',
     jax.jit(_zero_doc_rows_impl, donate_argnums=(0,)))
+
+
+def _gather_grid_rows_impl(state, idx):
+    """The given docs' rows of the three grids, stacked [3, len(idx), K+1]
+    (winners, values, counters): a point read moves these rows to the host
+    in one transfer, not the fleet. Callers pad idx to a power of two (a
+    repeated index is read twice), so a read compiles once a size class."""
+    return jnp.stack([state.winners[idx], state.values[idx],
+                      state.counters[idx]])
+
+
+gather_grid_rows = instrument_kernel('gather_grid_rows',
+                                     jax.jit(_gather_grid_rows_impl))
 
 
 def fleet_merge(state, op_batches):

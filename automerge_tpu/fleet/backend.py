@@ -37,6 +37,7 @@ import contextlib
 import copy
 import gc
 import hashlib
+import itertools
 import queue
 import threading
 import time
@@ -113,11 +114,15 @@ class _ValueTable(list):
     """Boxed-value store with dedup interning: the table grows with the
     number of DISTINCT values, not with op count (repeated strings across a
     long change log were an unbounded leak). Unhashable payloads append
-    without dedup."""
+    without dedup. `links` holds the indexes of the link entries
+    (_SeqLink / _MapLink), so a bulk read tells the cells that need a
+    subtree resolved from those that are plain values without looking at
+    each value."""
 
     def __init__(self):
         super().__init__()
         self.index = {}
+        self.links = []
 
     def intern(self, value):
         # Key by (type, value): Python equality conflates True/1/1.0 etc.,
@@ -135,6 +140,8 @@ class _ValueTable(list):
         self.append(value)
         if hashable:
             self.index[key] = idx
+        if key[0] is _SeqLink or key[0] is _MapLink:
+            self.links.append(idx)
         return idx
 
 
@@ -242,6 +249,32 @@ def _pow2(n):
     while cap < n:
         cap *= 2
     return cap
+
+
+def _held_rows(batch, kills):
+    """The mask of a grid batch's rows that hold a lane or a kill lane."""
+    held = batch.valid.any(axis=1)
+    if kills is not None:
+        held |= (kills[1] > 0).any(axis=1)
+    return held
+
+
+def _slot_rows(batch, kills):
+    """A grid batch laid out a slot a row (row i is slot i) and its kill
+    lanes, cut to the rows that hold a lane or a kill lane and padded to
+    the power of two of them: (batch, kills, rows), rows the slot of each,
+    as DocFleet._dispatch_grid takes them. A flush over a few documents of
+    a large fleet moves just their rows."""
+    slots = np.flatnonzero(_held_rows(batch, kills))
+    rows = np.zeros(_pow2(len(slots)), dtype=np.int32)
+    rows[:len(slots)] = slots
+
+    def take(col):
+        out = np.zeros((len(rows),) + col.shape[1:], dtype=col.dtype)
+        out[:len(slots)] = col[slots]
+        return out
+    return (type(batch)(*map(take, batch.tree_flatten()[0])),
+            kills and tuple(map(take, kills)), rows)
 
 
 class DocFleet:
@@ -566,15 +599,22 @@ class DocFleet:
         """Fold every doc's pending turbo-commit segments into the real
         logs — the amortized eager path bounding seam-record memory on
         write-heavy workloads that never read history (the hot path
-        stays O(1); this runs once per _SEAM_FOLD_LIMIT commits)."""
-        for seg in list(self._pend_seams):
-            for slot in list(seg.rowmap):
+        stays O(1); this runs once per _SEAM_FOLD_LIMIT commits). One pass
+        over the segments in commit order, each entry spliced into its
+        slot's log (_FlatEngine._splice): linear in the segments' entries,
+        where folding slot by slot walked every segment for each slot."""
+        segs, self._pend_seams = self._pend_seams, []
+        folded = []
+        for seg in segs:
+            for slot, ent in seg.rowmap.items():
                 eng = self._engines.get(slot)
-                if eng is None:
-                    seg.rowmap.pop(slot, None)
-                else:
-                    eng._fold_pending()
-        self._pend_seams = [s for s in self._pend_seams if s.rowmap]
+                if eng is not None:
+                    eng._splice(seg, ent)
+            folded.append(np.fromiter(seg.rowmap, dtype=np.int64,
+                                      count=len(seg.rowmap)))
+            seg.rowmap.clear()
+        if folded:
+            self.doc_cols.pend_n[np.concatenate(folded)] = 0
 
     def clone_slot(self, src):
         self.flush()
@@ -1356,18 +1396,33 @@ class DocFleet:
             (arr & ~np.int64(0xffffffff)) | shifted)
 
     @_spanned('dispatch_grid')
-    def _dispatch_grid(self, batch, kills=None):
+    def _dispatch_grid(self, batch, kills, rows):
         """One LWW-grid merge dispatch. With `kills` (a (kill_key,
-        kill_packed) [N, Q] pair from delete preds), the kills-aware
-        kernel runs so deletes only kill the ops they pred
-        (apply.apply_op_batch_kills — ref new.js:1204-1217); without, the
-        plain scatter kernel. The batch must already be padded to the
-        state's doc capacity; kills are padded here."""
+        kill_packed) [N, Q] pair from delete preds, row for row with the
+        batch), the kills-aware kernel runs so deletes only kill the ops
+        they pred (apply.apply_op_batch_kills — ref new.js:1204-1217);
+        without, the plain scatter kernel. Row r of the batch holds the ops
+        of slot rows[r], one row a slot; a padding row holds no lane. A
+        sharded fleet takes the batch spread to its capacity, row i for
+        slot i (the kernels' own row numbering), so that each device
+        scatters into the rows it holds."""
         from .apply import (apply_op_batch_donated, apply_op_batch_fresh,
                             apply_op_batch_kills_donated,
                             apply_op_batch_kills_fresh,
                             apply_op_batch_noinc_donated,
                             apply_op_batch_noinc_fresh)
+        if self.mesh is not None:
+            held = _held_rows(batch, kills)
+            at = rows[held]
+
+            def spread(col):
+                out = np.zeros((self._grid_cap(),) + col.shape[1:],
+                               dtype=col.dtype)
+                out[at] = col[held]
+                return out
+            batch = type(batch)(*map(spread, batch.tree_flatten()[0]))
+            kills = kills and tuple(map(spread, kills))
+            rows = None
         fresh = self.state is None      # deferred fresh-fleet allocation
         has_inc = bool(batch.is_inc.any())
         if has_inc:
@@ -1378,32 +1433,27 @@ class DocFleet:
                 # kernel skips ~3 whole-grid memory passes (see apply.py)
                 if fresh:
                     self.state, _stats = apply_op_batch_noinc_fresh(
-                        batch, self.doc_cap, self.key_cap)
+                        batch, self.doc_cap, self.key_cap, rows)
                 else:
                     self.state, _stats = apply_op_batch_noinc_donated(
-                        self.state, self._shard_docs(batch))
+                        self.state, self._shard_docs(batch), rows)
             elif fresh:
                 self.state, _stats = apply_op_batch_fresh(
-                    batch, self.doc_cap, self.key_cap)
+                    batch, self.doc_cap, self.key_cap, rows)
             else:
                 self.state, _stats = apply_op_batch_donated(
-                    self.state, self._shard_docs(batch))
+                    self.state, self._shard_docs(batch), rows)
         else:
             kill_key, kill_packed = kills
-            n_cap = self._grid_cap()
-            if kill_key.shape[0] < n_cap:
-                pad = n_cap - kill_key.shape[0]
-                kill_key = np.pad(kill_key, ((0, pad), (0, 0)))
-                kill_packed = np.pad(kill_packed, ((0, pad), (0, 0)))
             if fresh:
                 self.state, _stats = apply_op_batch_kills_fresh(
                     batch, kill_key, kill_packed, self.doc_cap,
-                    self.key_cap)
+                    self.key_cap, rows)
             else:
                 self.state, _stats = apply_op_batch_kills_donated(
                     self.state, self._shard_docs(batch),
                     self._shard_docs(kill_key),
-                    self._shard_docs(kill_packed))
+                    self._shard_docs(kill_packed), rows)
         self.metrics.dispatches += 1
 
     def _note_grid_batch(self, set_doc, set_key, set_packed,
@@ -1547,13 +1597,9 @@ class DocFleet:
             self._flush_mixed(per_doc, n_docs)
             return
         self._ensure_capacity(n_docs=n_docs, n_keys=len(self.keys))
-        if batch.key_id.shape[0] < self._grid_cap():
-            pad = self._grid_cap() - batch.key_id.shape[0]
-            batch = type(batch)(*(np.pad(col, ((0, pad), (0, 0)))
-                                  for col in batch.tree_flatten()[0]))
         if index_rows:
             self._index_ops(*index_rows[0])
-        self._dispatch_grid(batch, kills[0] if kills else None)
+        self._dispatch_grid(*_slot_rows(batch, kills[0] if kills else None))
         self.metrics.device_ops += int(batch.valid.sum())
         if hazard:
             self._note_grid_batch(*hazard[0])
@@ -1674,8 +1720,7 @@ class DocFleet:
                 counts[r[0]] += 1
             width = max(int(counts.max()), 1)
             self._ensure_capacity(n_docs=n_docs, n_keys=len(self.keys))
-            n_cap = self._grid_cap()
-            shape = (n_cap, width)
+            shape = (n_docs, width)
             cols = {name: np.zeros(shape, dtype=np.int32)
                     for name in ('key_id', 'packed', 'value')}
             is_set = np.zeros(shape, dtype=bool)
@@ -1704,9 +1749,9 @@ class DocFleet:
                 kk = np.array([k[1] for k in kill_rows], dtype=np.int64)
                 kp = np.array([k[2] for k in kill_rows], dtype=np.int64)
                 (kk_arr, kp_arr), _ = layout_doc_rows(
-                    kd, n_cap, (kk, kp), (np.int32, np.int32))
+                    kd, n_docs, (kk, kp), (np.int32, np.int32))
                 kills = (kk_arr, kp_arr)
-            self._dispatch_grid(batch, kills)
+            self._dispatch_grid(*_slot_rows(batch, kills))
             self.metrics.device_ops += len(rows) + len(kill_rows)
             sets = [(r[0], r[1], r[2]) for r in rows if r[4]]
             self._note_grid_batch([s[0] for s in sets], [s[1] for s in sets],
@@ -1807,43 +1852,113 @@ class DocFleet:
     # -- reads ----------------------------------------------------------
 
     def materialize_all(self):
-        """Whole-fleet state readback in one device->host transfer:
-        slot -> {key: value} with LWW winners, tombstones dropped, and
-        counter accumulators added to their base value. In exact-device
-        mode the read comes from the multi-value registers instead (winner
-        per key from the visible set, per-op counter folds)."""
+        """Whole-fleet readback: slot -> {key: value} for every slot, by
+        the point read over all of them (gather_rows, render_rows); a free
+        slot reads {}."""
         self.flush()
+        slots = np.arange(self.n_slots, dtype=np.int32)
+        return self.render_rows(slots, self.gather_rows(slots))[0]
+
+    def gather_rows(self, slots):
+        """The device rows of `slots` (distinct slot numbers): one gather
+        on the device, one program a power-of-two size class of the read,
+        so that only these rows move, never the fleet's. The grid's
+        winners, values and counters come to the host as one [3, n, K+1]
+        array; the registers' rows stay a device RegisterState, whose
+        visible sets materialize_registers reads there. None where the
+        fleet holds no device state. The caller has flushed."""
+        device_state = self.reg_state if self.exact_device else self.state
+        if device_state is None or not len(slots):
+            return None
+        idx = np.zeros(max(_pow2(len(slots)), 64), dtype=np.int32)
+        idx[:len(slots)] = slots
         if self.exact_device:
-            return self._materialize_registers()
-        if self.state is None:
-            return [{} for _ in range(self.n_slots)]
-        winners = np.asarray(self.state.winners)
-        values = np.asarray(self.state.values)
-        counters = np.asarray(self.state.counters)
-        out = []
+            from .registers import gather_register_rows
+            return gather_register_rows(device_state, idx)
+        from .apply import gather_grid_rows
+        return np.asarray(gather_grid_rows(device_state, idx))
+
+    def render_rows(self, slots, rows):
+        """({key: value} of each of `slots` from their gathered `rows`,
+        the mask of those the device cannot serve). LWW winners with
+        tombstones dropped and counter accumulators added to their base
+        value; in exact-device mode the multi-value registers (winner per
+        key from the visible set, per-op counter folds), where a slot
+        flagged inexact is one the device cannot serve. Nested maps and
+        sequences resolve into their subtrees, and a document whose
+        subtree stays an unresolved link is one the device cannot serve
+        either; a free slot reads {}."""
+        slots = np.asarray(slots, dtype=np.int32)
+        unserved = np.zeros(len(slots), dtype=bool)
+        if rows is None:
+            return [{} for _ in range(len(slots))], unserved
+        if self.exact_device:
+            from .registers import materialize_registers
+            docs = materialize_registers(rows, self.keys.keys,
+                                         value_table=self.value_table)
+            unserved[:] = np.asarray(rows.inexact)[:len(slots)]
+            out = self._render_cells(slots, [
+                [(key, value) for key, (value, _conflicts) in doc.items()]
+                for doc in docs[:len(slots)]])
+            unserved |= [_has_unresolved_link(doc) for doc in out]
+            return out, unserved
+        winners, values, counters = rows[:, :len(slots)]
+        n_keys = len(self.keys)
+        row, col = np.nonzero((winners[:, :n_keys] != 0) &
+                              (values[:, :n_keys] != TOMBSTONE))
+        raw = values[row, col]
+        table = self.value_table
+        boxed = raw <= -2
+        # one C-level walk of the table for the boxed values (a list
+        # comprehension costs four times as much at a million entries)
+        vals = list(map(table.__getitem__, np.where(
+            boxed, -raw - 2, 0).tolist())) if table else raw.tolist()
+        for j in np.flatnonzero(~boxed).tolist():
+            vals[j] = int(raw[j])
+        added = counters[row, col]
+        for j in np.flatnonzero(added).tolist():
+            if isinstance(vals[j], int) and not isinstance(vals[j], bool):
+                vals[j] += int(added[j])
+        keys = self.keys.keys
+        pairs = zip([keys[k] for k in col.tolist()], vals)
+        out = [dict(itertools.islice(pairs, n)) for n in
+               np.bincount(row, minlength=len(slots)).tolist()]
+        # a row with a nested key or a link value assembles its subtrees,
+        # and a free slot reads {}; every other row is its cells as they
+        # stand
+        tree = np.zeros(len(slots), dtype=bool)
+        nested = np.fromiter((isinstance(k, tuple) for k in keys),
+                             dtype=bool, count=n_keys)
+        tree[row[nested[col]]] = True
+        if table.links:
+            tree[row[boxed & np.isin(-raw - 2, table.links)]] = True
+        if self.free_slots:
+            tree |= np.isin(slots, self.free_slots)
+        picked = np.flatnonzero(tree).tolist()
+        for i, doc in zip(picked, self._render_cells(
+                slots[picked], [list(out[i].items()) for i in picked])):
+            out[i] = doc
+            unserved[i] = _has_unresolved_link(doc)
+        return out, unserved
+
+    def _render_cells(self, slots, cells):
+        """{key: value} of each slot from its cells, [(key, value)] in key
+        order: root keys keep their value, (objectId, key) cells fill their
+        nested map, link values resolve into their subtrees
+        (_resolve_value, every sequence row rendered once for all). A free
+        slot reads {}."""
         free = set(self.free_slots)
+        out = []
         rendered = None
-        for slot in range(self.n_slots):
+        for slot, doc_cells in zip(slots.tolist(), cells):
             if slot in free:
                 out.append({})
                 continue
-            root_cells = {}      # root key -> value
-            nested = {}          # objectId -> {key: value}
+            root_cells, nested = {}, {}
             any_seq = False
-            live = np.flatnonzero(winners[slot, :len(self.keys)])
-            for k in live:
-                v = int(values[slot, k])
-                if v == TOMBSTONE:
-                    continue
-                value = self.value_table[-v - 2] if v <= -2 else v
+            for key, value in doc_cells:
                 if isinstance(value, _SeqLink):
                     any_seq = True
-                elif not isinstance(value, _MapLink):
-                    c = int(counters[slot, k])
-                    if c and isinstance(value, int) and \
-                            not isinstance(value, bool):
-                        value += c
-                key = self.keys.keys[k]
                 if isinstance(key, tuple):
                     nested.setdefault(key[0], {})[key[1]] = value
                 else:
@@ -1882,41 +1997,6 @@ class DocFleet:
                                            depth + 1)
                     for k, v in nested.get(value.object_id, {}).items()}
         return value
-
-    def materialize(self, slot):
-        return self.materialize_all()[slot]
-
-    def _materialize_registers(self):
-        from .registers import materialize_registers
-        if self.reg_state is None:
-            return [{} for _ in range(self.n_slots)]
-        docs = materialize_registers(self.reg_state, self.keys.keys,
-                                     value_table=self.value_table)
-        free = set(self.free_slots)
-        out = []
-        rendered = None
-        for slot in range(self.n_slots):
-            if slot in free or slot >= len(docs):
-                out.append({})
-            else:
-                # Keys legitimately set to null keep their None value (the
-                # LWW grid and host mirror both report them; only absent /
-                # fully-deleted keys are omitted)
-                root_cells, nested = {}, {}
-                any_seq = False
-                for k, (v, _conflicts) in docs[slot].items():
-                    if isinstance(v, _SeqLink):
-                        any_seq = True
-                    if isinstance(k, tuple):
-                        nested.setdefault(k[0], {})[k[1]] = v
-                    else:
-                        root_cells[k] = v
-                if any_seq and rendered is None:
-                    rendered = self.render_seq_all()
-                out.append({k: self._resolve_value(slot, v, rendered or {},
-                                                   nested)
-                            for k, v in root_cells.items()})
-        return out
 
     def conflicts_all(self):
         """Exact-device only: slot -> {key: {packed opId: value}} for every
@@ -2274,21 +2354,25 @@ class _FlatEngine(HashGraph):
         r = self.slot
         if not fleet.doc_cols.pend_n[r]:
             return
-        log = self._log
-        defer = self._defer
         compact = False
         for seg in fleet._pend_seams:
             ent = seg.rowmap.pop(r, None)
             if ent is None:
                 continue
-            start, stop, base = ent
-            log.extend(seg.buffers[start:stop])
-            defer.append((base, seg.meta, range(start, stop)))
+            self._splice(seg, ent)
             if not seg.rowmap:
                 compact = True
         fleet.doc_cols.pend_n[r] = 0
         if compact:
             fleet._pend_seams = [s for s in fleet._pend_seams if s.rowmap]
+
+    def _splice(self, seg, ent):
+        """Append this doc's entries of one pending segment, `ent` its
+        (start, stop, base) in `seg`, to the log and the deferred-graph
+        records."""
+        start, stop, base = ent
+        self._log.extend(seg.buffers[start:stop])
+        self._defer.append((base, seg.meta, range(start, stop)))
 
     @property
     def _changes(self):
@@ -4094,7 +4178,8 @@ def _apply_changes_turbo(handles, per_doc_changes, parsed=None):
     ran. `stage.root` and `stage.grid`, over 40 % of `turbo_stage` in the
     bulk cell, are split once more by a third sequence: `root.rows` /
     `root.keys` / `root.index`, `grid.lanes` / `grid.columns` (`runs`,
-    the document runs laid out; `ragged`, those shorter than the longest) /
+    the document runs laid out; `ragged`, those shorter than the longest;
+    `cells`, rows times width: the op cells handed to the kernel) /
     `grid.kills`. The first `seq.enqueue` or `dispatch.enqueue` of a call
     is where the device gets its work: the benchmark splits a call there
     (`seam.pre_enqueue_ms_per_step`, `seam.post_enqueue_ms_per_step`).
@@ -5088,14 +5173,11 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
 
     if n_kept_root:
         part.mark('grid.lanes', at=sub.mark('stage.grid', at=part.done()))
-        n_slots = fleet.n_slots
-        # Fused staging: size the device state FIRST and scatter the op
-        # columns straight into capacity-shaped arrays — the old
-        # stage-then-np.pad sequence copied every column a second time on
-        # every turbo call (part of the round-5 "turbo-commit Python"
-        # budget).
-        fleet._ensure_capacity(n_docs=n_slots, n_keys=len(fleet.keys))
-        n_cap = fleet._grid_cap()
+        # Fused staging: size the device state FIRST and lay the op
+        # columns straight out in the batch's padded shape — a
+        # stage-then-np.pad sequence would copy every column a second time
+        # on every turbo call.
+        fleet._ensure_capacity(n_docs=fleet.n_slots, n_keys=len(fleet.keys))
         # Pred-scoped deletes (ref new.js:1204-1217): del rows (flags 1,
         # TOMBSTONE value — boxed values are <= -2, so -1 is del-only)
         # write no winner; their preds become kill lanes for the
@@ -5109,15 +5191,27 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
         # doc-contiguous (the parser emits rows in change order, changes in
         # doc order; gate.order reorders rows only inside a document, and a
         # call that names a document twice left at gate.chain), so a
-        # document's rows are one run and fill the prefix of its grid row
-        # in their own order — a block a run, no index a row.
+        # document's rows are one run and fill the prefix of its batch row
+        # in their own order — a block a run, no index a row. Row r holds
+        # the run of slot row_slots[r] (the kernel's `rows`), and there are
+        # as many rows as the power of two that holds the runs, so that
+        # calls of a size share one program and a call over a few
+        # documents of a large fleet moves just their rows. Runs of one
+        # length make rows of that width; ragged runs, rows of the power of
+        # two that holds the longest.
         run_starts, run_lens = doc_runs(doc_arr)
+        run_slots = slots[run_starts]
         max_ops = max(int(run_lens.max()), 1)
-        part.mark('grid.columns', runs=len(run_lens),
-                  ragged=int((run_lens < max_ops).sum()))
+        ragged = int((run_lens < max_ops).sum())
+        width = _pow2(max_ops) if ragged else max_ops
+        n_rows = _pow2(len(run_lens))
+        row_slots = np.zeros(n_rows, dtype=np.int32)
+        row_slots[:len(run_lens)] = run_slots
+        part.mark('grid.columns', runs=len(run_lens), ragged=ragged,
+                  cells=n_rows * width)
         flags_laid = np.where(del_sel, 0, flags_root)
         key_id, packed_id, value_id, flags_grid = layout_doc_runs(
-            slots[run_starts], run_lens, max_ops, n_cap,
+            np.arange(len(run_lens)), run_lens, width, n_rows,
             (key, packed, vals_root, flags_laid),
             (np.int32, np.int32, np.int32, flags_laid.dtype))
         batch = OpBatch(key_id, packed_id, value_id, flags_grid == 1,
@@ -5147,15 +5241,19 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
                 rows['pred'][np.repeat(del_all, pred_counts)], actor_map,
                 on_bad_actor=lambda ds: fleet.grid_overflow.update(
                     int(s) for s in ds))
-            # laid out at capacity so _dispatch_grid skips its pad copy
+            # laid out row for row with the batch, which is each kill's
+            # document's run
+            by_slot = np.argsort(run_slots)
+            kill_row = by_slot[np.searchsorted(run_slots, kill_doc,
+                                               sorter=by_slot)]
             (kk_arr, kp_arr), _ = layout_doc_rows(
-                kill_doc, n_cap, (kill_key_f, kill_packed_f),
+                kill_row, n_rows, (kill_key_f, kill_packed_f),
                 (np.int32, np.int32))
             kills = (kk_arr, kp_arr)
 
         at = ps.mark('turbo_dispatch', at=sub.done(at=part.done()))
         sub.mark('dispatch.enqueue', at=at)
-        fleet._dispatch_grid(batch, kills)
+        fleet._dispatch_grid(batch, kills, row_slots)
         sub.mark('dispatch.note')
         # Counter-attribution check (see _note_grid_batch): advance the
         # host winner mirror with this batch's set and kill rows and
@@ -5351,46 +5449,85 @@ def _has_unresolved_link(value):
 
 
 def materialize_docs(handles):
-    """Bulk {key: value} readback for many documents; fleet-resident docs
-    come from one device transfer, promoted docs from their host engine."""
-    by_fleet = {}
-    for handle in handles:
+    """Bulk {key: value} readback for many documents. A fleet-resident
+    document is read from the device rows of its slot: one gather a fleet
+    of just the asked slots' rows (a slot asked twice is read once, and its
+    handles share the one dict), rendered on the host
+    (DocFleet.gather_rows, render_rows). The exact host mirror serves it
+    where the device cannot: a slot in grid_overflow (counter spread past
+    the packing window) or del_fallback (a history with deletes, whose
+    winner view after kills is best-effort), one the register engine
+    flagged inexact, or one whose render left a link unresolved (a
+    device-inexact sequence row). A promoted document reads from its host
+    engine.
+
+    Traced, the call is the root span `read_batch` (`docs`), tiled by
+    `read.flush` (the handles grouped by fleet and routed, each fleet's
+    pending changes landed), `read.gather` (the device gather and its
+    transfer; `rows`), `read.render` (rows to {key: value}) and
+    `read.host` (the documents the host serves; `docs`). Each fleet counts
+    the handles asked of it (`read_docs`), the rows its gather moved
+    (`read_rows`) and the documents its host side served
+    (`read_host_docs`)."""
+    ps = _span_seq()
+    sub = _span_seq()
+    sub.mark('read.flush', at=ps.mark('read_batch', docs=len(handles)))
+    try:
+        # what a read allocates is what it returns: live on exit, as the
+        # turbo apply's (see _gc_paused)
+        with _gc_paused():
+            return _materialize_docs_inner(handles, sub)
+    finally:
+        ps.done(at=sub.done())
+
+
+def _materialize_docs_inner(handles, sub):
+    out = [None] * len(handles)
+    host = []                # positions the host serves
+    groups = {}              # fleet -> (positions, slots, promoted)
+    for i, handle in enumerate(handles):
         state = handle['state']
-        if isinstance(state, FleetDoc) and state.is_fleet:
-            fleet = state.fleet
-            if id(fleet) not in by_fleet:
-                by_fleet[id(fleet)] = fleet.materialize_all()
-    inexact_by_fleet = {}
-    out = []
-    for handle in handles:
-        state = handle['state']
-        if isinstance(state, FleetDoc) and state.is_fleet:
-            fleet = state.fleet
-            if fleet.exact_device:
-                if id(fleet) not in inexact_by_fleet:
-                    inexact_by_fleet[id(fleet)] = fleet.inexact_slots()
-                if state._impl.slot in inexact_by_fleet[id(fleet)]:
-                    # History fell outside the register engine's exact
-                    # shape: the host mirror is authoritative
-                    out.append(state.materialize())
-                    continue
-            if state._impl.slot in fleet.grid_overflow or \
-                    state._impl.slot in fleet.del_fallback:
-                # Counter spread exceeded the packing window, or the
-                # doc's history contains deletes (the grid's winner view
-                # after kills is best-effort): the exact host mirror is
-                # authoritative for this slot
-                out.append(state.materialize())
-                continue
-            raw = by_fleet[id(fleet)][state._impl.slot]
-            if _has_unresolved_link(raw):
-                # A sequence row is device-inexact (concurrent overwrite,
-                # counter in list): the host mirror serves the whole doc
-                out.append(state.materialize())
-            else:
-                out.append(raw)
-        elif isinstance(state, FleetDoc):
-            out.append(state.materialize())
-        else:
+        if not isinstance(state, FleetDoc):
             raise TypeError('materialize_docs needs fleet backend handles')
+        group = groups.get(state.fleet)
+        if group is None:
+            group = groups[state.fleet] = ([], [], [])
+        impl = state._impl
+        if isinstance(impl, _FlatEngine):
+            group[0].append(i)
+            group[1].append(impl.slot)
+        else:
+            group[2].append(i)
+    reads = []
+    for fleet, (positions, slots, promoted) in groups.items():
+        fleet.metrics.read_docs += len(positions) + len(promoted)
+        host += promoted
+        fleet.flush()
+        positions = np.asarray(positions, dtype=np.int64)
+        slots = np.asarray(slots, dtype=np.int32)
+        mirrored = fleet.grid_overflow | fleet.del_fallback
+        if mirrored:
+            to_host = np.isin(slots, list(mirrored))
+            host += positions[to_host].tolist()
+            positions, slots = positions[~to_host], slots[~to_host]
+        uniq, inverse = np.unique(slots, return_inverse=True)
+        reads.append((fleet, positions, uniq, inverse))
+    sub.mark('read.gather')
+    rows = []
+    for fleet, _positions, uniq, _inverse in reads:
+        rows.append(fleet.gather_rows(uniq))
+        fleet.metrics.read_rows += len(uniq)
+    if _spans_on():
+        sub.note(rows=sum(len(read[2]) for read in reads))
+    sub.mark('read.render')
+    for (fleet, positions, uniq, inverse), fleet_rows in zip(reads, rows):
+        docs, unserved = fleet.render_rows(uniq, fleet_rows)
+        for position, k in zip(positions.tolist(), inverse.tolist()):
+            out[position] = docs[k]
+        host += positions[unserved[inverse]].tolist()
+    sub.mark('read.host', docs=len(host))
+    for i in host:
+        state = handles[i]['state']
+        state.fleet.metrics.read_host_docs += 1
+        out[i] = state.materialize()
     return out

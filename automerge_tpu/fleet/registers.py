@@ -260,6 +260,19 @@ visible_registers = instrument_kernel(
     'visible_registers', jax.jit(_visible_registers_impl))
 
 
+def _gather_register_rows_impl(state, idx):
+    """The given docs' rows of every register tensor and their inexact
+    flags, as a RegisterState of len(idx) docs: what a point read moves to
+    the host (padded to a power of two by the caller, as the grid's)."""
+    return RegisterState(state.reg[idx], state.killed[idx],
+                         state.value[idx], state.counter[idx],
+                         state.inexact[idx])
+
+
+gather_register_rows = instrument_kernel(
+    'gather_register_rows', jax.jit(_gather_register_rows_impl))
+
+
 def rows_to_register_batch(doc_ids, flags, key_ids, packed, values,
                            pred_off, pred, n_docs, d_preds=4,
                            force_overflow=None):

@@ -132,6 +132,11 @@ class Metrics:
                                  # more than one actor
         'seq_inexact_reads',     # rows a bulk render found flagged
                                  # inexact and left to the host mirror
+        # bulk reads (fleet/backend.py materialize_docs)
+        'read_docs',             # handles asked of the fleet
+        'read_rows',             # rows the device gather moved to the host
+        'read_host_docs',        # of read_docs, the documents the host
+                                 # mirror or engine served
         # gauges, not counters: what the pools hold after the last
         # dispatch or bulk load (delta() gives their change)
         'seq_pool_bytes',        # bytes of every pool's arrays

@@ -1,8 +1,9 @@
 """The turbo seam lays the grid's op columns out a document run at a time
 (ISSUE-40: `ingest.doc_runs` / `ingest.layout_doc_runs`). Held bit for bit
-to the form it replaced, a two-dimensional scatter `arr[slots, pos] = col`
-written out here: on the helper, on the whole OpBatch a turbo call hands
-the grid kernel, and end to end against the exact path."""
+to a two-dimensional scatter `arr[rows, pos] = col` written out here: on
+the helper, on the whole OpBatch a turbo call hands the grid kernel (a row
+a document of the call, beside each row's slot), and end to end against
+the exact path."""
 
 import numpy as np
 import pytest
@@ -17,8 +18,9 @@ from automerge_tpu.fleet.tensor_doc import OpBatch
 A, B = 'aa' * 16, 'bb' * 16
 
 
-def scatter_reference(slots, n_docs, cols, dtypes):
-    """The parent's layout: each row's lane is its rank within its run."""
+def scatter_reference(slots, n_docs, cols, dtypes, width=None):
+    """The parent's layout: each row's lane is its rank within its run;
+    `width` lanes a row, the longest run's where not given."""
     n = len(slots)
     starts = np.r_[0, np.flatnonzero(slots[1:] != slots[:-1]) + 1] \
         if n else np.zeros(0, dtype=np.int64)
@@ -27,7 +29,7 @@ def scatter_reference(slots, n_docs, cols, dtypes):
     max_ops = max(int(lens.max()) if n else 0, 1)
     out = []
     for col, dt in zip(cols, dtypes):
-        arr = np.zeros((n_docs, max_ops), dtype=dt)
+        arr = np.zeros((n_docs, width or max_ops), dtype=dt)
         arr[slots, pos] = col
         out.append(arr)
     return out
@@ -164,9 +166,9 @@ def test_the_turbo_batch_is_the_scatter_and_the_exact_path(case,
     batches = []
     real_dispatch = turbo_fleet._dispatch_grid
 
-    def dispatch(batch, kills=None):
-        batches.append((batch, kills))
-        return real_dispatch(batch, kills)
+    def dispatch(batch, kills=None, rows=None):
+        batches.append((batch, kills, rows))
+        return real_dispatch(batch, kills, rows)
     turbo_fleet._dispatch_grid = dispatch
     inc_preds = []
     real_note = turbo_fleet._note_grid_batch
@@ -189,22 +191,34 @@ def test_the_turbo_batch_is_the_scatter_and_the_exact_path(case,
     assert turbo_fleet.metrics.turbo_calls == len(calls)
     assert turbo_fleet.metrics.fallbacks == 0
 
-    # each grid batch, against the scatter of the same rows
+    # each grid batch, against the scatter of the same rows: a row a
+    # document of the call, in the order of their runs, as many rows as the
+    # power of two that holds them (each row's slot beside the batch), the
+    # longest run's width, its power of two where runs differ
     assert len(batches) == len(calls) == len(seen['doc_runs'])
     slot_of = np.array([h['state']._impl.slot for h in turbo])
-    for (batch, kills), (doc_arr,), layout_args in zip(
+    for (batch, kills, rows), (doc_arr,), layout_args in zip(
             batches, seen['doc_runs'], seen['layout_doc_runs']):
-        _, _, _, n_cap, cols, dtypes = layout_args
+        cols, dtypes = layout_args[4:]
+        starts, lens = doc_runs(doc_arr)
+        width = int(lens.max())
+        if (lens < width).any():
+            width = 1 << (width - 1).bit_length()
+        n_rows = 1 << (len(lens) - 1).bit_length()
         key_id, packed, value, flags = scatter_reference(
-            slot_of[doc_arr].astype(np.int32), n_cap, cols, dtypes)
+            np.repeat(np.arange(len(lens), dtype=np.int32), lens), n_rows,
+            cols, dtypes, width)
         want = OpBatch(key_id, packed, value, flags == 1, flags == 2,
                        flags != 0)
         same_arrays(batch.tree_flatten()[0], want.tree_flatten()[0])
+        same_arrays([rows], [np.r_[slot_of[doc_arr[starts]],
+                                   np.zeros(n_rows - len(lens))]
+                             .astype(np.int32)])
     # the call with deletes and incs still reads its pred offsets: kill
     # lanes for the deletes, each inc's pred (op 3 of its document's
     # actor) for the winner mirror's check
     deletes = case == 'deletes-and-incs'
-    assert [kills is not None for _, kills in batches] == \
+    assert [kills is not None for _, kills, _rows in batches] == \
         [False] * (len(calls) - 1) + [deletes]
     assert [len(p) for p in inc_preds] == [0] * (len(calls) - 1) + \
         [2 * deletes]
@@ -220,3 +234,60 @@ def test_the_turbo_batch_is_the_scatter_and_the_exact_path(case,
         assert [doc.get('x') for doc in got] == [None, None, 1]
         assert [doc['n'] for doc in got] == [10 * i + (i + 2) * (i != 1)
                                              for i in order]
+
+
+def with_a_string():
+    """counters_and_deletes with one more document, whose value is a
+    string: a flush over it takes the Python decode (_flush_mixed)."""
+    calls = counters_and_deletes()
+    calls[0].append([change(A, 1, 1, [set_op('s', 'text', datatype=None)])[0]])
+    calls[1].append([])
+    return calls
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+@pytest.mark.parametrize('sharded', [False, True], ids=['one-device',
+                                                        'mesh'])
+@pytest.mark.parametrize('path,make_calls', [
+    ('turbo', counters_and_deletes), ('flush', counters_and_deletes),
+    ('flush-mixed', with_a_string)])
+def test_every_grid_dispatch_takes_a_row_a_slot(path, make_calls, sharded):
+    """Each caller of the grid dispatch hands it the rows of the slots its
+    call touched (a row a slot, `rows` beside the batch, as many rows as
+    the power of two that holds them), and a sharded fleet's grid ends as
+    an unsharded one's: the batch is spread to its capacity there."""
+    import jax
+    from jax.sharding import Mesh
+    mesh = Mesh(np.array(jax.devices()[:4]), ('docs',)) if sharded else None
+    fleet = DocFleet(doc_capacity=8, key_capacity=8, mesh=mesh)
+    plain = DocFleet(doc_capacity=8, key_capacity=8)
+    calls = make_calls()
+    rows_seen = []
+    real_dispatch = fleet._dispatch_grid
+
+    def dispatch(batch, kills, rows):
+        rows_seen.append(rows)
+        return real_dispatch(batch, kills, rows)
+    fleet._dispatch_grid = dispatch
+    handles = fleet_backend.init_docs(len(calls[0]), fleet)
+    reference = fleet_backend.init_docs(len(calls[0]), plain)
+    for per_doc in calls:
+        handles, _ = fleet_backend.apply_changes_docs(
+            handles, per_doc, mirror=path != 'turbo')
+        reference, _ = fleet_backend.apply_changes_docs(reference, per_doc,
+                                                        mirror=False)
+        if path != 'turbo':
+            fleet.flush()
+        touched = [h['state']._impl.slot for h, p in zip(handles, per_doc)
+                   if p]
+        rows = rows_seen[-1]
+        assert len(rows) == 1 << (len(touched) - 1).bit_length()
+        assert sorted(rows[:len(touched)].tolist()) == sorted(touched)
+    assert fleet.metrics.turbo_calls == len(calls) * (path == 'turbo')
+    assert len(rows_seen) == len(calls)
+    for got, want in zip(fleet.state.tree_flatten()[0],
+                         plain.state.tree_flatten()[0]):
+        assert np.array_equal(np.asarray(got)[:fleet.n_slots],
+                              np.asarray(want)[:plain.n_slots])
+    assert fleet_backend.materialize_docs(handles) == \
+        fleet_backend.materialize_docs(reference)

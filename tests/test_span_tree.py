@@ -7,9 +7,10 @@ are off. Since ISSUE-39 `turbo_stage`, `turbo_dispatch` and `turbo_setup`
 are tiled too (`stage.*`, `dispatch.*`, `setup.*`, exactly: a sub-phase
 opens AT its parent's mark), `stage.root` and `stage.grid` once more
 (`root.*`, `grid.*`; `grid.columns` notes the document runs it lays out
-since ISSUE-40), and a call's root span carries `thread_cpu_ns`. All
-of it is held structurally: order, parentage, shared instants, no clock
-budget."""
+and the grid cells they fill), and a call's root span carries
+`thread_cpu_ns`. A bulk read is the root span `read_batch`, tiled by
+`read.*`. All of it is held structurally: order, parentage, shared
+instants, no clock budget."""
 
 import gc
 import threading
@@ -20,7 +21,7 @@ import pytest
 from automerge_tpu import native, observability
 from automerge_tpu.columnar import decode_change, encode_change
 from automerge_tpu.fleet.backend import (DocFleet, apply_changes_docs,
-                                         init_docs)
+                                         init_docs, materialize_docs)
 from automerge_tpu.observability import spans as obs_spans
 
 
@@ -453,9 +454,12 @@ def tiled_call(make_call, root_rows, seq_rows, fleet_kw):
         if not fleet_kw:
             # the grid's columns are laid out a document run at a time
             # (ISSUE-40): both documents have root rows, six each in
-            # `map_only`; in `both` the Text's make is one, the map's six
+            # `map_only`; in `both` the Text's make is one, the map's six.
+            # `cells` is rows times width: two rows of six, or of eight
+            # (the power of two) where the runs differ
             assert named['grid.columns'][0]['attrs'] == {
-                'runs': 2, 'ragged': 0 if make_call is map_only else 1}
+                'runs': 2, 'ragged': 0 if make_call is map_only else 1,
+                'cells': 12 if make_call is map_only else 16}
         assert named['turbo_dispatch'][0]['t0_ns'] == \
             named['turbo_stage'][0]['t1_ns']
     else:
@@ -533,6 +537,34 @@ def test_a_call_that_queues_everything_ends_with_its_commit():
     tiles(named, 'turbo_gate', GATE)
     tiles(named, 'turbo_commit', COMMIT)
     assert not {'turbo_stage', 'turbo_dispatch', 'stage.flush'} & set(named)
+    assert obs_spans._open_spans.stack == []
+
+
+READ = ['read.flush', 'read.gather', 'read.render', 'read.host']
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+@pytest.mark.parametrize('routed', [False, True])
+def test_read_phases_tile_a_bulk_read(routed):
+    """A `materialize_docs` call is the root span `read_batch`, tiled by
+    `read.flush` / `read.gather` / `read.render` / `read.host`: a slot
+    asked twice is gathered once, and a slot routed to the host mirror is
+    not gathered at all."""
+    fleet, handles, per_doc = map_only({})
+    handles, _ = apply_changes_docs(handles, per_doc, mirror=False)
+    if routed:
+        fleet.del_fallback.add(handles[1]['state']._impl.slot)
+    obs_spans.enable(capacity=64)
+    views = materialize_docs([handles[1], handles[0], handles[1]])
+    observability.disable()
+    named = by_name(observability.iter_spans())
+    tiles(named, 'read_batch', READ)
+    (batch,) = named['read_batch']
+    assert batch['parent'] is None and batch['attrs'] == {'docs': 3}
+    assert isinstance(batch['thread_cpu_ns'], int)
+    assert named['read.gather'][0]['attrs'] == {'rows': 1 if routed else 2}
+    assert named['read.host'][0]['attrs'] == {'docs': 2 if routed else 0}
+    assert views[0] == views[2] == handles[1]['state'].materialize()
     assert obs_spans._open_spans.stack == []
 
 
