@@ -329,16 +329,19 @@ class DocFleet:
         # Unlike grid_overflow this does NOT block the turbo apply path:
         # packing stays trustworthy, only reads fall back.
         self.del_fallback = set()
-        # Per-slot index of every map-key op row ever applied, as sorted
-        # int64 combos (key_id << 32) | packed — the turbo path's
-        # dangling-pred oracle (ref op_set.py: a pred must name a non-del
-        # row on its key; ref new.js rejects invalid op references during
-        # the merge). Fed by every ingest path; slots whose ops landed
-        # without indexing (bulk document loads) are marked incomplete
-        # and skip validation rather than risk a false reject — their
+        # Per-slot index of every map-key op row ever applied, as int64
+        # combos (key_id << 32) | packed — the turbo path's dangling-pred
+        # oracle (ref op_set.py: a pred must name a non-del row on its
+        # key; ref new.js rejects invalid op references during the
+        # merge). Fed by every ingest path, a flat batch at a time; the
+        # batches reach the native index (native.OpIndex, made at the
+        # first hand-over) in one call when it is next asked, copied or
+        # rebased. Without the codec nothing reads it (the turbo gate
+        # needs the codec), so it keeps no rows. Slots marked incomplete
+        # skip validation rather than risk a false reject — their
         # dangling preds surface at the next mirror rebuild as before.
         # ~8 bytes/op of host memory, vs the ~60+ bytes/op change log.
-        self._op_index = {}            # slot -> sorted np.int64 combos
+        self._op_index = None          # native.OpIndex
         self._op_index_pending = []    # [(slots, combos)] flat batches
         self._op_index_incomplete = set()
         # Set rows fold into host_winners lazily: inc-free batches (the
@@ -480,11 +483,10 @@ class DocFleet:
         if self.host_winners is not None:
             # host-RAM mirror for counter-attribution checks (not device)
             out['host_winner_mirror'] = int(self.host_winners.nbytes)
-        if self._op_index or self._op_index_pending:
+        op_index = self._index_nbytes()
+        if op_index:
             # host-RAM dangling-pred oracle: 8 bytes per applied op row
-            out['op_index'] = int(
-                sum(a.nbytes for a in self._op_index.values()) +
-                sum(p[1].nbytes for p in self._op_index_pending))
+            out['op_index'] = op_index
         if self.reg_state is not None:
             out['registers'] = nbytes(self.reg_state.tree_flatten()[0])
         pools = {}
@@ -577,11 +579,12 @@ class DocFleet:
                 # stale: numpy None-indexing broadcasts, so a setter
                 # would overwrite whole columns.
                 eng.slot = 'freed'
+        if self._op_index is not None:
+            self._op_index.drop(slots)
         for slot in slots:
             self.ctr_base.pop(slot, None)
             self.grid_overflow.discard(slot)
             self.del_fallback.discard(slot)
-            self._op_index.pop(slot, None)
             self._op_index_incomplete.discard(slot)
             rows = self.slot_seq.pop(slot, {})
             if rows:
@@ -631,9 +634,8 @@ class DocFleet:
         if src in self._op_index_incomplete:
             self._op_index_incomplete.add(dst)
         self._index_consolidate()
-        src_idx = self._op_index.get(src)
-        if src_idx is not None:
-            self._op_index[dst] = src_idx.copy()
+        if self._op_index is not None:
+            self._op_index.copy(src, dst)
         copies = {}    # cls -> ([src idx], [dst idx])
         for oid, row in list(self.slot_seq.get(src, {}).items()):
             info = self.seq_rows[row]
@@ -1339,61 +1341,47 @@ class DocFleet:
             (np.asarray(slots, dtype=np.int64), combos))
 
     def _index_consolidate(self):
-        """Drain the flat pending batches into per-slot sorted arrays."""
+        """Hand the flat pending batches to the native index in one call
+        (without the codec, drop them: nothing reads the index)."""
         if not self._op_index_pending:
             return
-        slots = np.concatenate([p[0] for p in self._op_index_pending])
-        combos = np.concatenate([p[1] for p in self._op_index_pending])
-        self._op_index_pending = []
-        order = np.argsort(slots, kind='stable')
-        ss = slots[order]
-        cs = combos[order]
-        bounds = np.flatnonzero(np.r_[True, ss[1:] != ss[:-1]])
-        ends = np.r_[bounds[1:], len(ss)]
-        for b, e in zip(bounds, ends):
-            slot = int(ss[b])
-            old = self._op_index.get(slot)
-            if old is None:
-                self._op_index[slot] = np.sort(cs[b:e])
-            else:
-                self._op_index[slot] = np.sort(
-                    np.concatenate([old, cs[b:e]]))
+        pending, self._op_index_pending = self._op_index_pending, []
+        if self._op_index is None:
+            self._op_index = native.op_index()
+            if self._op_index is None:
+                return
+        self._op_index.add(np.concatenate([p[0] for p in pending]),
+                           np.concatenate([p[1] for p in pending]))
 
-    def _index_lookup(self, slot, combos):
-        """Membership of (key << 32 | packed) combos in the slot's
-        applied-op index (consolidates the pending backlog first)."""
+    def _index_lookup(self, slots, combos):
+        """Per i, whether the combo (key << 32 | packed) ``combos[i]`` is
+        in slot ``slots[i]``'s applied-op index: one native call for the
+        whole batch (consolidates the pending backlog first)."""
         self._index_consolidate()
-        arr = self._op_index.get(slot)
-        if arr is None or not len(arr):
+        if self._op_index is None:
             return np.zeros(len(combos), dtype=bool)
-        pos = np.searchsorted(arr, combos)
-        pos = np.clip(pos, 0, len(arr) - 1)
-        return arr[pos] == combos
+        return self._op_index.contains(slots, combos)
 
     def _index_remap_actors(self, perm_full):
         """Renumber the actor bits of every indexed packed opId (actor
-        table re-sort) — consolidated arrays and pending batches alike."""
+        table re-sort) — the native index and pending batches alike."""
         mask = np.int64(MAX_ACTORS - 1)
         perm64 = perm_full.astype(np.int64)
-
-        def remap(arr):
-            return (arr & ~mask) | perm64[arr & mask]
-
-        for slot, arr in self._op_index.items():
-            self._op_index[slot] = np.sort(remap(arr))
-        self._op_index_pending = [(s, remap(c))
+        if self._op_index is not None:
+            self._op_index.remap(perm64)
+        self._op_index_pending = [(s, (c & ~mask) | perm64[c & mask])
                                   for s, c in self._op_index_pending]
 
     def _index_rebase(self, slot, delta_packed):
         """Shift a slot's indexed packed ids down by a counter rebase."""
         self._index_consolidate()
-        arr = self._op_index.get(slot)
-        if arr is None or not len(arr):
-            return
-        low = arr & 0xffffffff
-        shifted = np.maximum(low - delta_packed, 0)
-        self._op_index[slot] = np.sort(
-            (arr & ~np.int64(0xffffffff)) | shifted)
+        if self._op_index is not None:
+            self._op_index.rebase(slot, delta_packed)
+
+    def _index_nbytes(self):
+        """Host bytes of the applied-op index: 8 a row, pending or not."""
+        return int((self._op_index.nbytes if self._op_index is not None
+                    else 0) + sum(p[1].nbytes for p in self._op_index_pending))
 
     @_spanned('dispatch_grid')
     def _dispatch_grid(self, batch, kills, rows):
@@ -3414,9 +3402,7 @@ def host_memory_stats(handles):
     if fleet is not None:
         if fleet.host_winners is not None:
             out['host_winner_mirror_bytes'] = int(fleet.host_winners.nbytes)
-        out['op_index_bytes'] = int(
-            sum(a.nbytes for a in fleet._op_index.values()) +
-            sum(p[1].nbytes for p in fleet._op_index_pending))
+        out['op_index_bytes'] = fleet._index_nbytes()
         out['value_table_entries'] = len(fleet.value_table)
     return out
 
@@ -4642,12 +4628,15 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
     # (op_set.py `no matching operation for pred`; the reference rejects
     # invalid op references during the merge, new.js:1219-1220). Sequence
     # refs/preds keep their existing envelope (unknown targets drop and
-    # flag inexact; the mirror serves). Bulk-loaded docs' indexes are
-    # incomplete, so their rows skip the check rather than false-reject —
-    # for them a dangling pred still surfaces at the next mirror rebuild.
-    _validate_turbo_preds(fleet, engines, rows, keep, seq_sel, seq_make_sel,
-                          change_doc, nat_keys, nat_actors, _MA,
-                          restore_all)
+    # flag inexact; the mirror serves). Rows of slots whose index is
+    # marked incomplete skip the check rather than false-reject — for
+    # them a dangling pred still surfaces at the next mirror rebuild.
+    slot_of_doc = np.array([e.slot for e in engines], dtype=np.int64)
+    standing = _validate_turbo_preds(
+        fleet, slot_of_doc, rows, keep, seq_sel, seq_make_sel, change_doc,
+        nat_keys, nat_actors, _MA, restore_all)
+    fleet.metrics.standing_preds += standing
+    sub.note(standing_preds=standing)
 
     # Count only causally-applied changes: queued ones are re-counted when
     # the exact path drains and flushes them later. Byte counts come from
@@ -4897,7 +4886,6 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
     # of silently renumbering to actor 0
     actor_map = np.array([fleet.actors.index.get(a, -1) for a in nat_actors],
                          dtype=np.int32) if nat_actors else np.zeros(1, np.int32)
-    slot_of_doc = np.array([e.slot for e in engines], dtype=np.int64)
 
     keep_root = keep & ~seq_sel & ~seq_make_sel
     keep_seq = keep & (seq_sel | seq_make_sel)
@@ -5309,7 +5297,7 @@ def _op_in_queued_object(rows, keep, queued, change_doc):
                                             rows['obj'][inside].tolist()))
 
 
-def _validate_turbo_preds(fleet, engines, rows, keep, seq_sel, seq_make_sel,
+def _validate_turbo_preds(fleet, slot_arr, rows, keep, seq_sel, seq_make_sel,
                           change_doc, nat_keys, nat_actors, _MA,
                           restore_all):
     """Reject kept map-key rows whose preds name no existing op row —
@@ -5317,26 +5305,24 @@ def _validate_turbo_preds(fleet, engines, rows, keep, seq_sel, seq_make_sel,
     exists iff it is (a) an earlier kept non-del map-key row of the same
     (doc, object, key) in THIS batch (ops arrive causally, so a valid
     pred's packed id is strictly below its op's), or (b) in the slot's
-    standing applied-op index. Raises ValueError (after restore_all)
-    with the exact path's message on the first dangling pred. The fast
-    path — no preds, or every pred resolved batch-internally — is fully
-    vectorized; only genuinely-missing candidates take the per-pred
-    standing-index walk (they either resolve via the index or raise)."""
+    standing applied-op index. Raises DanglingPred (after restore_all)
+    with the exact path's message on the first dangling pred, in pred
+    order. Vectorized throughout: the preds the batch does not resolve
+    are asked of the standing index in one native call. Returns how
+    many preds were asked of it. `slot_arr` is each document's slot."""
     pc = np.diff(rows['pred_off'])
     root_rows = keep & ~seq_sel & ~seq_make_sel
     check_rows = root_rows & (pc > 0)
     if not check_rows.any():
-        return
+        return 0
     row_doc = change_doc[rows['doc']]
-    slot_arr = np.fromiter((e.slot for e in engines), dtype=np.int64,
-                           count=len(engines))
     if fleet._op_index_incomplete:
-        inc = np.fromiter(
-            (s in fleet._op_index_incomplete for s in slot_arr),
-            dtype=bool, count=len(slot_arr))
+        inc = np.isin(slot_arr, np.fromiter(
+            fleet._op_index_incomplete, dtype=np.int64,
+            count=len(fleet._op_index_incomplete)))
         check_rows &= ~inc[row_doc]
         if not check_rows.any():
-            return
+            return 0
     # Batch-internal pred targets: kept, non-seq, non-del rows (dels have
     # no rows in the reference representation; incs and makes do). Dense
     # collision-free ids for (doc, obj, key) triples — restricted to the
@@ -5366,7 +5352,7 @@ def _validate_turbo_preds(fleet, engines, rows, keep, seq_sel, seq_make_sel,
             (pred_nat < rows['packed'][owner])
     missing = (pred_nat > 0) & ~in_batch
     if not missing.any():
-        return
+        return 0
     # Lazily-pending earlier changes haven't fed the index yet: land
     # them before consulting it (they were already accepted — flushing
     # here mutates only fleet device state, never the engines' causal
@@ -5374,41 +5360,38 @@ def _validate_turbo_preds(fleet, engines, rows, keep, seq_sel, seq_make_sel,
     if fleet.pending:
         fleet.flush()
     # Standing-index check for the remainder, in fleet numbering (reads
-    # only — unknown actors/keys simply have no standing ops)
+    # only — unknown actors/keys simply have no standing ops): the actor
+    # by one fancy index, the fleet key once per distinct (obj, key), the
+    # index once for every pred whose actor and key the fleet knows
     amap = np.array([fleet.actors.index.get(a, -1) for a in nat_actors],
                     dtype=np.int64) if nat_actors else np.zeros(1, np.int64)
-
-    def raise_dangling(p, d):
+    miss = np.flatnonzero(missing)
+    p_miss = pred_nat[miss]
+    row_miss = owner[miss]
+    pa = amap[p_miss & (_MA - 1)]
+    okid = np.zeros(len(row_doc), dtype=np.int64)
+    okid[rel] = ok_inv
+    ok_used, ok_of = np.unique(okid[row_miss], return_inverse=True)
+    fk_used = np.empty(len(ok_used), dtype=np.int64)
+    for j, objkey in enumerate(_u1[ok_used].tolist()):
+        o, ks = objkey >> 32, nat_keys[objkey & 0xffffffff]
+        if o:
+            ks = (f'{o >> 8}@{nat_actors[o & (_MA - 1)]}', ks)
+        fk_used[j] = fleet.keys.index.get(ks, -1)
+    fk = fk_used[ok_of]
+    known = np.flatnonzero((pa >= 0) & (fk >= 0))
+    found = np.zeros(len(miss), dtype=bool)
+    found[known] = fleet._index_lookup(
+        slot_arr[row_doc[row_miss[known]]],
+        (fk[known] << 32) | (p_miss[known] >> 8 << 8) | pa[known])
+    if not found.all():
+        i = int(np.argmin(found))
+        p = int(p_miss[i])
         restore_all()
         pred = f'{p >> 8}@{nat_actors[p & (_MA - 1)]}'
         raise DanglingPred(f'no matching operation for pred: {pred}',
-                           doc_index=d)
-
-    key_cache = {}
-    for i in np.flatnonzero(missing):
-        p = int(pred_nat[i])
-        d = int(row_doc[owner[i]])
-        pa = int(amap[p & (_MA - 1)])
-        if pa < 0:
-            raise_dangling(p, d)
-        o = int(rows['obj'][owner[i]])
-        kn = int(rows['key'][owner[i]])
-        fk = key_cache.get((o, kn), -2)
-        if fk == -2:
-            ks = nat_keys[kn]
-            if o == 0:
-                fk = fleet.keys.index.get(ks)
-            else:
-                oid = f'{o >> 8}@{nat_actors[o & (_MA - 1)]}'
-                fk = fleet.keys.index.get((oid, ks))
-            key_cache[(o, kn)] = fk
-        if fk is None:
-            raise_dangling(p, d)
-        pf = (p >> 8 << 8) | pa
-        slot = int(slot_arr[d])
-        if not bool(fleet._index_lookup(
-                slot, np.array([(fk << 32) | pf], dtype=np.int64))[0]):
-            raise_dangling(p, d)
+                           doc_index=int(row_doc[row_miss[i]]))
+    return len(known)
 
 
 def _max_pred_per_inc(pred_col, offs, counts, actor_map):

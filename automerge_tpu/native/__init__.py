@@ -26,9 +26,11 @@ to its fixed point, change by change, for the documents the other two
 refuse — changes out of order, held back, delivered again — asking a
 document's history of a byte index the engine keeps,
 ``fleet.hashindex.HistoryIndex``). These three have no Python form: the
-turbo path returns None without the codec.
+turbo path returns None without the codec. Nor has the gate's
+dangling-pred oracle, ``OpIndex`` (am_opindex_*: per document slot, the
+map-key op rows applied), which only the turbo path reads.
 
-A compiled binary carries an ABI stamp (``am_abi_version``, now 5); a
+A compiled binary carries an ABI stamp (``am_abi_version``, now 6); a
 stale .so that cannot be rebuilt fails loudly at import instead of
 silently running an old single-threaded codec (see tools/build_native.sh).
 """
@@ -51,7 +53,7 @@ from ..observability.spans import span as _span
 # Bumped in lockstep with codec.cpp's am_abi_version whenever the C
 # surface changes shape. A mismatch means the cached .so predates this
 # wrapper (or vice versa) and MUST NOT be used.
-_ABI_VERSION = 5
+_ABI_VERSION = 6
 
 
 class NativeAbiMismatch(RuntimeError):
@@ -1040,6 +1042,109 @@ def general_gate(doc_off, actor, seq, hash32, deps_off, deps_blob, head32,
               for d in np.flatnonzero(state[:n_docs] == 3).tolist()]
     return (applied, app_off, left, left_off, nh, nh_off, g_seq, errors,
             int(probes.sum()))
+
+
+class OpIndex:
+    """The turbo path's dangling-pred oracle (codec.cpp am_opindex_*): per
+    document slot, the int64 combos ``(key_id << 32) | packed`` of every
+    map-key op row the fleet applied, in native memory. Rows are added a
+    batch at a time and asked a batch at a time, each batch one call with
+    the GIL released; a slot's rows are sorted lazily, when a check first
+    reads them after an out-of-order append. Needs the codec: build one
+    with `op_index`."""
+
+    __slots__ = ('_lib', '_h', 'rows')
+
+    def __init__(self, lib):
+        self._lib = lib
+        self._h = ctypes.c_void_p(lib.am_opindex_new())
+        self.rows = 0         # combos held, duplicates included
+
+    def __del__(self):
+        h, self._h = getattr(self, '_h', None), None
+        if h:
+            self._lib.am_opindex_free(h)
+
+    @property
+    def nbytes(self):
+        """Eight bytes a combo held."""
+        return 8 * self.rows
+
+    def _done(self, rows, what):
+        if rows < 0:
+            raise ValueError(f'OpIndex.{what}: a slot or table out of range')
+        self.rows = int(rows)
+
+    def add(self, slots, combos):
+        """Append the rows ``(slots[i], combos[i])``."""
+        slots = np.ascontiguousarray(slots, dtype=np.int64)
+        combos = np.ascontiguousarray(combos, dtype=np.int64)
+        if len(slots) != len(combos):
+            raise ValueError('OpIndex.add: slots and combos differ in length')
+        self._done(self._lib.am_opindex_add(
+            self._h, slots.ctypes.data, combos.ctypes.data, len(slots)),
+            'add')
+
+    def contains(self, slots, combos):
+        """Per i, whether ``combos[i]`` is among ``slots[i]``'s rows."""
+        slots = np.ascontiguousarray(slots, dtype=np.int64)
+        combos = np.ascontiguousarray(combos, dtype=np.int64)
+        if len(slots) != len(combos):
+            raise ValueError('OpIndex.contains: slots and combos differ in '
+                             'length')
+        found = np.zeros(len(slots), dtype=np.uint8)
+        self._lib.am_opindex_check(self._h, slots.ctypes.data,
+                                   combos.ctypes.data, len(slots),
+                                   found.ctypes.data)
+        return found.view(bool)
+
+    def drop(self, slots):
+        """Forget the rows of every slot in ``slots``."""
+        slots = np.ascontiguousarray(slots, dtype=np.int64)
+        self._done(self._lib.am_opindex_drop(self._h, slots.ctypes.data,
+                                             len(slots)), 'drop')
+
+    def copy(self, src, dst):
+        """``dst``'s rows become a copy of ``src``'s."""
+        self._done(self._lib.am_opindex_copy(self._h, src, dst), 'copy')
+
+    def rebase(self, slot, delta):
+        """Move ``slot``'s packed opIds (a combo's low 32 bits) down by
+        ``delta``, stopping at 0: a counter rebase of the slot."""
+        self._done(self._lib.am_opindex_rebase(self._h, slot, delta),
+                   'rebase')
+
+    def remap(self, perm):
+        """Renumber every combo's actor bits (its low ``len(perm) - 1``
+        bits, ``len(perm)`` a power of two) to ``perm[bits]``."""
+        perm = np.ascontiguousarray(perm, dtype=np.int64)
+        self._done(self._lib.am_opindex_remap(self._h, perm.ctypes.data,
+                                              len(perm)), 'remap')
+
+
+def op_index():
+    """A new, empty `OpIndex`; None when the codec is unavailable."""
+    lib = _load()
+    if lib is None:
+        return None
+    if not hasattr(lib, '_opindex_ready'):
+        i64, ptr = ctypes.c_int64, ctypes.c_void_p
+        lib.am_opindex_new.argtypes = []
+        lib.am_opindex_new.restype = ptr
+        lib.am_opindex_free.argtypes = [ptr]
+        lib.am_opindex_free.restype = None
+        lib.am_opindex_add.argtypes = [ptr, ptr, ptr, i64]
+        lib.am_opindex_check.argtypes = [ptr, ptr, ptr, i64, ptr]
+        lib.am_opindex_drop.argtypes = [ptr, ptr, i64]
+        lib.am_opindex_copy.argtypes = [ptr, i64, i64]
+        lib.am_opindex_rebase.argtypes = [ptr, i64, i64]
+        lib.am_opindex_remap.argtypes = [ptr, ptr, i64]
+        for fn in (lib.am_opindex_add, lib.am_opindex_check,
+                   lib.am_opindex_drop, lib.am_opindex_copy,
+                   lib.am_opindex_rebase, lib.am_opindex_remap):
+            fn.restype = i64
+        lib._opindex_ready = True
+    return OpIndex(lib)
 
 
 def parse_documents(buffers):

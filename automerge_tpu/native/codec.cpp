@@ -1746,7 +1746,7 @@ int64_t am_ingest_changes_list(PyObject *buffers, int with_meta,
 // Monotone ABI stamp, bumped on any C-surface change. The Python wrapper
 // refuses to run against a binary whose stamp mismatches (a stale .so
 // would otherwise silently run the old single-threaded codec).
-int64_t am_abi_version() { return 5; }
+int64_t am_abi_version() { return 6; }
 
 int64_t am_pool_configure(int n) { return NativePool::inst().configure(n); }
 
@@ -5007,6 +5007,164 @@ int64_t am_extract_fetch(uint8_t *ok, int64_t *d_off, int64_t *c_off,
   delete g_extract;
   g_extract = nullptr;
   return ci;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// Applied-op index: the turbo path's dangling-pred oracle
+// ---------------------------------------------------------------------------
+//
+// Per document slot, every map-key op row the fleet applied, as int64
+// combos (key_id << 32) | packed opId. A slot's combos are appended as
+// they come and sorted the first time a check reads the slot after an
+// out-of-order append (rows mostly arrive in counter order, so most slots
+// never need it). Every entry takes the index's own lock: the calls
+// release the GIL, and a check sorts in place.
+
+namespace {
+
+struct OpIndex {
+  std::mutex m;
+  std::vector<std::vector<int64_t>> slots;
+  std::vector<uint8_t> unsorted;    // per slot: appended out of order
+  int64_t rows = 0;                 // combos held, duplicates included
+
+  void reach(int64_t slot) {
+    if (slot >= int64_t(slots.size())) {
+      slots.resize(size_t(slot) + 1);
+      unsorted.resize(size_t(slot) + 1, 0);
+    }
+  }
+  void put(int64_t slot, int64_t combo) {
+    std::vector<int64_t> &v = slots[size_t(slot)];
+    if (!v.empty() && combo < v.back()) unsorted[size_t(slot)] = 1;
+    v.push_back(combo);
+  }
+  const std::vector<int64_t> &sorted(int64_t slot) {
+    std::vector<int64_t> &v = slots[size_t(slot)];
+    if (unsorted[size_t(slot)]) {
+      std::sort(v.begin(), v.end());
+      unsorted[size_t(slot)] = 0;
+    }
+    return v;
+  }
+};
+
+}  // namespace
+
+extern "C" {
+
+void *am_opindex_new() { return new OpIndex(); }
+
+void am_opindex_free(void *h) { delete static_cast<OpIndex *>(h); }
+
+// Append n (slot, combo) rows. Returns the combos the index then holds,
+// or -1 (nothing added) when a slot is negative.
+int64_t am_opindex_add(void *h, const int64_t *slot, const int64_t *combo,
+                       int64_t n) {
+  OpIndex &ix = *static_cast<OpIndex *>(h);
+  std::lock_guard<std::mutex> lk(ix.m);
+  int64_t top = -1;
+  for (int64_t i = 0; i < n; i++) {
+    if (slot[i] < 0) return -1;
+    top = std::max(top, slot[i]);
+  }
+  if (top < 0) return ix.rows;
+  ix.reach(top);
+  if (n >= int64_t(ix.slots.size())) {
+    // a load-sized batch: one counting pass, then each slot grows once,
+    // with half again as much room, so that the next few rows a slot
+    // takes (an update a record) append without a reallocation
+    std::vector<uint32_t> count(ix.slots.size(), 0);
+    for (int64_t i = 0; i < n; i++) count[size_t(slot[i])]++;
+    for (size_t s = 0; s < count.size(); s++)
+      if (count[s]) {
+        size_t want = ix.slots[s].size() + count[s];
+        ix.slots[s].reserve(want + want / 2);
+      }
+  }
+  for (int64_t i = 0; i < n; i++) ix.put(slot[i], combo[i]);
+  ix.rows += n;
+  return ix.rows;
+}
+
+// found[i] = 1 where combo[i] is in slot[i]'s index, else 0. Returns how
+// many were found.
+int64_t am_opindex_check(void *h, const int64_t *slot, const int64_t *combo,
+                         int64_t n, uint8_t *found) {
+  OpIndex &ix = *static_cast<OpIndex *>(h);
+  std::lock_guard<std::mutex> lk(ix.m);
+  int64_t hits = 0;
+  for (int64_t i = 0; i < n; i++) {
+    uint8_t hit = 0;
+    if (slot[i] >= 0 && slot[i] < int64_t(ix.slots.size())) {
+      const std::vector<int64_t> &v = ix.sorted(slot[i]);
+      hit = std::binary_search(v.begin(), v.end(), combo[i]) ? 1 : 0;
+    }
+    found[i] = hit;
+    hits += hit;
+  }
+  return hits;
+}
+
+// Forget the given slots' rows and release their memory. Returns the
+// combos the index then holds.
+int64_t am_opindex_drop(void *h, const int64_t *slot, int64_t n) {
+  OpIndex &ix = *static_cast<OpIndex *>(h);
+  std::lock_guard<std::mutex> lk(ix.m);
+  for (int64_t i = 0; i < n; i++) {
+    if (slot[i] < 0 || slot[i] >= int64_t(ix.slots.size())) continue;
+    std::vector<int64_t> &v = ix.slots[size_t(slot[i])];
+    ix.rows -= int64_t(v.size());
+    std::vector<int64_t>().swap(v);
+    ix.unsorted[size_t(slot[i])] = 0;
+  }
+  return ix.rows;
+}
+
+// dst's rows become a copy of src's (none, where src has none). Returns
+// the combos the index then holds, or -1 for a negative slot.
+int64_t am_opindex_copy(void *h, int64_t src, int64_t dst) {
+  OpIndex &ix = *static_cast<OpIndex *>(h);
+  std::lock_guard<std::mutex> lk(ix.m);
+  if (src < 0 || dst < 0) return -1;
+  ix.reach(std::max(src, dst));
+  ix.rows += int64_t(ix.slots[size_t(src)].size()) -
+             int64_t(ix.slots[size_t(dst)].size());
+  ix.slots[size_t(dst)] = ix.slots[size_t(src)];
+  ix.unsorted[size_t(dst)] = ix.unsorted[size_t(src)];
+  return ix.rows;
+}
+
+// A counter rebase of one slot: each combo's low 32 bits (the packed
+// opId) move down by delta, stopping at 0. The shift is monotone, so a
+// sorted slot stays sorted. Returns the combos the index holds.
+int64_t am_opindex_rebase(void *h, int64_t slot, int64_t delta) {
+  OpIndex &ix = *static_cast<OpIndex *>(h);
+  std::lock_guard<std::mutex> lk(ix.m);
+  if (slot < 0 || slot >= int64_t(ix.slots.size())) return ix.rows;
+  const int64_t low = 0xffffffffLL;
+  for (int64_t &c : ix.slots[size_t(slot)])
+    c = (c & ~low) | std::max<int64_t>((c & low) - delta, 0);
+  return ix.rows;
+}
+
+// An actor-table re-sort: every combo's actor bits (its low bits under
+// mask = n_perm - 1, n_perm a power of two) become perm[bits]. Returns
+// the combos the index holds, or -1 when n_perm is no power of two.
+int64_t am_opindex_remap(void *h, const int64_t *perm, int64_t n_perm) {
+  OpIndex &ix = *static_cast<OpIndex *>(h);
+  std::lock_guard<std::mutex> lk(ix.m);
+  if (n_perm <= 0 || (n_perm & (n_perm - 1))) return -1;
+  const int64_t mask = n_perm - 1;
+  for (size_t s = 0; s < ix.slots.size(); s++) {
+    std::vector<int64_t> &v = ix.slots[s];
+    if (v.empty()) continue;
+    for (int64_t &c : v) c = (c & ~mask) | perm[c & mask];
+    ix.unsorted[s] = 1;
+  }
+  return ix.rows;
 }
 
 }  // extern "C"

@@ -16,7 +16,8 @@ phase, and what happened around it. Four layers, one package:
   `heldback_changes` / `drained_changes` / `heldback_docs`, the changes
   a turbo call queued, applied out of a queue, and the documents it left
   with one; `history_probes`, what the native general gate asked of
-  documents' history indexes),
+  documents' history indexes; `standing_preds`, the map-key preds the
+  turbo gate asked of the applied-op index),
   `register_dispatch_source`/`dispatch_counts` and
   `register_health_source`/`health_counts` system-wide roll-ups, and
   `trace`, the operator's one entry to a profiler capture: it turns the

@@ -123,6 +123,9 @@ class Metrics:
                                  # indexes: dependencies neither the run
                                  # nor the heads meet, own hashes of
                                  # changes their actor's clock has reached
+        'standing_preds',        # preds the turbo gate asked of the
+                                 # applied-op index: map-key preds the
+                                 # call's own rows do not resolve
         # the sequence engine (fleet/backend.py _dispatch_seq)
         'seq_ops',               # real sequence ops dispatched
         'seq_op_cells',          # rows x width of the op columns handed to

@@ -13,6 +13,7 @@ import pytest
 
 import automerge_tpu as am
 from automerge_tpu import backend as host_backend
+from automerge_tpu import native
 from automerge_tpu.columnar import encode_change
 from automerge_tpu.fleet import backend as fleet_backend
 from automerge_tpu.fleet.backend import DocFleet, FleetBackend, FleetDoc
@@ -2713,6 +2714,257 @@ class TestTurboDanglingPreds:
         # state unchanged, handle still live
         assert handles[0]['state'].heads == heads
         assert fleet_backend.materialize_docs(handles) == [{'k': 1}]
+
+    # -- the standing applied-op index, asked a batch at a time ----------
+
+    def _standing(self, n_docs, actor=ACTORS[0]):
+        """n_docs documents whose key 'k' was set (op 1@actor, value d)
+        by an earlier turbo call: a pred of 1@actor on 'k' resolves only
+        from the standing index."""
+        fleet = DocFleet(doc_capacity=n_docs + 2, key_capacity=8)
+        handles = fleet_backend.init_docs(n_docs, fleet)
+        per_doc = [[change_buf(actor, 1, 1, [
+            {'action': 'set', 'obj': '_root', 'key': 'k', 'value': d,
+             'datatype': 'int', 'pred': []}])] for d in range(n_docs)]
+        handles, _ = fleet_backend.apply_changes_docs(handles, per_doc,
+                                                      mirror=False)
+        return fleet, handles
+
+    @staticmethod
+    def _over(handle, value, pred, key='k', actor=ACTORS[0], seq=2,
+              start=2):
+        """The handle's next change, by ACTORS[0] (op start@): sets `key`
+        to `value` over the ops of `actor` that `pred` counts."""
+        return change_buf(ACTORS[0], seq, start, [
+            {'action': 'set', 'obj': '_root', 'key': key, 'value': value,
+             'datatype': 'int', 'pred': [f'{p}@{actor}' for p in pred]}],
+            deps=handle['heads'])
+
+    def _refused(self, handles, per_doc, pred, doc_index):
+        """The call raises the exact path's DanglingPred for `pred` in
+        document `doc_index`, and every document is as it was."""
+        from automerge_tpu.errors import DanglingPred
+        heads = [h['state'].heads for h in handles]
+        before = fleet_backend.materialize_docs(handles)
+        with pytest.raises(DanglingPred) as err:
+            fleet_backend.apply_changes_docs(handles, per_doc, mirror=False)
+        assert str(err.value) == f'no matching operation for pred: {pred}'
+        assert err.value.doc_index == doc_index
+        assert [h['state'].heads for h in handles] == heads
+        assert fleet_backend.materialize_docs(handles) == before
+
+    def _oracle_many_slots(self):
+        fleet, handles = self._standing(48)
+        asked = []
+        lookup = fleet._index_lookup
+        fleet._index_lookup = lambda s, c: asked.append(len(s)) or \
+            lookup(s, c)
+        per_doc = [[self._over(h, 100 + d, [1])]
+                   for d, h in enumerate(handles)]
+        handles, _ = fleet_backend.apply_changes_docs(handles, per_doc,
+                                                      mirror=False)
+        assert asked == [48]               # one lookup for the whole call
+        assert fleet.metrics.standing_preds == 48
+        assert fleet_backend.materialize_docs(handles) == \
+            [{'k': 100 + d} for d in range(48)]
+
+    def _oracle_dangling_mid_batch(self):
+        fleet, handles = self._standing(5)
+        preds = {2: 7, 4: 8}               # the first in pred order wins
+        per_doc = [[self._over(h, 100 + d, [preds.get(d, 1)])]
+                   for d, h in enumerate(handles)]
+        self._refused(handles, per_doc, f'7@{ACTORS[0]}', 2)
+        # the same call less its dangling preds applies
+        per_doc = [[self._over(h, 100 + d, [1])]
+                   for d, h in enumerate(handles)]
+        handles, _ = fleet_backend.apply_changes_docs(handles, per_doc,
+                                                      mirror=False)
+        assert fleet_backend.materialize_docs(handles) == \
+            [{'k': 100 + d} for d in range(5)]
+
+    def _oracle_unknown_actor(self):
+        fleet, handles = self._standing(3)
+        assert ACTORS[3] not in fleet.actors.index
+        per_doc = [[self._over(h, 100 + d, [1],
+                               actor=ACTORS[3] if d == 1 else ACTORS[0])]
+                   for d, h in enumerate(handles)]
+        self._refused(handles, per_doc, f'1@{ACTORS[3]}', 1)
+
+    def _oracle_unknown_key(self):
+        fleet, handles = self._standing(3)
+        assert 'fresh' not in fleet.keys.index
+        per_doc = [[self._over(h, 100 + d, [1],
+                               key='fresh' if d == 2 else 'k')]
+                   for d, h in enumerate(handles)]
+        self._refused(handles, per_doc, f'1@{ACTORS[0]}', 2)
+
+    def _oracle_incomplete_slot(self):
+        fleet, handles = self._standing(2)
+        fleet._op_index_incomplete.add(handles[0]['state']._impl.slot)
+        per_doc = [[self._over(handles[0], 100, [9])],     # not checked
+                   [self._over(handles[1], 101, [1])]]
+        handles, _ = fleet_backend.apply_changes_docs(handles, per_doc,
+                                                      mirror=False)
+        assert fleet.metrics.standing_preds == 1
+        assert fleet_backend.materialize_docs(handles) == \
+            [{'k': 100}, {'k': 101}]
+
+    def _oracle_after_clone(self):
+        fleet, handles = self._standing(2)
+        twin = fleet_backend.clone(handles[1])
+        self._refused([twin], [[self._over(twin, 5, [9])]],
+                      f'9@{ACTORS[0]}', 0)
+        (twin,), _ = fleet_backend.apply_changes_docs(
+            [twin], [[self._over(twin, 5, [1])]], mirror=False)
+        assert fleet_backend.materialize_docs([handles[1], twin]) == \
+            [{'k': 1}, {'k': 5}]
+
+    def _oracle_after_free(self):
+        fleet, handles = self._standing(2)
+        slot = handles[0]['state']._impl.slot
+        fleet_backend.free(handles[0])
+        (fresh,) = fleet_backend.init_docs(1, fleet)
+        assert fresh['state']._impl.slot == slot   # the slot is recycled
+        # its last tenant's 1@A on 'k' is gone with it
+        first = change_buf(ACTORS[0], 1, 2, [
+            {'action': 'set', 'obj': '_root', 'key': 'k', 'value': 7,
+             'datatype': 'int', 'pred': [f'1@{ACTORS[0]}']}])
+        self._refused([fresh], [[first]], f'1@{ACTORS[0]}', 0)
+        first = change_buf(ACTORS[0], 1, 1, [
+            {'action': 'set', 'obj': '_root', 'key': 'k', 'value': 7,
+             'datatype': 'int', 'pred': []}])
+        (fresh,), _ = fleet_backend.apply_changes_docs([fresh], [[first]],
+                                                       mirror=False)
+        (fresh,), _ = fleet_backend.apply_changes_docs(
+            [fresh], [[self._over(fresh, 8, [1])]], mirror=False)
+        assert fleet_backend.materialize_docs([fresh, handles[1]]) == \
+            [{'k': 8}, {'k': 1}]
+
+    def _oracle_after_rebase(self):
+        # A rebased slot's calls take the exact path, so the turbo gate
+        # never asks for it; the index still moves with the slot's window:
+        # the standing op is found at its rebased packed id, not its old
+        from automerge_tpu.fleet.tensor_doc import ACTOR_BITS, CTR_LIMIT
+        fleet = DocFleet(doc_capacity=2, key_capacity=4)
+        gb = FleetBackend(fleet).init()
+        A, step, heads, pred = ACTORS[0], CTR_LIMIT - 100, [], []
+        for seq, start in enumerate([1, step, 2 * step], 1):
+            buf = change_buf(A, seq, start, [
+                {'action': 'set', 'obj': '_root', 'key': 'k', 'value': seq,
+                 'datatype': 'int', 'pred': pred}], deps=heads)
+            heads = [am.decode_change(buf)['hash']]
+            pred = [f'{start}@{A}']
+            gb, _ = fleet_backend.apply_changes(gb, [buf])
+            fleet.flush()
+        slot = gb['state']._impl.slot
+        base = fleet.ctr_base[slot]
+        assert base > 0
+        key, actor = fleet.keys.index['k'], fleet.actors.index[A]
+
+        def combo(ctr):
+            return (key << 32) | ((ctr - base) << ACTOR_BITS) | actor
+
+        assert list(fleet._index_lookup(
+            [slot, slot, slot], [combo(2 * step), combo(2 * step + base),
+                                 combo(2 * step - 1)])) == \
+            [True, False, False]
+
+    def _oracle_after_actor_resort(self):
+        # ACTORS[3] sorts before ACTORS[1]: registering it renumbers every
+        # op of ACTORS[1] the index holds, asked (so handed over) or not
+        fleet, handles = self._standing(2, actor=ACTORS[1])
+        handles, _ = fleet_backend.apply_changes_docs(handles, [
+            [change_buf(ACTORS[1], 2, 2, [
+                {'action': 'set', 'obj': '_root', 'key': 'k', 'value': 5 + d,
+                 'datatype': 'int', 'pred': [f'1@{ACTORS[1]}']}],
+                deps=h['heads'])] for d, h in enumerate(handles)],
+            mirror=False)
+        assert fleet.metrics.standing_preds == 2
+        was = fleet.actors.index[ACTORS[1]]
+        (h0,), _ = fleet_backend.apply_changes_docs(
+            [handles[0]], [[change_buf(ACTORS[3], 1, 3, [
+                {'action': 'set', 'obj': '_root', 'key': 'm', 'value': 1,
+                 'datatype': 'int', 'pred': []}], deps=handles[0]['heads'])]],
+            mirror=False)
+        assert fleet.actors.index[ACTORS[1]] != was
+        handles = [h0, handles[1]]
+        self._refused(handles, [[self._over(h, 9, [3], actor=ACTORS[1],
+                                            seq=1, start=4)]
+                                for h in handles], f'3@{ACTORS[1]}', 0)
+        # 1@ was handed over before the re-sort, 2@ after it
+        per_doc = [[self._over(h, 10 + d, [1, 2], actor=ACTORS[1], seq=1,
+                               start=4)] for d, h in enumerate(handles)]
+        handles, _ = fleet_backend.apply_changes_docs(handles, per_doc,
+                                                      mirror=False)
+        assert fleet.metrics.standing_preds == 6
+        assert fleet_backend.materialize_docs(handles) == \
+            [{'k': 10, 'm': 1}, {'k': 11}]
+
+    @pytest.mark.skipif(not native.available(),
+                        reason='the index is native')
+    def test_native_op_index_matches_a_set_reference(self):
+        """native.OpIndex against per-slot Python sets, through appends out
+        of order, handed over in two batches, and every maintenance call."""
+        rng = np.random.default_rng(7)
+        index, ref = native.op_index(), {}
+
+        def add(n, n_slots):
+            slots = rng.integers(0, n_slots, n)
+            combos = (rng.integers(0, 6, n) << 32) | \
+                (rng.integers(1, 40, n) << 8) | rng.integers(0, 4, n)
+            index.add(slots, combos)
+            for s, c in zip(slots.tolist(), combos.tolist()):
+                ref.setdefault(s, []).append(c)
+
+        def agree():
+            held = [(s, c) for s, cs in ref.items() for c in cs]
+            probes = held + [(s, c + 256) for s, c in held] + \
+                [(99, 1), (-1, 1)]
+            slots, combos = map(np.array, zip(*probes))
+            want = [c in ref.get(s, ()) for s, c in probes]
+            assert index.contains(slots, combos).tolist() == want
+            assert index.rows == len(held) and index.nbytes == 8 * len(held)
+
+        add(400, 20)
+        agree()
+        add(30, 24)                   # a slot's later rows, out of order
+        agree()
+        perm = rng.permutation(256)
+        index.remap(perm)
+        ref = {s: [(c & ~255) | int(perm[c & 255]) for c in cs]
+               for s, cs in ref.items()}
+        agree()
+        index.rebase(3, 5 << 8)
+        ref[3] = [(c & ~0xffffffff) | max((c & 0xffffffff) - (5 << 8), 0)
+                  for c in ref[3]]
+        agree()
+        index.copy(4, 30)
+        ref[30] = list(ref[4])
+        index.copy(98, 5)             # a slot the index never held
+        ref[5] = []
+        agree()
+        index.drop([1, 2, 30, 97])
+        for s in (1, 2, 30):
+            ref.pop(s, None)
+        agree()
+        with pytest.raises(ValueError):
+            index.add([-1], [1])
+
+    @pytest.mark.skipif(not native.available(),
+                        reason='the turbo path needs the native codec')
+    @pytest.mark.parametrize('case', [
+        'many_slots', 'dangling_mid_batch', 'unknown_actor', 'unknown_key',
+        'incomplete_slot', 'after_clone', 'after_free', 'after_rebase',
+        'after_actor_resort'])
+    def test_standing_index_oracle(self, case):
+        """The preds a turbo call's own rows do not resolve are asked of
+        the slot's standing applied-op index, all in one lookup: a valid
+        pred applies, and the first dangling pred in pred order (unknown
+        actor, unknown key or not indexed) raises the exact path's error
+        for its document with every document rolled back; slots marked
+        incomplete are not checked; and the index follows its slots
+        through clone, free, counter rebase and actor re-sort."""
+        getattr(self, f'_oracle_{case}')()
 
     @pytest.mark.parametrize('exact', [False, True])
     def test_dangling_inc_pred_raises(self, exact):

@@ -335,6 +335,52 @@ def test_what_the_general_gate_asks_of_history_is_noted_on_its_phase():
     assert len(handles[0]['state'].queue) == 4
 
 
+def overwrite(seq, deps, key, value, pred):
+    """One set by actor aa..: op seq@aa, overwriting the ops in `pred`."""
+    buf = encode_change({
+        'actor': 'aa' * 16, 'seq': seq, 'startOp': seq, 'time': 0,
+        'message': '', 'deps': list(deps),
+        'ops': [{'action': 'set', 'obj': '_root', 'key': key,
+                 'value': value, 'datatype': 'int',
+                 'pred': [f'{p}@{"aa" * 16}' for p in pred]}]})
+    return buf, decode_change(buf)['hash']
+
+
+@pytest.mark.skipif(not native.available(), reason='needs the native codec')
+def test_what_the_gate_asks_of_the_applied_op_index_is_noted_on_its_phase():
+    """Each document overwrites a key an earlier call set (a pred only the
+    standing applied-op index answers), then overwrites it again (a pred
+    the call's own rows answer): the first kind is counted, once a pred,
+    and noted on gate.validate; the second is not. A text-only call asks
+    the index nothing."""
+    fleet = DocFleet(doc_capacity=4, key_capacity=8)
+    handles, _ = apply_changes_docs(
+        init_docs(3, fleet), [linear_log(d, n=2) for d in range(3)],
+        mirror=False)
+    assert fleet.metrics.standing_preds == 0      # no preds at all
+    per_doc = []
+    for d, handle in enumerate(handles):
+        buf3, h3 = overwrite(3, handle['heads'], 'k1', 100 + d, [1])
+        buf4, _ = overwrite(4, [h3], 'k1', 200 + d, [3])
+        per_doc.append([buf3, buf4])
+    obs_spans.enable(capacity=256)
+    handles, _ = apply_changes_docs(handles, per_doc, mirror=False)
+    observability.disable()
+    named = by_name(observability.iter_spans())
+    assert named['gate.validate'][0]['attrs'] == {'standing_preds': 3}
+    assert fleet.metrics.standing_preds == 3
+    assert [doc['k1'] for doc in materialize_docs(handles)] == \
+        [200, 201, 202]
+
+    fleet, handles, per_doc = text_only({})
+    obs_spans.enable(capacity=256)
+    apply_changes_docs(handles, per_doc, mirror=False)
+    observability.disable()
+    named = by_name(observability.iter_spans())
+    assert named['gate.validate'][0]['attrs'] == {'standing_preds': 0}
+    assert fleet.metrics.standing_preds == 0
+
+
 # ---------------------------------------------------------------------------
 # the seam: turbo_setup, turbo_stage and turbo_dispatch tiled (ISSUE-39)
 # ---------------------------------------------------------------------------
