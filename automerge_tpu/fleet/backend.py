@@ -25,12 +25,16 @@ later call delegates to it, so the full reference semantics are always
 available — the fleet path is an accelerator, never a semantic fork.
 `link` ops reject loudly in the pre-scan (see PARITY.md).
 
-Scale notes: one fleet packs up to 256 actors (tensor_doc.ACTOR_BITS); actor
-numbers are kept in actor-hex sort order so the device's packed-opId
-scatter-max resolves Lamport ties identically to the reference's
-lamportCompare (frontend/apply_patch.js:33-42) — when a new actor lands
-between existing ones, the fleet renumbers by remapping the low bits of the
-winners tensor in one dispatch.
+Scale notes: one fleet's actor table holds up to 256 actors
+(tensor_doc.ACTOR_BITS); actor numbers are kept in actor-hex sort order so
+the device's packed-opId scatter-max resolves Lamport ties identically to
+the reference's lamportCompare (frontend/apply_patch.js:33-42) — when a new
+actor lands between existing ones, the fleet renumbers by remapping the low
+bits of the winners tensor in one dispatch. A sequence row whose op
+counters pass the packed window (tensor_doc.CTR_LIMIT) leaves that layout:
+its ids pack the actor's rank among the row's own writers, in as few bits
+as they need (DocFleet._seq_wide), and a writer joining it repacks that row
+alone.
 """
 
 import contextlib
@@ -64,7 +68,7 @@ _live_fleets = weakref.WeakSet()
 from ..backend.op_set import OpSet
 from ..columnar import decode_change, OBJECT_TYPE
 from .tensor_doc import (ACTOR_BITS, CTR_LIMIT, FleetState, MAX_ACTORS,
-                         TOMBSTONE, pack_op_id)
+                         SEQ_CTR_LIMIT, TOMBSTONE, pack_op_id)
 from .ingest import KeyInterner, doc_runs, layout_doc_runs
 
 _FLAT_ACTIONS = ('set', 'del', 'inc')
@@ -405,6 +409,11 @@ class DocFleet:
         self.seq_place = []       # row -> (cls, idx) | None (unwritten)
         self.seq_len = []         # row -> host upper bound on elements
         self.seq_writers = []     # row -> actors (hex) that wrote a lane
+        # row -> None (ids in the fleet-wide layout) or, for a row whose
+        # counters passed the packed window, its wide layout:
+        # {'writers': sorted hex, 'bits': actor bits, 'ctr': greatest
+        # counter, 'lost': the layout overflowed (the row is inexact)}
+        self.seq_wide = []
         self.seq_free = []
         self.slot_seq = {}        # slot -> {objectId: row}
         # Optional durability hook (fleet/durability.py ChangeJournal):
@@ -647,6 +656,9 @@ class DocFleet:
                 self.seq_place[dst_row] = (place[0], idx)
                 self.seq_len[dst_row] = self.seq_len[row]
                 self.seq_writers[dst_row] = set(self.seq_writers[row])
+                if self.seq_wide[row] is not None:
+                    self.seq_wide[dst_row] = dict(self.seq_wide[row])
+                    self.metrics.seq_wide_rows += 1
                 srcs, dsts = copies.setdefault(place[0], ([], []))
                 srcs.append(place[1])
                 dsts.append(idx)
@@ -717,12 +729,14 @@ class DocFleet:
             self.seq_place[row] = None
             self.seq_len[row] = 0
             self.seq_writers[row] = set()
+            self.seq_wide[row] = None
         else:
             row = len(self.seq_rows)
             self.seq_rows.append(info)
             self.seq_place.append(None)
             self.seq_len.append(0)
             self.seq_writers.append(set())
+            self.seq_wide.append(None)
         self.slot_seq.setdefault(slot, {})[object_id] = row
         return row
 
@@ -789,6 +803,9 @@ class DocFleet:
     def _zero_seq_rows(self, rows):
         by_cls = {}
         for row in rows:
+            if row < len(self.seq_wide) and self.seq_wide[row] is not None:
+                self.seq_wide[row] = None
+                self.metrics.seq_wide_rows -= 1
             place = self.seq_place[row] if row < len(self.seq_place) \
                 else None
             if place is not None:
@@ -805,16 +822,154 @@ class DocFleet:
         sequence pool after a sorted-order actor insertion. The lanes stay
         where they are: an element's lanes are an unordered set found by
         value, not indexed by actor number as the map registers' are
-        (_remap_reg_actors)."""
+        (_remap_reg_actors). Wide rows rank their own writers, whom a
+        renumbering of the fleet's table leaves in order: their ids stay,
+        and a pool of wide rows alone is not touched."""
         if not self.seq_pools.pools:
             return
+        import jax.numpy as jnp
         from .sequence import SeqState
         self.metrics.remaps += 1
         renum = self._actor_renumber(perm)
+        wide = {}       # cls -> pool indexes of its wide rows
+        if self.metrics.seq_wide_rows:
+            for row, layout in enumerate(self.seq_wide):
+                place = self.seq_place[row]
+                if layout is not None and place is not None:
+                    wide.setdefault(place[0], []).append(place[1])
         for cls, st in list(self.seq_pools.pools.items()):
+            held = wide.get(cls)
+            if held is None:
+                self.seq_pools.pools[cls] = SeqState(
+                    renum(st.elem_id), st.nxt, renum(st.reg), st.killed,
+                    st.val, st.counter, st.n, st.inexact)
+                continue
+            if len(held) == st.elem_id.shape[0]:
+                continue
+            narrow = np.ones(st.elem_id.shape[0], dtype=bool)
+            narrow[held] = False
+            narrow = jnp.asarray(narrow)[:, None]
             self.seq_pools.pools[cls] = SeqState(
-                renum(st.elem_id), st.nxt, renum(st.reg), st.killed,
+                jnp.where(narrow, renum(st.elem_id), st.elem_id), st.nxt,
+                jnp.where(narrow, renum(st.reg), st.reg), st.killed,
                 st.val, st.counter, st.n, st.inexact)
+
+    # -- wide sequence rows ----------------------------------------------
+    # A row's ids pack (counter << ACTOR_BITS) | fleet actor number while
+    # its counters stay under CTR_LIMIT. Past it the row is wide: an id
+    # packs (counter << bits) | rank, the actor's rank among the row's
+    # writers (those that inserted or set an element: the actors whose
+    # ids the row holds), sorted as the fleet's table is, in the fewest
+    # bits that hold them — 31 bits of counter for one writer, 30 for
+    # two. Lamport order inside a row is all the kernel compares. On the
+    # host the ops and reads keep the fleet layout, widened to int64:
+    # _seq_to_row / _seq_from_row translate at the device's edge.
+
+    def _seq_rank_lut(self, writers):
+        """[MAX_ACTORS] int64: fleet actor number -> rank in `writers`
+        (sorted hex), -1 for an actor that is not one of them."""
+        lut = np.full(MAX_ACTORS, -1, dtype=np.int64)
+        for rank, actor in enumerate(writers):
+            num = self.actors.index.get(actor)
+            if num is not None:
+                lut[num] = rank
+        return lut
+
+    def _seq_layout(self, row, ctr):
+        """The wide layout `row` needs for its writers and a greatest
+        counter `ctr`: (layout, repack), repack (bits_in, [MAX_ACTORS]
+        lut) for the ids the device holds, or None where they stay."""
+        writers = sorted(self.seq_writers[row])
+        bits = (len(writers) - 1).bit_length()
+        old = self.seq_wide[row]
+        layout = {'writers': writers, 'bits': bits,
+                  'ctr': max(ctr, old['ctr'] if old else 0),
+                  'lost': bool(old and old['lost'])}
+        # the greatest id stays below INT32_MAX, which names no op
+        if layout['ctr'] >= (1 << (31 - bits)) - 1:
+            layout['lost'] = True
+        if old is None:
+            return layout, (ACTOR_BITS, self._seq_rank_lut(writers))
+        if old['writers'] == writers:
+            return layout, None
+        lut = np.zeros(MAX_ACTORS, dtype=np.int64)
+        rank_of = {a: r for r, a in enumerate(writers)}
+        for r, actor in enumerate(old['writers']):
+            lut[r] = rank_of[actor]
+        return layout, (old['bits'], lut)
+
+    def _seq_to_row(self, row, ids):
+        """Fleet-layout ids (int64; 0 none, negative an actor the fleet
+        does not know) -> `row`'s wide layout. An id of an actor that is
+        not one of the row's writers names no element or lane of it and
+        becomes INT32_MAX, which no id of the row equals."""
+        from .sequence import INT32_MAX
+        layout = self.seq_wide[row]
+        rank = self._seq_rank_lut(layout['writers'])[ids & (MAX_ACTORS - 1)]
+        wide = ((ids >> ACTOR_BITS) << layout['bits']) | rank
+        out = np.where(ids > 0, np.where(rank >= 0, wide, INT32_MAX), ids)
+        return np.clip(out, -1, INT32_MAX)
+
+    def _seq_from_row(self, row):
+        """from_row(id) -> the fleet-layout id (a Python int) of one of
+        `row`'s device ids."""
+        layout = self.seq_wide[row]
+        if layout is None:
+            return int
+        bits = layout['bits']
+        nums = [self.actors.index[a] for a in layout['writers']]
+
+        def from_row(packed):
+            packed = int(packed)
+            if packed <= 0:
+                return packed
+            return ((packed >> bits) << ACTOR_BITS) | \
+                nums[packed & ((1 << bits) - 1)]
+        return from_row
+
+    def _seq_widen(self, arr, rows):
+        """Bring the op tuples of wide rows (and of rows whose ops pass
+        the window, which become wide) into their rows' layouts, repacking
+        the ids a row already holds on the device where its layout is new
+        or changed. Returns (arr, {row: actor mask}) for the wide rows."""
+        from .sequence import SEQ_PRED_LANES, repack_rows
+        ids_at = [2, 3] + list(range(5, 5 + SEQ_PRED_LANES))
+        row_a = arr[:, 0]
+        top = np.zeros(len(self.seq_rows), dtype=np.int64)
+        np.maximum.at(top, row_a, arr[:, ids_at].max(axis=1) >> ACTOR_BITS)
+        repack = {}     # cls -> [(idx, bits_in, bits_out, lut)]
+        masks = {}
+        for row in rows:
+            if self.seq_wide[row] is None and top[row] < CTR_LIMIT:
+                continue
+            layout, moved = self._seq_layout(row, int(top[row]))
+            if self.seq_wide[row] is None:
+                self.metrics.seq_wide_rows += 1
+            self.seq_wide[row] = layout
+            if moved is not None and not layout['lost']:
+                cls, idx = self.seq_place[row]
+                repack.setdefault(cls, []).append(
+                    (idx, moved[0], layout['bits'], moved[1]))
+            at = row_a == row
+            arr[np.ix_(at, ids_at)] = self._seq_to_row(
+                row, arr[np.ix_(at, ids_at)])
+            if layout['lost']:
+                arr[at, 5 + SEQ_PRED_LANES] = 1
+            masks[row] = (1 << layout['bits']) - 1
+        if repack:
+            with _span('seq.repack', rows=sum(map(len, repack.values()))):
+                import jax.numpy as jnp
+                for cls, jobs in repack.items():
+                    idx, b_in, b_out, lut = zip(*jobs)
+                    self.seq_pools.pools[cls] = repack_rows(
+                        self.seq_pools.state(cls),
+                        jnp.asarray(np.array(idx, dtype=np.int32)),
+                        jnp.asarray(np.array(b_in, dtype=np.int32)),
+                        jnp.asarray(np.array(b_out, dtype=np.int32)),
+                        jnp.asarray(np.stack(lut).astype(np.int32)))
+                    self.metrics.dispatches += 1
+                    self.metrics.seq_repacks += len(jobs)
+        return arr, masks
 
     def _intern_value(self, value):
         """Inline int32 in [0, 2^31) or a value-table ref -(i + 2)."""
@@ -878,16 +1033,19 @@ class DocFleet:
 
     def _pack_seq_op(self, row, info, op, packed, op_id=None):
         """One decoded sequence op -> (row, kind, ref, packed, value,
-        pred0..predD-1, flag) with packed opIds in fleet actor numbering."""
+        pred0..predD-1, flag) with packed opIds in fleet actor numbering,
+        as Python ints that may pass the int32 window (_dispatch_seq
+        packs a row whose ids do wide)."""
         from .sequence import INSERT, SET, DEL, PAD, SEQ_PRED_LANES
-        from .tensor_doc import pack_op_id
         from ..common import parse_op_id
 
         def pack_ref(eid):
             if eid in (None, '_head'):
                 return 0
             ctr, actor = parse_op_id(eid)
-            return pack_op_id(ctr, self.actors.intern(actor))
+            if ctr >= SEQ_CTR_LIMIT:
+                return -1             # names no op a row can hold
+            return (ctr << ACTOR_BITS) | self.actors.intern(actor)
 
         action = op['action']
         flag = False
@@ -938,9 +1096,11 @@ class DocFleet:
         """Place every touched row in a size-class pool with enough
         capacity (migrating rows that outgrew their class) and batch-apply
         all pending sequence ops — ONE dispatch per active size class.
-        seq_ops rows are (row, kind, ref, packed, value, pred0..D-1, flag).
-        Its three phases tile it as spans: seq.place, seq.columns,
-        seq.enqueue (one a class)."""
+        seq_ops rows are (row, kind, ref, packed, value, pred0..D-1, flag),
+        ids in the fleet layout (int64: a row past the packed window is
+        brought into its wide layout here, _seq_widen).
+        Its three phases tile it as spans: seq.place (a seq.repack child
+        where rows are repacked), seq.columns, seq.enqueue (one a class)."""
         from .sequence import SeqOpBatch, apply_seq_batch_donated, \
             ACTOR_MASK, INSERT, SET, SEQ_PRED_LANES
         if len(self.seq_rows) == 0 or len(seq_ops) == 0:
@@ -971,6 +1131,12 @@ class DocFleet:
         places = self._place_seq_rows(
             uniq_rows, [self.seq_len[row] + int(ins[row])
                         for row in uniq_rows])
+        masks = {}
+        # (a fleet id passes int32 where its counter passes CTR_LIMIT)
+        if self.metrics.seq_wide_rows or \
+                max(int(arr[:, 2:4].max()), int(arr[:, 5:5 + D].max())) \
+                >= 1 << 31:
+            arr, masks = self._seq_widen(arr, uniq_rows)
         # One batch per active class, rows addressed by pool index
         by_cls = {}
         for row, (cls, _idx) in zip(uniq_rows, places):
@@ -1001,17 +1167,28 @@ class DocFleet:
                 cols[name][rows_idx, pos] = arr[sub, j + 1]
             preds[rows_idx, pos] = arr[sub, 5:5 + D]
             flag[rows_idx, pos] = arr[sub, 5 + D] != 0
+            actor_mask = None       # every row in the fleet-wide layout
+            if masks:
+                actor_mask = np.full(r_cap, ACTOR_MASK, dtype=np.int32)
+                for row in rows:
+                    if row in masks:
+                        actor_mask[idx_of[row]] = masks[row]
             batches.append((cls, len(sub), int(multi[rows].sum()), SeqOpBatch(
                 cols['kind'], cols['ref'], cols['packed'], cols['value'],
-                preds, flag)))
+                preds, flag, actor_mask)))
         for cls, n_ops, n_multi, batch in batches:
             r_cap, width = batch.kind.shape
+            # what the referent lookup compares: every row of the class,
+            # each whole
+            lookup_nodes = r_cap * pools.state(cls).elem_id.shape[1]
             ps.mark('seq.enqueue', cls=cls, rows=r_cap, width=width,
-                    ops=n_ops, multiwriter_rows=n_multi)
+                    ops=n_ops, multiwriter_rows=n_multi,
+                    lookup_nodes=lookup_nodes)
             pools.pools[cls], _stats = apply_seq_batch_donated(
                 pools.state(cls), batch)
             self.metrics.dispatches += 1
             self.metrics.seq_op_cells += r_cap * width
+            self.metrics.seq_lookup_nodes += lookup_nodes
         ps.done()
         self.metrics.seq_multiwriter_rows += int(multi.sum())
         self.metrics.seq_ops += len(seq_ops)
@@ -1067,10 +1244,18 @@ class DocFleet:
                     out[row] = None
                     self.metrics.seq_inexact_reads += 1
                     continue
+                is_text = self.seq_rows[row]['type'] == 'text'
+                row_vals = vals[idx][vis[idx]]
+                if is_text and (row_vals >= 0).all():
+                    # every element an inline code point: the whole text
+                    # at once (a long text is millions of them)
+                    out[row] = row_vals.astype('<u4').tobytes().decode(
+                        'utf-32-le', 'surrogatepass')
+                    continue
                 # counter lanes bit-pack (sum << 2) | count-bits
                 items = [(int(v), int(c) >> 2) for v, c in
-                         zip(vals[idx][vis[idx]], cnts[idx][vis[idx]])]
-                if self.seq_rows[row]['type'] == 'text':
+                         zip(row_vals, cnts[idx][vis[idx]])]
+                if is_text:
                     out[row] = ''.join(
                         chr(v) if v >= 0 else str(unbox(v, c))
                         for v, c in items)
@@ -1654,7 +1839,7 @@ class DocFleet:
             action = op['action']
             if obj != '_root' and obj in self.slot_seq.get(d, {}):
                 row = self.slot_seq[d][obj]
-                packed = pack_op_id(ctr, self.actors.intern(actor))
+                packed = (ctr << ACTOR_BITS) | self.actors.intern(actor)
                 seq_ops.append(self._pack_seq_op(row, self.seq_rows[row],
                                                  op, packed, op_id=op_id))
                 continue
@@ -1772,12 +1957,14 @@ class DocFleet:
         for d, op_id, op in changes_to_decoded_ops(per_doc):
             obj = op['obj']
             action = op['action']
-            packed = pack(op_id)
             if obj != '_root' and obj in self.slot_seq.get(d, {}):
                 row = self.slot_seq[d][obj]
+                ctr, actor = parse_op_id(op_id)
+                packed = (ctr << ACTOR_BITS) | self.actors.intern(actor)
                 seq_ops.append(self._pack_seq_op(row, self.seq_rows[row],
                                                  op, packed, op_id=op_id))
                 continue
+            packed = pack(op_id)
             if action in _SEQ_MAKE:
                 self._alloc_seq_row(
                     d, op_id, 'text' if action == 'makeText' else 'list')
@@ -2679,9 +2866,11 @@ class _FlatEngine(HashGraph):
         host engine.
 
         Counter headroom: the LWW grid rebases its packing window per slot
-        (unbounded history), but the sequence rows and the exact-device
-        register engine pack raw counters — ops at or past CTR_LIMIT on
-        those paths promote cleanly here, BEFORE any state mutates."""
+        (unbounded history) and a sequence row past the window repacks
+        wide (counters up to SEQ_CTR_LIMIT), but the exact-device register
+        engine packs raw counters — ops at or past CTR_LIMIT there, and
+        sequence ops at or past SEQ_CTR_LIMIT, promote cleanly here,
+        BEFORE any state mutates."""
         action = op['action']
         if action == 'link':
             # Reserved wire-table action the reference never applies
@@ -2692,8 +2881,9 @@ class _FlatEngine(HashGraph):
         if op['obj'] == '_root' or op['obj'] in made_map:
             if op.get('insert') or op.get('key') is None:
                 raise _Unsupported()
-            if ctr is not None and ctr >= CTR_LIMIT and \
-                    (self.fleet.exact_device or action in _SEQ_MAKE):
+            if ctr is not None and (
+                    (ctr >= CTR_LIMIT and self.fleet.exact_device) or
+                    (ctr >= SEQ_CTR_LIMIT and action in _SEQ_MAKE)):
                 raise _Unsupported()
             if action in _SEQ_MAKE or action in _MAP_MAKE:
                 return
@@ -2715,8 +2905,8 @@ class _FlatEngine(HashGraph):
                 raise _Unsupported()
         elif action not in ('set', 'del', 'inc') or op.get('key') is not None:
             raise _Unsupported()
-        if ctr is not None and ctr >= CTR_LIMIT:
-            raise _Unsupported()      # sequence rows pack raw counters
+        if ctr is not None and ctr >= SEQ_CTR_LIMIT:
+            raise _Unsupported()      # past what a wide row packs
 
     def _rollback(self, backup):
         """Restore gate state; the partially-mutated mirror rebuilds lazily
@@ -2976,6 +3166,8 @@ class _FlatEngine(HashGraph):
             reg, killed, val, cnt = (x.reshape(st.actor_slots, -1).T
                                      for x in (reg, killed, val, cnt))
             is_text = self.seq_objects.get(oid) == 'text'
+            # ids read back in the fleet layout, a wide row's too
+            fleet_id = fleet._seq_from_row(row)
             elems = []
             node = int(nxt[HEAD])
             hops = 0
@@ -2997,12 +3189,12 @@ class _FlatEngine(HashGraph):
                     # so the patch walk can replay the reference's
                     # counterStates edit shapes
                     bits = int(cnt[node, s]) & 3
-                    lanes.append((int(reg[node, s]), raw,
+                    lanes.append((fleet_id(reg[node, s]), raw,
                                   int(cnt[node, s]) >> 2, char,
                                   2 if bits == 3 else bits,
                                   bool(dead_incd[s])))
                 lanes.sort(key=lambda lane: lane[0])
-                elems.append((int(elem_id[node]), lanes))
+                elems.append((fleet_id(elem_id[node]), lanes))
                 node = int(nxt[node])
                 hops += 1
             if hops > limit:
@@ -4610,7 +4802,8 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
     kept_packed_nat = rows['packed'][keep]
     if len(kept_packed_nat):
         kept_doc = change_doc[kept_change]
-        pairs = kept_doc * (1 << 32) + kept_packed_nat
+        # (a sequence op's id may pass int32: the parser widens it)
+        pairs = kept_doc * (1 << 40) + kept_packed_nat
         # run-boundary dup check (the trick staging uses): one sort and
         # an adjacent-equality scan — np.unique(return_counts=True) paid
         # for the unique array and a reduceat nobody read
@@ -4618,7 +4811,7 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
         dup = pairs_sorted[1:] == pairs_sorted[:-1]
         if dup.any():
             restore_all()
-            bad_doc = int(pairs_sorted[1:][dup][0] >> 32)
+            bad_doc = int(pairs_sorted[1:][dup][0] >> 40)
             raise DuplicateOpId('duplicate operation ID in turbo batch',
                                 doc_index=bad_doc)
 
@@ -5095,7 +5288,10 @@ def _apply_changes_turbo_inner(handles, per_doc_changes, ps, sub, part,
     n_kept_root = int(keep_root.sum())
     doc_arr = change_doc[rows['doc'][keep_root]].astype(np.int32)
     slots = slot_of_doc.astype(np.int32)[doc_arr]
-    kept_packed_root = rows['packed'][keep_root]
+    # map-key ops stay inside the packed window (the parser refuses the
+    # others), so a batch that widened its sequence ids narrows here
+    kept_packed_root = rows['packed'][keep_root].astype(np.int32,
+                                                        copy=False)
     # Key interning: root keys as bare strings; nested map/table cells as
     # composite (objectId, key) — shared with the register ingest
     from .ingest import intern_composite_keys

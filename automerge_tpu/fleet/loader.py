@@ -20,9 +20,12 @@ round-trip; note this skips save()'s usual canonical re-encode, so two
 replicas bulk-loaded from *different* foreign encodings of the same state
 can save different bytes until their first edit.
 
-Documents outside the fleet subset (link ops, unknown columns, op counters
-past the 2^23 packing window, >256 actors) fall back per-doc to the
+Documents outside the fleet subset (link ops, unknown columns, map-key ops
+or objects with counters past the 2^23 packing window, sequence elements
+past tensor_doc.SEQ_CTR_LIMIT, >256 actors) fall back per-doc to the
 ordinary load path — the loader is an accelerator, never a semantic fork.
+A Text or list whose element counters pass the window installs as a WIDE
+row, its ids packed with its own writers' ranks (DocFleet._seq_wide).
 Objects inside sequences (rows-in-lists) bulk-load natively: make element
 rows install as links (round 4).
 """
@@ -32,7 +35,7 @@ import numpy as np
 from .. import native
 from ..columnar import (decode_value, split_containers,
                         CHUNK_TYPE_DOCUMENT, MAGIC_BYTES as _MAGIC)
-from .tensor_doc import CTR_LIMIT, MAX_ACTORS
+from .tensor_doc import CTR_LIMIT, MAX_ACTORS, SEQ_CTR_LIMIT
 from ..observability.spans import spanned as _spanned
 
 # Wire action numbers (ref columnar.js:51-52)
@@ -59,8 +62,9 @@ class _DocDeferredBatch:
 
 def _okey(doc, ctr, actor):
     """Doc-scoped object/op key: collision-free int64 for (doc, ctr, actor)
-    with ctr < 2^23 and actor < 256 (root encodes as ctr=0, actor=-1)."""
-    return doc.astype(np.int64) * (1 << 33) + ctr * 512 + (actor + 1)
+    with ctr < SEQ_CTR_LIMIT (2^30) and actor < 256 (root encodes as
+    ctr=0, actor=-1)."""
+    return doc.astype(np.int64) * (1 << 40) + ctr * 512 + (actor + 1)
 
 
 def _isin_sorted(values, sorted_arr):
@@ -157,13 +161,16 @@ def _install_parsed(fleet, out, native_idx, chunks, handles, fleet_backend):
     n_ops = len(doc)
 
     # ---- per-doc viability ----------------------------------------------
-    # Overflow badness FIRST: _okey packing assumes ctr < 2^23 and
-    # actor < 256, so rows of overflowing (fallback-bound) docs must be
-    # excluded from classification keys before they can alias another
-    # doc's object identities
+    # Overflow badness FIRST: _okey packing assumes ctr < SEQ_CTR_LIMIT
+    # and actor < 256, so rows of overflowing (fallback-bound) docs must
+    # be excluded from classification keys before they can alias another
+    # doc's object identities. Objects (and so their makes) stay inside
+    # the packed window; a map-key op's too, checked once rows are
+    # classified below
     bad = ~ok.copy()
-    ctr_over = (id_ctr >= CTR_LIMIT) | (key_ctr >= CTR_LIMIT) | \
-        (obj_ctr >= CTR_LIMIT)
+    ctr_over = (id_ctr >= SEQ_CTR_LIMIT) | (key_ctr >= SEQ_CTR_LIMIT) | \
+        (obj_ctr >= CTR_LIMIT) | \
+        (np.isin(action, _MAKES) & (id_ctr >= CTR_LIMIT))
     actor_over = (id_actor >= MAX_ACTORS) | (id_actor < 0) | \
         (key_actor >= MAX_ACTORS) | (obj_actor >= MAX_ACTORS)
     for mask in (ctr_over, actor_over):
@@ -173,8 +180,8 @@ def _install_parsed(fleet, out, native_idx, chunks, handles, fleet_backend):
     srow = np.repeat(np.arange(n_ops), np.diff(succ_off)) if n_succ else \
         np.zeros(0, dtype=np.int64)
     if n_succ:
-        sc_over = (succ_ctr >= CTR_LIMIT) | (succ_actor >= MAX_ACTORS) | \
-            (succ_actor < 0)
+        sc_over = (succ_ctr >= SEQ_CTR_LIMIT) | \
+            (succ_actor >= MAX_ACTORS) | (succ_actor < 0)
         if sc_over.any():
             bad[np.unique(doc[srow[sc_over]])] = True
 
@@ -194,7 +201,11 @@ def _install_parsed(fleet, out, native_idx, chunks, handles, fleet_backend):
     # are legal element rows (rows-in-lists): their value lane becomes a
     # link to the child object, handled in _install_seq_rows.
     map_malformed = row_ok & ~row_is_seq & ((key_str < 0) | insert)
-    for mask in (orphan, map_malformed):
+    # a map-key op's id and successors pack into the grid's int32 window
+    map_wide = row_ok & ~row_is_seq & (id_ctr >= CTR_LIMIT)
+    if n_succ:
+        map_wide[srow[~row_is_seq[srow] & (succ_ctr >= CTR_LIMIT)]] = True
+    for mask in (orphan, map_malformed, map_wide):
         if mask.any():
             bad[np.unique(doc[mask])] = True
 
@@ -546,11 +557,11 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
 
     # update rows: find the target element by its insert op id
     ins_idx = np.flatnonzero(ins)
-    ikey = inv[ins_idx] * (1 << 33) + packed32[rows][ins_idx]
+    ikey = inv[ins_idx] * (1 << 40) + packed32[rows][ins_idx]
     ins_sorted = np.argsort(ikey)
     ins_keys = ikey[ins_sorted]
     tgt_packed = (key_ctr[rows] << 8) | np.maximum(key_actor[rows], 0)
-    tkey = inv * (1 << 33) + tgt_packed
+    tkey = inv * (1 << 40) + tgt_packed
     if len(ins_keys):
         pos = np.clip(np.searchsorted(ins_keys, tkey), 0, len(ins_keys) - 1)
         matched = ins_keys[pos] == tkey
@@ -627,6 +638,24 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
     wrote[inv[~inc_mask[rows]], id_actor[rows][~inc_mask[rows]]] = True
     for u, a in np.argwhere(wrote).tolist():
         fleet.seq_writers[int(fleet_row[u])].add(fleet.actors.actors[a])
+    # the ids as the device holds them: a row whose counters pass the
+    # packed window installs wide, in its writers' ranks
+    dev_id = packed32[rows]
+    top = np.zeros(len(uniq), dtype=np.int64)
+    np.maximum.at(top, inv, dev_id >> 8)
+    wide_objs = np.flatnonzero(top >= CTR_LIMIT)
+    if len(wide_objs):
+        dev_id = dev_id.copy()
+        for u in wide_objs.tolist():
+            row = int(fleet_row[u])
+            layout, _moved = fleet._seq_layout(row, int(top[u]))
+            if fleet.seq_wide[row] is None:
+                fleet.metrics.seq_wide_rows += 1
+            fleet.seq_wide[row] = layout
+            at = inv == u
+            dev_id[at] = fleet._seq_to_row(row, dev_id[at])
+            if layout['lost']:
+                inex_obj[u] = True
     place = fleet._place_seq_rows(fleet_row.tolist(), n_elems.tolist())
     cls_arr = np.array([p[0] for p in place], dtype=np.int64)
     idx_arr = np.array([p[1] for p in place], dtype=np.int64)
@@ -671,7 +700,7 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
         in_cls = row_of_op >= 0
         ins_sel = np.flatnonzero(ins & in_cls)
         elem_host = np.zeros((len(objs), nodes), dtype=np.int32)
-        elem_host[row_of_op[ins_sel], node[ins_sel]] = packed32[rows][ins_sel]
+        elem_host[row_of_op[ins_sel], node[ins_sel]] = dev_id[ins_sel]
         install('elem_id', elem_host)
 
         live_sel = np.flatnonzero(live_mask & in_cls)
@@ -707,7 +736,7 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
         # lane l of node i is at column l * nodes + i
         at = (row_of_op[both], lane * nodes + node[both])
         lanes_shape = (len(objs), st.actor_slots * nodes)
-        for name, values_of in (('reg', packed32[rows]), ('val', values),
+        for name, values_of in (('reg', dev_id), ('val', values),
                                 ('counter', counter_add[rows])):
             host = np.zeros(lanes_shape, dtype=np.int32)
             host[at] = values_of[both]

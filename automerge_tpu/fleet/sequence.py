@@ -27,12 +27,17 @@ loop does), while the fleet axis is embarrassingly parallel — the SURVEY §7
 fully parallel, replacing the reference's visibleCount block walk
 (new.js:225-240).
 
-Packed opIds: (counter << ACTOR_BITS) | actorNum, as in tensor_doc. For the
-integer comparisons here to agree with the host engine's Lamport order
-(counter, actorId-hex-string) — used both for the RGA concurrent-insert skip
-and per-element LWW — actor numbers MUST be assigned in ascending
-lexicographic order of the actor hex ids (the reference's columnar format
-sorts its actor table the same way, ref backend/columnar.js:133-170).
+Packed opIds: (counter << ACTOR_BITS) | actorNum, as in tensor_doc, for a
+row whose counters stay inside the packed window; a row past it is WIDE,
+its ids (counter << b) | rank, the rank of the actor among the row's
+writers in b bits (DocFleet._seq_wide). A row's layout is all the kernel
+has to know: the RGA skip and the per-element Lamport winner compare ids
+of ONE row, and an op batch gives each row the mask of its actor bits
+(SeqOpBatch.actor_mask). For the integer comparisons to agree with the
+host engine's Lamport order (counter, actorId-hex-string), actor numbers
+and ranks MUST follow ascending lexicographic order of the actor hex ids
+(the reference's columnar format sorts its actor table the same way, ref
+backend/columnar.js:133-170).
 
 Per-element overwrite state is an exact multi-value register (the
 fleet/registers.py design applied to sequence elements): each element keeps
@@ -236,23 +241,27 @@ class SeqOpBatch:
       Lamport-max pred is the attribution target (new.js:942-945).
     - flag   bool: host-detected inexactness for this row (pred-lane
       overflow, object elements in Text rows): applied unconditionally.
+    - actor_mask int32 [N] or None: the actor bits of each row's ids
+      (ACTOR_MASK in the fleet-wide layout, fewer in a wide row's); None
+      where every row is in the fleet-wide layout.
     """
 
-    def __init__(self, kind, ref, packed, value, preds=None, flag=None):
+    def __init__(self, kind, ref, packed, value, preds=None, flag=None,
+                 actor_mask=None):
         self.kind = kind
         self.ref = ref
         self.packed = packed
         self.value = value
+        shape = np.shape(kind)
         if preds is None:
-            preds = np.zeros(np.asarray(kind).shape + (SEQ_PRED_LANES,),
-                             dtype=np.int32)
+            preds = np.zeros(shape + (SEQ_PRED_LANES,), dtype=np.int32)
         self.preds = preds
-        self.flag = np.zeros(np.asarray(kind).shape, dtype=bool) \
-            if flag is None else flag
+        self.flag = np.zeros(shape, dtype=bool) if flag is None else flag
+        self.actor_mask = actor_mask
 
     def tree_flatten(self):
         return ((self.kind, self.ref, self.packed, self.value, self.preds,
-                 self.flag), None)
+                 self.flag, self.actor_mask), None)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -268,10 +277,11 @@ def _pick(at, row):
     return jnp.sum(jnp.where(at, row, 0))
 
 
-def _apply_one_doc(carry, op, elem_id, nxt, n0):
+def _apply_one_doc(carry, op, elem_id, nxt, n0, actor_mask):
     """One op against one doc. `elem_id`, `nxt` and `n0` are the row as the
     dispatch found it, read and never written here; the batch's splices
-    live in the carry's overlay (see _apply_seq_batch_impl).
+    live in the carry's overlay (see _apply_seq_batch_impl). `actor_mask`
+    is the row's actor bits.
     carry = (ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n,
     inexact); the op's `in_row` is its referent's node in the row as the
     dispatch found it, or `nodes` (_referent_lookup).
@@ -447,7 +457,7 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0):
     # the element than the pool has lanes: the fleet widens a pool before
     # that, from the actors that wrote the row) flags the row too.
     is_set_live = upd_ok & (kind == SET)
-    mine = (reg_row != 0) & ((reg_row & ACTOR_MASK) == (packed & ACTOR_MASK))
+    mine = (reg_row != 0) & ((reg_row & actor_mask) == (packed & actor_mask))
     empty = reg_row == 0
     a_ok = jnp.any(mine | empty)
     a_c = jnp.where(jnp.any(mine), jnp.argmax(mine),
@@ -560,14 +570,17 @@ def _apply_seq_batch_impl(state, ops):
     by a search of `elem_id` in every scan step."""
     rows, width = ops.kind.shape
     width = max(width, 1)       # an empty batch still traces the step
+    actor_mask = jnp.full((rows,), ACTOR_MASK, jnp.int32) \
+        if ops.actor_mask is None else ops.actor_mask
 
     def per_doc(elem_id, nxt, reg, killed, val, counter, n, inexact,
-                kind, ref, in_row, packed, value, preds, flag, zeros):
+                kind, ref, in_row, packed, value, preds, flag, actor_mask,
+                zeros):
         carry = (zeros, zeros, zeros - 1, zeros, reg, killed, val, counter,
                  n, inexact)
         xs = (kind, ref, in_row, packed, value, preds, flag)
         carry, applied = lax.scan(
-            lambda c, x: _apply_one_doc(c, x, elem_id, nxt, n),
+            lambda c, x: _apply_one_doc(c, x, elem_id, nxt, n, actor_mask),
             carry, xs)
         ov_id, ov_nxt, rp_node, rp_nxt, reg, killed, val, counter, n_out, \
             inexact = carry
@@ -587,7 +600,7 @@ def _apply_seq_batch_impl(state, ops):
         state.elem_id, state.nxt, state.reg, state.killed, state.val,
         state.counter, state.n, state.inexact, ops.kind, ops.ref,
         _referent_lookup(state.elem_id, ops.ref), ops.packed, ops.value,
-        ops.preds, ops.flag,
+        ops.preds, ops.flag, actor_mask,
         # the empty overlay, batched like the rest of the scan's carry
         jnp.zeros((rows, width), jnp.int32))
     return SeqState(*carry), jnp.sum(applied)
@@ -808,6 +821,30 @@ def _copy_rows_impl(d, s, si, di):
 
 
 _copy_rows = instrument_kernel('seq_copy_rows', jax.jit(_copy_rows_impl))
+
+
+def _repack_rows_impl(state, rows, bits_in, bits_out, lut):
+    """State with the ids of its rows `rows` ([k]) repacked: an id of row
+    rows[i] packs (counter << bits_in[i]) | a, and becomes (counter <<
+    bits_out[i]) | lut[i, a]. Zero stays zero. Only `elem_id` and `reg`
+    hold ids; the pointers, payloads and flags stay where they are."""
+
+    def repack(arr):
+        part = arr[rows]
+        b_in, b_out = bits_in[:, None], bits_out[:, None]
+        actor = part & ((1 << b_in) - 1)
+        new = ((part >> b_in) << b_out) | jnp.take_along_axis(
+            lut, actor, axis=1)
+        return arr.at[rows].set(jnp.where(part != 0, new, 0))
+
+    return SeqState(repack(state.elem_id), state.nxt, repack(state.reg),
+                    state.killed, state.val, state.counter, state.n,
+                    state.inexact)
+
+
+repack_rows = instrument_kernel(
+    'seq_repack_rows',
+    jax.jit(_repack_rows_impl, donate_argnums=(0,)))
 
 
 class SeqPools:

@@ -26,11 +26,17 @@ import numpy as np
 
 ACTOR_BITS = 8               # up to 256 distinct actors per fleet
 MAX_ACTORS = 1 << ACTOR_BITS
-# Packed counters occupy 23 bits (~8.4M) — a WINDOW, not a history cap: the
-# LWW grid rebases each slot's window as counters grow (DocFleet.ctr_base /
-# _rebase_slot), so history length is unbounded; only a slot's live-winner
-# counter spread is window-bounded (beyond that, reads use the host mirror)
+# Packed counters occupy 23 bits (~8.4M) in the fleet-wide layout — a
+# WINDOW, not a history cap. The LWW grid rebases each slot's window as
+# counters grow (DocFleet.ctr_base / _rebase_slot): only a slot's
+# live-winner counter spread is window-bounded (beyond that, reads use the
+# host mirror). A sequence row cannot rebase (any old element can be a
+# referent), so a row whose ids pass the window is repacked WIDE: its ids
+# pack (counter, the actor's rank among the row's writers) with as many
+# actor bits as its writers need (DocFleet._seq_wide), up to
+# SEQ_CTR_LIMIT. The map register engine still packs raw counters.
 CTR_LIMIT = 1 << (31 - ACTOR_BITS)
+SEQ_CTR_LIMIT = 1 << 30
 TOMBSTONE = -1               # value-table index marking a deleted key
 
 

@@ -30,7 +30,7 @@ turbo path returns None without the codec. Nor has the gate's
 dangling-pred oracle, ``OpIndex`` (am_opindex_*: per document slot, the
 map-key op rows applied), which only the turbo path reads.
 
-A compiled binary carries an ABI stamp (``am_abi_version``, now 6); a
+A compiled binary carries an ABI stamp (``am_abi_version``, now 7); a
 stale .so that cannot be rebuilt fails loudly at import instead of
 silently running an old single-threaded codec (see tools/build_native.sh).
 """
@@ -53,7 +53,7 @@ from ..observability.spans import span as _span
 # Bumped in lockstep with codec.cpp's am_abi_version whenever the C
 # surface changes shape. A mismatch means the cached .so predates this
 # wrapper (or vice versa) and MUST NOT be used.
-_ABI_VERSION = 6
+_ABI_VERSION = 7
 
 
 class NativeAbiMismatch(RuntimeError):
@@ -599,6 +599,9 @@ def _ingest_changes(buffers, doc_ids, with_meta, with_seq, blob, lens):
             return None
         seq_cols = seq_cols + (vlen[:int(n_rows)],
                                arena[:arena_size].tobytes())
+    wide = _fetch_ingest_wide(lib)
+    if wide is None:
+        return None
     n = max(int(n_rows), 1)
     doc = np.zeros(n, dtype=np.int32)
     key = np.zeros(n, dtype=np.int32)
@@ -648,8 +651,43 @@ def _ingest_changes(buffers, doc_ids, with_meta, with_seq, blob, lens):
          rows['vblob']) = seq_cols
     if with_meta:
         rows['pred_off'], rows['pred'] = preds
+    if len(wide):
+        for name in ('packed', 'ref', 'pred'):
+            if name in rows:
+                rows[name] = _widened(rows[name], wide)
+    if with_meta:
         return rows, keys, actors, metas
     return rows, keys, actors
+
+
+def _fetch_ingest_wide(lib):
+    """The pending ingest's wide ids: sequence element ops whose own id,
+    referent or pred has a counter past the int32 packing window. Their
+    cells hold the marker -(2 + k) and entry k the id, (ctr << 8) | actor,
+    as int64. Must run before am_ingest_fetch."""
+    i64 = ctypes.c_int64
+    lib.am_ingest_wide_count.argtypes = []
+    lib.am_ingest_wide_count.restype = i64
+    count = int(lib.am_ingest_wide_count())
+    if count < 0:
+        return None
+    out = np.zeros(max(count, 1), dtype=np.int64)
+    if count:
+        lib.am_ingest_wide_fetch.argtypes = [ctypes.POINTER(i64),
+                                             ctypes.c_uint64]
+        lib.am_ingest_wide_fetch.restype = i64
+        if lib.am_ingest_wide_fetch(out.ctypes.data_as(ctypes.POINTER(i64)),
+                                    out.size) != count:
+            return None
+    return out[:count]
+
+
+def _widened(column, wide):
+    """An id column as int64, its markers replaced by their wide ids."""
+    out = column.astype(np.int64)
+    marked = out < -1
+    out[marked] = wide[-out[marked] - 2]
+    return out
 
 
 def _fetch_ingest_preds(lib, n_rows):

@@ -873,6 +873,11 @@ struct IngestCtx {
   // packed referent elemId (0 = head/none), wire value-type tag low nibble
   std::vector<int32_t> out_obj, out_ref;
   std::vector<uint8_t> out_vtype;
+  // Ids of sequence element ops past the packed window (counter at or
+  // past 2^(31 - kActorBits)): their packed/ref/pred cell holds the
+  // marker -(2 + k), and out_wide[k] the id as (ctr << kActorBits) |
+  // actor in int64. Empty unless such an op was parsed.
+  std::vector<int64_t> out_wide;
   // Boxed-value passthrough (with_seq only): rows whose payload an int32
   // lane can't carry (strings/floats/bytes, multi-char text) get their raw
   // wire value bytes appended here; out_vlen is 0 for inline-value rows
@@ -942,6 +947,20 @@ constexpr int kActionSet = 1, kActionDel = 3, kActionInc = 5;
 constexpr int kActionMakeMap = 0, kActionMakeList = 2;
 constexpr int kActionMakeText = 4, kActionMakeTable = 6;
 constexpr int kActorBits = 8;
+// Counters below this pack into an int32 id; sequence element ops (and
+// the ids they name) may go up to kSeqCtrLimit, as wide ids (out_wide)
+constexpr int64_t kCtrLimit = int64_t(1) << (31 - kActorBits);
+constexpr int64_t kSeqCtrLimit = int64_t(1) << 30;
+
+// An id as an int32 cell: packed when its counter is under the window,
+// else a marker into the context's wide table
+static int32_t pack_cell(std::vector<int64_t> &wide, int64_t ctr,
+                         int32_t actor) {
+  int64_t id = (ctr << kActorBits) | int64_t(actor);
+  if (ctr < kCtrLimit) return int32_t(id);
+  wide.push_back(id);
+  return int32_t(-2 - int64_t(wide.size() - 1));
+}
 
 // Decode a UTF-8 buffer holding EXACTLY one code point; returns it or -1.
 // Text-element payloads are single characters in the hot editing path —
@@ -1191,6 +1210,14 @@ static bool parse_change_body(IngestCtx &ctx, const uint8_t *body,
   uint64_t pred_pos = 0;
   for (uint64_t i = 0; i < n_ops; i++) {
     int64_t action = actions[i];
+    // a sequence element op's own id, referent and preds may lie past
+    // the packed window; every other op's stay inside it
+    bool seq_elem = with_seq &&
+        i < obj_ctr.size() && obj_ctr_ok.size() > i && obj_ctr_ok[i] &&
+        !(i < key_ids.size() && key_ids[i] >= 0) &&
+        (action == kActionSet || action == kActionDel ||
+         action == kActionInc);
+    int64_t ctr_cap = seq_elem ? kSeqCtrLimit : kCtrLimit;
     if (with_meta) {
       ctx.out_pred_off.push_back(int64_t(ctx.out_pred.size()));
       uint64_t np = 0;
@@ -1206,10 +1233,9 @@ static bool parse_change_body(IngestCtx &ctx, const uint8_t *body,
         uint64_t ta = uint64_t(pred_actor[pred_pos]);
         if (ta >= actor_table.size()) return false;
         int64_t pctr = pred_ctr[pred_pos];
-        if (pctr <= 0 || pctr >= (int64_t(1) << (31 - kActorBits)))
-          return false;
+        if (pctr <= 0 || pctr >= ctr_cap) return false;
         ctx.out_pred.push_back(
-            int32_t((pctr << kActorBits) | actor_table[ta]));
+            pack_cell(ctx.out_wide, pctr, actor_table[ta]));
       }
     }
     bool is_root = !(i < obj_ctr.size() && obj_ctr_ok.size() > i &&
@@ -1223,8 +1249,8 @@ static bool parse_change_body(IngestCtx &ctx, const uint8_t *body,
     const uint8_t *vbytes = val_raw ? val_raw + raw_pos : nullptr;
     raw_pos += vsize;
     int64_t ctr = int64_t(start_op + i);
-    if (ctr >= (int64_t(1) << (31 - kActorBits))) return false;
-    int32_t self_packed = int32_t((ctr << kActorBits) | actor_id);
+    if (ctr >= ctr_cap) return false;
+    int32_t self_packed = pack_cell(ctx.out_wide, ctr, actor_id);
 
     // Containing object for non-root ops, packed (ctr << bits) | actor
     int32_t obj_packed = 0;
@@ -1233,8 +1259,7 @@ static bool parse_change_body(IngestCtx &ctx, const uint8_t *body,
       uint64_t ta = uint64_t(obj_actor[i]);
       if (ta >= actor_table.size()) return false;
       int64_t objc = (i < obj_ctr.size()) ? obj_ctr[i] : 0;
-      if (objc <= 0 || objc >= (int64_t(1) << (31 - kActorBits)))
-        return false;
+      if (objc <= 0 || objc >= kCtrLimit) return false;
       obj_packed = int32_t((objc << kActorBits) | actor_table[ta]);
     }
 
@@ -1249,7 +1274,7 @@ static bool parse_change_body(IngestCtx &ctx, const uint8_t *body,
       // referent elemId: keyCtr 0 = '_head' (insert only); else packed
       if (i >= key_ctr.size() || !key_ctr_ok[i]) return false;
       int64_t kc = key_ctr[i];
-      if (kc < 0 || kc >= (int64_t(1) << (31 - kActorBits))) return false;
+      if (kc < 0 || kc >= ctr_cap) return false;
       int32_t ref = 0;
       if (kc == 0) {
         if (!insert) return false;    // update needs a real target
@@ -1257,7 +1282,7 @@ static bool parse_change_body(IngestCtx &ctx, const uint8_t *body,
         if (i >= key_actor.size() || !key_actor_ok[i]) return false;
         uint64_t ka = uint64_t(key_actor[i]);
         if (ka >= actor_table.size()) return false;
-        ref = int32_t((kc << kActorBits) | actor_table[ka]);
+        ref = pack_cell(ctx.out_wide, kc, actor_table[ka]);
       }
       if (is_make) {
         // Object nested inside a sequence (rows-in-lists): flag-coded
@@ -1553,7 +1578,13 @@ static bool merge_ingest_slices(IngestCtx &g, std::vector<IngestCtx> &slices,
       if (amap[i] >= (1 << kActorBits)) return false;
     }
     constexpr uint32_t kAMask = (1u << kActorBits) - 1;
+    // a wide id's marker moves by the wide entries earlier slices gave
+    int32_t wide_base = int32_t(g.out_wide.size());
+    for (int64_t w : s.out_wide)
+      g.out_wide.push_back((w & ~int64_t(kAMask)) |
+                           int64_t(amap[uint32_t(w) & kAMask]));
     auto remap = [&](int32_t v) -> int32_t {
+      if (v < 0) return v - wide_base;
       return int32_t((uint32_t(v) & ~kAMask) |
                      uint32_t(amap[uint32_t(v) & kAMask]));
     };
@@ -1746,7 +1777,7 @@ int64_t am_ingest_changes_list(PyObject *buffers, int with_meta,
 // Monotone ABI stamp, bumped on any C-surface change. The Python wrapper
 // refuses to run against a binary whose stamp mismatches (a stale .so
 // would otherwise silently run the old single-threaded codec).
-int64_t am_abi_version() { return 6; }
+int64_t am_abi_version() { return 7; }
 
 int64_t am_pool_configure(int n) { return NativePool::inst().configure(n); }
 
@@ -2459,6 +2490,20 @@ int64_t am_ingest_val_fetch(int32_t *vlen, uint8_t *arena, uint64_t cap) {
   if (!ctx.val_arena.empty())
     copy_bytes(arena, ctx.val_arena.data(), ctx.val_arena.size());
   return int64_t(ctx.val_arena.size());
+}
+
+// Wide ids of the pending ingest (see IngestCtx::out_wide): the count,
+// and a copy of the table. Must run before am_ingest_fetch.
+int64_t am_ingest_wide_count() {
+  return g_ingest ? int64_t(g_ingest->out_wide.size()) : -1;
+}
+
+int64_t am_ingest_wide_fetch(int64_t *out, uint64_t cap) {
+  if (!g_ingest) return -1;
+  IngestCtx &ctx = *g_ingest;
+  if (ctx.out_wide.size() > cap) return -1;
+  copy_bytes(out, ctx.out_wide.data(), ctx.out_wide.size() * 8);
+  return int64_t(ctx.out_wide.size());
 }
 
 int64_t am_ingest_pred_count() {

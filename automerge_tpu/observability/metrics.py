@@ -135,6 +135,12 @@ class Metrics:
                                  # more than one actor
         'seq_inexact_reads',     # rows a bulk render found flagged
                                  # inexact and left to the host mirror
+        'seq_repacks',           # rows whose ids were rewritten on the
+                                 # device into a wide layout (a row past
+                                 # the packed window, or a wide row that
+                                 # gained a writer)
+        'seq_lookup_nodes',      # rows x nodes of every dispatched class:
+                                 # what the referent lookups compared
         # bulk reads (fleet/backend.py materialize_docs)
         'read_docs',             # handles asked of the fleet
         'read_rows',             # rows the device gather moved to the host
@@ -144,6 +150,8 @@ class Metrics:
         # dispatch or bulk load (delta() gives their change)
         'seq_pool_bytes',        # bytes of every pool's arrays
         'seq_nodes',             # rows x nodes over all pools
+        'seq_wide_rows',         # rows holding a counter at or past the
+                                 # packed window (tensor_doc.CTR_LIMIT)
     )
 
     def __init__(self):

@@ -776,12 +776,14 @@ class TestPromotion:
              'datatype': 'int', 'pred': [f'1@{ACTORS[0]}']}], deps=[h1])
         gb, patch = fleet_backend.apply_changes(gb, [c2])
         assert patch['pendingChanges'] == 1
-        # A sequence make past the packed-counter window still promotes
+        # A sequence make past the packed-counter window stays on the
+        # device: the grid rebases the key's window and the list's row
+        # packs wide once its ids pass it
         from automerge_tpu.fleet.tensor_doc import CTR_LIMIT
         big = change_buf(ACTORS[1], 1, CTR_LIMIT + 1, [
             {'action': 'makeList', 'obj': '_root', 'key': 'l', 'pred': []}])
         gb, _ = fleet_backend.apply_changes(gb, [big])
-        assert not gb['state'].is_fleet
+        assert gb['state'].is_fleet
         gb, patch = fleet_backend.apply_changes(gb, [c1])
         assert patch['pendingChanges'] == 0
         props = fleet_backend.get_patch(gb)['diffs']['props']

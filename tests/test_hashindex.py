@@ -407,11 +407,11 @@ class TestFleetWiring:
         # warm the index so the probe path is live
         _s, _m = generate_sync_messages_docs(
             handles, [init_sync_state() for _ in handles])
-        from automerge_tpu.fleet.tensor_doc import CTR_LIMIT
-        # a makeText past the counter-packing window: fleet-unsupported
+        from automerge_tpu.fleet.tensor_doc import SEQ_CTR_LIMIT
+        # a makeText past what a wide sequence row packs: fleet-unsupported
         # (promotes), host-valid (applies cleanly after promotion)
         big_inc = encode_change({
-            'actor': 'dd' * 16, 'seq': 1, 'startOp': CTR_LIMIT + 10,
+            'actor': 'dd' * 16, 'seq': 1, 'startOp': SEQ_CTR_LIMIT + 10,
             'time': 0, 'message': '', 'deps': list(handles[0]['heads']),
             'ops': [{'action': 'makeText', 'obj': '_root', 'key': 'deep',
                      'pred': []}]})

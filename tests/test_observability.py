@@ -51,10 +51,11 @@ def test_metrics_counters_track_turbo_and_exact():
     assert d['graph_builds'] >= 1
 
     # Exact path and promotion (nested maps AND objects inside sequences
-    # are fleet-resident now; a sequence make past the packed-counter
-    # window is the remaining promotion trigger)
-    from automerge_tpu.fleet.tensor_doc import CTR_LIMIT
-    c = change_buf(ACTORS[0], 2, CTR_LIMIT + 1, [
+    # are fleet-resident now, and so are sequences past the packed-counter
+    # window; a sequence make past what a wide row packs is the remaining
+    # promotion trigger)
+    from automerge_tpu.fleet.tensor_doc import SEQ_CTR_LIMIT
+    c = change_buf(ACTORS[0], 2, SEQ_CTR_LIMIT + 1, [
         {'action': 'makeList', 'obj': '_root', 'key': 'l', 'pred': []}],
         deps=fleet_backend.get_heads(handles[0]))
     h0, _ = fleet_backend.apply_changes(handles[0], [c])

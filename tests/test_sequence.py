@@ -586,7 +586,9 @@ def _plain_apply(state, ops):
     rows, nodes = elem.shape
     capacity, lanes = nodes - 3, reg.shape[1] // nodes
     kinds, refs, ids, values, pred_lists, flags = [
-        np.asarray(a) for a in ops.tree_flatten()[0]]
+        np.asarray(a) for a in ops.tree_flatten()[0][:6]]
+    masks = np.full(len(kinds), ACTOR_MASK) if ops.actor_mask is None \
+        else np.asarray(ops.actor_mask)
     applied = np.zeros(kinds.shape, dtype=bool)
     for d in range(rows):
         for p in range(kinds.shape[1]):
@@ -639,7 +641,7 @@ def _plain_apply(state, ops):
             killed[d, at] |= held
             if kind == SET:
                 mine = [i for i in at if reg[d, i] and
-                        (reg[d, i] & ACTOR_MASK) == (op_id & ACTOR_MASK)]
+                        (reg[d, i] & masks[d]) == (op_id & masks[d])]
                 free = mine or [i for i in at if reg[d, i] == 0]
                 if not free:
                     bad = True      # more writers than lanes

@@ -440,11 +440,11 @@ class TestMixedBatchRouting:
     hashindex, stragglers route classic, outputs byte-identical."""
 
     def _mixed_batch(self, fleet, n=4):
-        from automerge_tpu.fleet.tensor_doc import CTR_LIMIT
+        from automerge_tpu.fleet.tensor_doc import SEQ_CTR_LIMIT
         handles = _workload(fleet, n, rounds=2)
         # promote doc 0 to the host engine via a fleet-unsupported op
         big = encode_change({
-            'actor': 'dd' * 16, 'seq': 1, 'startOp': CTR_LIMIT + 10,
+            'actor': 'dd' * 16, 'seq': 1, 'startOp': SEQ_CTR_LIMIT + 10,
             'time': 0, 'message': '', 'deps': list(handles[0]['heads']),
             'ops': [{'action': 'makeText', 'obj': '_root', 'key': 'deep',
                      'pred': []}]})

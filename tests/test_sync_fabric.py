@@ -242,11 +242,11 @@ class TestFusedByteIdentity:
 
     @needs_native
     def test_promoted_host_doc_rides_mixed_batch(self):
-        """One doc promoted OFF the fleet (CTR_LIMIT-overflow op) rides
+        """One doc promoted OFF the fleet (SEQ_CTR_LIMIT-overflow op) rides
         the same fused multi-peer round as its fleet neighbours —
         byte-identical to the classic loop, fleet links still promote
         their sentHashes, the straggler keeps a plain set."""
-        from automerge_tpu.fleet.tensor_doc import CTR_LIMIT
+        from automerge_tpu.fleet.tensor_doc import SEQ_CTR_LIMIT
         n, k = 3, 2
         doc_rows = _doc_change_rows(n)
         peer_rows = _peer_change_rows(n, k)
@@ -255,7 +255,7 @@ class TestFusedByteIdentity:
             docs, states, peers, peer_states = _build_universe(
                 'lww', doc_rows, peer_rows, fused)
             big = encode_change({
-                'actor': 'dd' * 16, 'seq': 1, 'startOp': CTR_LIMIT + 10,
+                'actor': 'dd' * 16, 'seq': 1, 'startOp': SEQ_CTR_LIMIT + 10,
                 'time': 0, 'message': '', 'deps': list(docs[0]['heads']),
                 'ops': [{'action': 'makeText', 'obj': '_root',
                          'key': 'deep', 'pred': []}]})
