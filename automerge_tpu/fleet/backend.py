@@ -414,6 +414,13 @@ class DocFleet:
         # {'writers': sorted hex, 'bits': actor bits, 'ctr': greatest
         # counter, 'lost': the layout overflowed (the row is inexact)}
         self.seq_wide = []
+        # row -> the greatest counter of an insert the row holds while its
+        # allocated slots hold ascending ids (0 for none), else -1: the
+        # dispatch's referent lookup may search a class whose rows with ops
+        # are all in that order (sequence._referent_lookup). An array, so
+        # that a dispatch reads and updates its rows' entries at once; it
+        # grows by doubling, and entries past the rows are unused
+        self.seq_ordered = np.zeros(0, dtype=np.int64)
         self.seq_free = []
         self.slot_seq = {}        # slot -> {objectId: row}
         # Optional durability hook (fleet/durability.py ChangeJournal):
@@ -659,6 +666,8 @@ class DocFleet:
                 if self.seq_wide[row] is not None:
                     self.seq_wide[dst_row] = dict(self.seq_wide[row])
                     self.metrics.seq_wide_rows += 1
+                # the copy keeps every slot where it is
+                self.seq_ordered[dst_row] = self.seq_ordered[row]
                 srcs, dsts = copies.setdefault(place[0], ([], []))
                 srcs.append(place[1])
                 dsts.append(idx)
@@ -737,6 +746,10 @@ class DocFleet:
             self.seq_len.append(0)
             self.seq_writers.append(set())
             self.seq_wide.append(None)
+            if row == len(self.seq_ordered):
+                self.seq_ordered = np.concatenate(
+                    [self.seq_ordered, np.zeros(max(row, 1), np.int64)])
+        self.seq_ordered[row] = 0
         self.slot_seq.setdefault(slot, {})[object_id] = row
         return row
 
@@ -806,6 +819,7 @@ class DocFleet:
             if row < len(self.seq_wide) and self.seq_wide[row] is not None:
                 self.seq_wide[row] = None
                 self.metrics.seq_wide_rows -= 1
+            self.seq_ordered[row] = 0
             place = self.seq_place[row] if row < len(self.seq_place) \
                 else None
             if place is not None:
@@ -946,6 +960,9 @@ class DocFleet:
             if self.seq_wide[row] is None:
                 self.metrics.seq_wide_rows += 1
             self.seq_wide[row] = layout
+            if layout['lost']:
+                # its ids no longer keep Lamport order
+                self.seq_ordered[row] = -1
             if moved is not None and not layout['lost']:
                 cls, idx = self.seq_place[row]
                 repack.setdefault(cls, []).append(
@@ -1102,7 +1119,8 @@ class DocFleet:
         Its three phases tile it as spans: seq.place (a seq.repack child
         where rows are repacked), seq.columns, seq.enqueue (one a class)."""
         from .sequence import SeqOpBatch, apply_seq_batch_donated, \
-            ACTOR_MASK, INSERT, SET, SEQ_PRED_LANES
+            search_pays, search_rounds, ACTOR_MASK, INSERT, SET, \
+            SEQ_PRED_LANES
         if len(self.seq_rows) == 0 or len(seq_ops) == 0:
             return
         ps = _span_seq()
@@ -1131,16 +1149,24 @@ class DocFleet:
         places = self._place_seq_rows(
             uniq_rows, [self.seq_len[row] + int(ins[row])
                         for row in uniq_rows])
+        # One batch per active class, rows addressed by pool index. Where
+        # a class's rows are long enough for the search to pay, its batch
+        # says whether every row of it is in id order as the dispatch finds
+        # it (one program either way); else it says nothing, and the lookup
+        # scans (sequence.search_pays)
+        by_cls = {}
+        for row, (cls, _idx) in zip(uniq_rows, places):
+            by_cls.setdefault(cls, []).append(row)
+        ordered = {cls: np.bool_((self.seq_ordered[rows] >= 0).all())
+                   for cls, rows in by_cls.items()
+                   if search_pays(pools.state(cls).elem_id.shape[1])}
+        self._seq_note_order(arr)
         masks = {}
         # (a fleet id passes int32 where its counter passes CTR_LIMIT)
         if self.metrics.seq_wide_rows or \
                 max(int(arr[:, 2:4].max()), int(arr[:, 5:5 + D].max())) \
                 >= 1 << 31:
             arr, masks = self._seq_widen(arr, uniq_rows)
-        # One batch per active class, rows addressed by pool index
-        by_cls = {}
-        for row, (cls, _idx) in zip(uniq_rows, places):
-            by_cls.setdefault(cls, []).append(row)
         ps.mark('seq.columns')
         order = np.argsort(row_a, kind='stable')
         row_sorted = row_a[order]
@@ -1175,25 +1201,51 @@ class DocFleet:
                         actor_mask[idx_of[row]] = masks[row]
             batches.append((cls, len(sub), int(multi[rows].sum()), SeqOpBatch(
                 cols['kind'], cols['ref'], cols['packed'], cols['value'],
-                preds, flag, actor_mask)))
+                preds, flag, actor_mask, ordered.get(cls))))
         for cls, n_ops, n_multi, batch in batches:
             r_cap, width = batch.kind.shape
-            # what the referent lookup compares: every row of the class,
-            # each whole
-            lookup_nodes = r_cap * pools.state(cls).elem_id.shape[1]
+            nodes = pools.state(cls).elem_id.shape[1]
+            search = int(bool(batch.ordered))
+            # the nodes the referent lookup reads: a gather a cell a round
+            # where it searches, else every row of the class, each whole
+            lookup_nodes = r_cap * width * search_rounds(nodes) if search \
+                else r_cap * nodes
             ps.mark('seq.enqueue', cls=cls, rows=r_cap, width=width,
                     ops=n_ops, multiwriter_rows=n_multi,
-                    lookup_nodes=lookup_nodes)
+                    lookup_nodes=lookup_nodes, search=search)
             pools.pools[cls], _stats = apply_seq_batch_donated(
                 pools.state(cls), batch)
             self.metrics.dispatches += 1
             self.metrics.seq_op_cells += r_cap * width
             self.metrics.seq_lookup_nodes += lookup_nodes
+            self.metrics.seq_search_dispatches += search
         ps.done()
         self.metrics.seq_multiwriter_rows += int(multi.sum())
         self.metrics.seq_ops += len(seq_ops)
         self.metrics.device_ops += len(seq_ops)
         self._note_seq_pools()
+
+    def _seq_note_order(self, arr):
+        """Note which rows the op tuples `arr` (fleet layout) keep in id
+        order: a row stays so where its inserts' counters, in its op
+        order, rise from above the greatest it held, as one writer's do (a
+        greater counter is a greater id in every layout, whatever the
+        actors' numbers); it then holds its last insert's counter."""
+        from .sequence import INSERT
+        at = np.flatnonzero(arr[:, 1] == INSERT)
+        if not len(at):
+            return
+        at = at[np.argsort(arr[at, 0], kind='stable')]
+        row, ctr = arr[at, 0], arr[at, 3] >> ACTOR_BITS
+        first = np.flatnonzero(np.r_[True, row[1:] != row[:-1]])
+        last = np.r_[first[1:], len(row)] - 1
+        falls = np.r_[False, ctr[1:] <= ctr[:-1]]
+        falls[first] = False        # (each row's first: against its top)
+        rows = row[first]
+        top = self.seq_ordered[rows]
+        self.seq_ordered[rows] = np.where(
+            (top >= 0) & (ctr[first] > top) &
+            ~np.logical_or.reduceat(falls, first), ctr[last], -1)
 
     def _note_seq_pools(self):
         """The sequence pools' size into the metrics, from shapes alone:

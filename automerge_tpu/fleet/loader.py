@@ -511,10 +511,13 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
                       key_ctr, key_actor, vtype, val_int, make_mask, rid,
                       counter_add, counter_over):
     """Reconstruct SeqState rows from document-order sequence ops: element
-    encounter order IS final RGA order, so the linked list is a straight
-    chain — no pointer walking, no replay. Make rows (objects nested inside
-    sequences) become link-valued elements, matching the ordinary apply
-    path (backend._pack_seq_op)."""
+    encounter order IS final RGA order, so the linked list is known without
+    pointer walking or replay. A row's slots hold its elements in id order
+    (Lamport order: counter, then actor), so that the referent lookup can
+    binary-search them (sequence._referent_lookup), and `nxt` chains them in
+    list order. Make rows (objects nested inside sequences) become
+    link-valued elements, matching the ordinary apply path
+    (backend._pack_seq_op)."""
     import jax.numpy as jnp
     from .sequence import END, HEAD, SLOT0
 
@@ -541,36 +544,51 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
         is_text[u] = typ == 'text'
 
     ins = insert[rows]
-    # element ordinal per insert row within its group (stable group sort
-    # preserves document order inside each group)
+    # the insert rows in list order: by group, and in document order inside
+    # each (a stable sort)
     order = np.argsort(inv, kind='stable')
-    inv_s = inv[order]
-    ins_s = ins[order].astype(np.int64)
-    cum = np.cumsum(ins_s)
-    grp_start = np.searchsorted(inv_s, np.arange(len(uniq)), side='left')
-    grp_sizes = np.diff(np.r_[grp_start, len(ins_s)])
-    base = cum - np.repeat(cum[grp_start] - ins_s[grp_start], grp_sizes)
-    elem_ord = np.zeros(len(rows), dtype=np.int64)
-    elem_ord[order] = base - 1                 # valid where ins
+    in_list = order[ins[order]]
     n_elems = np.bincount(inv, weights=ins.astype(np.float64),
                           minlength=len(uniq)).astype(np.int64)
 
-    # update rows: find the target element by its insert op id
+    # each element's slot: its rank by id in its group (packed32 orders
+    # (counter, actor) as Lamport order does); an update row's is its
+    # target's, found by the target's insert op id
     ins_idx = np.flatnonzero(ins)
     ikey = inv[ins_idx] * (1 << 40) + packed32[rows][ins_idx]
     ins_sorted = np.argsort(ikey)
     ins_keys = ikey[ins_sorted]
+    by_id = ins_idx[ins_sorted]
+    grp = inv[by_id]
+    starts = np.flatnonzero(np.r_[True, grp[1:] != grp[:-1]]) \
+        if len(grp) else np.zeros(0, dtype=np.int64)
+    rank = np.arange(len(by_id)) - np.repeat(
+        starts, np.diff(np.r_[starts, len(by_id)]))
+    elem_rank = np.zeros(len(rows), dtype=np.int64)
+    elem_rank[by_id] = rank                  # valid where ins
+    # the greatest insert counter of each group, from its greatest id
+    top_ins = np.zeros(len(uniq), dtype=np.int64)
+    if len(grp):
+        ends = np.r_[starts[1:], len(by_id)] - 1
+        top_ins[grp[ends]] = packed32[rows][by_id[ends]] >> 8
     tgt_packed = (key_ctr[rows] << 8) | np.maximum(key_actor[rows], 0)
     tkey = inv * (1 << 40) + tgt_packed
     if len(ins_keys):
         pos = np.clip(np.searchsorted(ins_keys, tkey), 0, len(ins_keys) - 1)
         matched = ins_keys[pos] == tkey
-        tgt_ord = elem_ord[ins_idx[ins_sorted[pos]]]
+        tgt_rank = rank[pos]
     else:
         matched = np.zeros(len(rows), dtype=bool)
-        tgt_ord = np.zeros(len(rows), dtype=np.int64)
+        tgt_rank = np.zeros(len(rows), dtype=np.int64)
     bad_upd = ~ins & ~matched       # update to unknown element -> inexact
-    node = SLOT0 + np.where(ins, elem_ord, tgt_ord)
+    node = SLOT0 + np.where(ins, elem_rank, tgt_rank)
+    # the slot after each element's in list order: the next insert row's
+    # of its group, or END after the group's last
+    last = np.r_[inv[in_list[1:]] != inv[in_list[:-1]], True] \
+        if len(in_list) else np.zeros(0, dtype=bool)
+    next_node = np.full(len(rows), END, dtype=np.int64)
+    next_node[in_list[:-1]] = node[in_list[1:]]
+    next_node[in_list[last]] = END
 
     # value lanes (text: single codepoints inline; lists: ints inline;
     # everything else boxes; counters flag the row, ref new.js:937-965)
@@ -656,6 +674,10 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
             dev_id[at] = fleet._seq_to_row(row, dev_id[at])
             if layout['lost']:
                 inex_obj[u] = True
+    # every loaded row is in id order, bar one whose ids passed its layout
+    lost = np.array([bool(fleet.seq_wide[row] and fleet.seq_wide[row]['lost'])
+                     for row in fleet_row.tolist()], dtype=bool)
+    fleet.seq_ordered[fleet_row] = np.where(lost, -1, top_ins)
     place = fleet._place_seq_rows(fleet_row.tolist(), n_elems.tolist())
     cls_arr = np.array([p[0] for p in place], dtype=np.int64)
     idx_arr = np.array([p[1] for p in place], dtype=np.int64)
@@ -667,18 +689,24 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
         st = fleet.seq_pools.state(cls)
         nodes = st.elem_id.shape[1]
 
-        # linked chain per pool row: HEAD -> SLOT0 .. SLOT0+n-1 -> END
+        # The rows are fresh (a loaded object's row is placed just above),
+        # so each array's rows are built whole on the host and installed
+        # with ONE row scatter: a scatter of single elements costs the
+        # device about a microsecond an element, minutes for long texts
+        row_of = np.full(len(uniq), -1, dtype=np.int64)
+        row_of[objs] = np.arange(len(objs))
+        row_of_op = row_of[inv]
+        in_cls = row_of_op >= 0
+
+        # the list per pool row: HEAD -> its first element's slot, each
+        # slot -> the next element's, the last -> END
         nxt_host = np.full((len(objs), nodes), END, dtype=np.int32)
-        n_host = np.zeros(len(objs), dtype=np.int32)
-        for i, u in enumerate(objs):
-            n_k = int(n_elems[u])
-            n_host[i] = n_k
-            if n_k:
-                nxt_host[i, HEAD] = SLOT0
-                if n_k > 1:
-                    nxt_host[i, SLOT0:SLOT0 + n_k - 1] = \
-                        np.arange(SLOT0 + 1, SLOT0 + n_k, dtype=np.int32)
-                nxt_host[i, SLOT0 + n_k - 1] = END
+        chain = in_list[in_cls[in_list]]
+        nxt_host[row_of_op[chain], node[chain]] = next_node[chain]
+        firsts = chain[np.r_[True, inv[chain[1:]] != inv[chain[:-1]]]] \
+            if len(chain) else chain
+        nxt_host[row_of_op[firsts], HEAD] = node[firsts]
+        n_host = n_elems[objs].astype(np.int32)
         tr = jnp.asarray(idx_arr[objs])
 
         def install(name, host_rows):
@@ -690,14 +718,6 @@ def _install_seq_rows(fleet, out, sel, doc, slot_of, okey, oid_str, obj_type,
         install('nxt', nxt_host)
         install('n', n_host)
 
-        # The rows are fresh (a loaded object's row is placed just above),
-        # so each array's rows are built whole on the host and installed
-        # with ONE row scatter: a scatter of single elements costs the
-        # device about a microsecond an element, minutes for long texts
-        row_of = np.full(len(uniq), -1, dtype=np.int64)
-        row_of[objs] = np.arange(len(objs))
-        row_of_op = row_of[inv]
-        in_cls = row_of_op >= 0
         ins_sel = np.flatnonzero(ins & in_cls)
         elem_host = np.zeros((len(objs), nodes), dtype=np.int32)
         elem_host[row_of_op[ins_sel], node[ins_sel]] = dev_id[ins_sel]

@@ -4,20 +4,24 @@ This is the tensorized equivalent of the reference's list-insertion path
 (ref backend/new.js:50-192 seekWithinBlock, :145-163 concurrent-insert skip;
 host mirror: automerge_tpu/backend/op_set.py ObjState.insert_rga): a fleet of
 N sequence documents (one Text or list object each) lives as padded [N, S]
-slot tensors plus a linked-list `nxt` pointer array encoding RGA order. Slots
-are allocated in op-arrival order and never move; an insert splices pointers,
-so an op's work is its referent lookup (O(S) vector compares) + an O(skip)
-pointer walk, with NO data movement of the sequence itself — the analogue of
-the reference editing a block in place instead of reshuffling the array.
+slot tensors plus a linked-list `nxt` pointer array encoding RGA order. A
+loaded row lays its elements out in id order (the bulk loader); an insert
+takes the next free slot and never moves, and splices pointers, so an op's
+work is its referent lookup + an O(skip) pointer walk, with NO data movement
+of the sequence itself — the analogue of the reference editing a block in
+place instead of reshuffling the array.
 That holds of the compiled program too because a dispatch defers its splices:
 inside the op scan `elem_id` and `nxt` are only read, the batch's new slots
 and repointed nodes live in a per-row overlay as wide as the batch, and each
 array takes its overlay in one scatter when the scan is over (a scan step that
 wrote them while the skip walk's loop held them had both copied whole, every
 step: see _apply_seq_batch_impl). And since the scan never writes `elem_id`,
-the lookups of a whole batch are made before it, each part of the row compared
-with many refs while it is on the chip (_referent_lookup): a scan step reads
-no node array in full, only the overlay, as wide as the batch.
+the lookups of a whole batch are made before it (_referent_lookup): a scan
+step reads no node array in full, only the overlay, as wide as the batch. A
+long row whose slots hold ascending ids (a loaded or new row whose inserts
+have since come in ascending id order, as one writer's do) is searched, in
+log2(capacity) gathers (search_pays); any other row is read once, each part
+of it compared with many refs while it is on the chip.
 
 Application is a `vmap` over docs of a `lax.scan` over each doc's op stream:
 ops within one doc apply in causal order (as the reference's per-change op
@@ -91,7 +95,8 @@ DEFAULT_ACTOR_SLOTS = 4
 #   1        END sentinel / pointer-scratch (masked pointer writes land here;
 #            its outgoing pointer is never followed)
 #   2        slot-scratch (masked writes of per-slot arrays land here)
-#   3..S+2   real slots, allocated in op-arrival order
+#   3..S+2   real slots: a loaded row's in id order, then one a new
+#            element in the order its insert was applied
 HEAD, END, SCRATCH, SLOT0 = 0, 1, 2, 3
 
 
@@ -244,10 +249,15 @@ class SeqOpBatch:
     - actor_mask int32 [N] or None: the actor bits of each row's ids
       (ACTOR_MASK in the fleet-wide layout, fewer in a wide row's); None
       where every row is in the fleet-wide layout.
+    - ordered bool [] or None: every row that has an op holds its allocated
+      slots' ids in ascending order, so the referent lookup may search
+      (_referent_lookup). A traced value: a dispatch of either kind runs
+      the same program. None, where the search would not pay
+      (search_pays), scans, in a program without the search.
     """
 
     def __init__(self, kind, ref, packed, value, preds=None, flag=None,
-                 actor_mask=None):
+                 actor_mask=None, ordered=None):
         self.kind = kind
         self.ref = ref
         self.packed = packed
@@ -258,10 +268,11 @@ class SeqOpBatch:
         self.preds = preds
         self.flag = np.zeros(shape, dtype=bool) if flag is None else flag
         self.actor_mask = actor_mask
+        self.ordered = ordered
 
     def tree_flatten(self):
         return ((self.kind, self.ref, self.packed, self.value, self.preds,
-                 self.flag, self.actor_mask), None)
+                 self.flag, self.actor_mask, self.ordered), None)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -521,7 +532,7 @@ def _apply_one_doc(carry, op, elem_id, nxt, n0, actor_mask):
 LOOKUP_BLOCK = 2048
 
 
-def _referent_lookup(elem_id, ref):
+def _referent_lookup(elem_id, ref, n=None, ordered=None):
     """[N, P]: for every op cell the least node of its row whose `elem_id`
     is the cell's `ref`, or `nodes` where the row has none. Packed elemIds
     are unique and non-zero, so an equality one-hot over the node axis finds
@@ -529,11 +540,74 @@ def _referent_lookup(elem_id, ref):
     the sentinels and unallocated slots keep elem_id 0. The row is what the
     dispatch found: the batch's own inserts are looked up in the scan.
 
-    The row is read once, a block of nodes at a time, and each block is
-    compared with all of its row's refs while it is on the chip; the
-    compare, select and min of a block fuse, so the [N, P, nodes] product is
-    never laid out, and where a backend does not fuse them (XLA's CPU one)
-    what it lays out is a block wide."""
+    Where `ordered` (a traced bool) holds, every row that has a ref other
+    than 0 keeps the ids of its `n` allocated slots in ascending order, and
+    the lookup is a binary search of them (_referent_search), with the same
+    answers; else, and where `ordered` is None, it scans (_referent_scan).
+    Either way it is one program: the search runs always, and the scan's
+    loop over blocks runs no round where `ordered` holds. (A `lax.cond` of
+    the two had the chip's compiler copy the row into each branch's own
+    layout: 134 MB a dispatch at 128 rows of 262,147 nodes.)"""
+    with jax.named_scope('seq.referent_lookup'):
+        if ordered is None:
+            return _referent_scan(elem_id, ref)
+        return jnp.where(ordered, _referent_search(elem_id, ref, n),
+                         _referent_scan(elem_id, ref, ordered))
+
+
+def search_rounds(nodes):
+    """Halvings the binary search takes to close a range of up to
+    `nodes - SLOT0` slots."""
+    return max(nodes - SLOT0, 1).bit_length()
+
+
+# What a searched id costs the chip against a node the block scan compares
+# with one ref: a round of the search gathers one id a cell, 140 us for the
+# 8,192 cells of 128 rows x 64 refs, where the scan compares those refs with
+# 262,147 nodes a row in 2.3 ms (the chip, PERF.md section 6, PR 46). So
+# the search pays where a row has more than about this many nodes a round.
+SEARCH_NODES_PER_ROUND = 16384
+
+
+def search_pays(nodes):
+    """Whether a dispatch over rows of `nodes` nodes in id order should
+    search them: from the 2^19-slot class up, not at 2^18 and below."""
+    return nodes >= SEARCH_NODES_PER_ROUND * search_rounds(nodes)
+
+
+def _referent_search(elem_id, ref, n):
+    """_referent_lookup for rows whose allocated slots [SLOT0, SLOT0 + n)
+    hold ascending ids: a lower bound of each ref among them, in
+    search_rounds(nodes) rounds of one [N, P] gather, then an equality
+    check. Where a row repeats an id the lower bound is its least node, as
+    the scan's is."""
+    nodes = elem_id.shape[1]
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = jnp.minimum((lo + hi) >> 1, nodes - 1)
+        below = jnp.take_along_axis(elem_id, mid, axis=1) < ref
+        live = lo < hi
+        return (jnp.where(live & below, mid + 1, lo),
+                jnp.where(live & ~below, mid, hi))
+
+    lo, _ = lax.fori_loop(
+        0, search_rounds(nodes), halve,
+        (jnp.full(ref.shape, SLOT0, jnp.int32),
+         jnp.broadcast_to((SLOT0 + n)[:, None], ref.shape)))
+    # past a row's last allocated slot lie 0s, which only ref 0 equals
+    at = jnp.take_along_axis(elem_id, jnp.minimum(lo, nodes - 1), axis=1)
+    found = jnp.where(at == ref, lo, nodes)
+    return jnp.where(ref == HEAD_REF, jnp.int32(HEAD), found)
+
+
+def _referent_scan(elem_id, ref, skip=None):
+    """_referent_lookup for any row: the row is read once, a block of nodes
+    at a time, and each block is compared with all of its row's refs while
+    it is on the chip; the compare, select and min of a block fuse, so the
+    [N, P, nodes] product is never laid out, and where a backend does not
+    fuse them (XLA's CPU one) what it lays out is a block wide. Where the
+    traced bool `skip` holds, the loop reads no block."""
     nodes = elem_id.shape[1]
     # blocks tile the slots (a pool's capacity is a power of two); what is
     # left over, the three nodes of the sentinels' offset, is a block of
@@ -546,15 +620,17 @@ def _referent_lookup(elem_id, ref):
         return jnp.min(jnp.where(part[:, None, :] == ref[:, :, None],
                                  node_ids, nodes), axis=2)
 
-    with jax.named_scope('seq.referent_lookup'):
-        least = lax.fori_loop(
-            0, nodes // block,
-            lambda k, least: jnp.minimum(least, among(k * block, block)),
-            jnp.full(ref.shape, nodes, jnp.int32))
-        rest = nodes % block
-        if rest:
-            least = jnp.minimum(least, among(nodes - rest, rest))
-        return least
+    blocks = nodes // block
+    if skip is not None:
+        blocks = jnp.where(skip, 0, blocks)
+    least = lax.fori_loop(
+        0, blocks,
+        lambda k, least: jnp.minimum(least, among(k * block, block)),
+        jnp.full(ref.shape, nodes, jnp.int32))
+    rest = nodes % block
+    if rest:
+        least = jnp.minimum(least, among(nodes - rest, rest))
+    return least
 
 
 def _apply_seq_batch_impl(state, ops):
@@ -566,7 +642,8 @@ def _apply_seq_batch_impl(state, ops):
     entry) and the repointed old nodes as (node, new next) pairs. Each
     array takes the overlay in one scatter after the scan. For the same
     reason every op's referent among the row's old nodes is found before
-    the scan, for the whole batch in one pass (_referent_lookup), and not
+    the scan, for the whole batch at once (_referent_lookup: a binary
+    search where `ops.ordered` holds, else one pass over the rows), and not
     by a search of `elem_id` in every scan step."""
     rows, width = ops.kind.shape
     width = max(width, 1)       # an empty batch still traces the step
@@ -599,7 +676,8 @@ def _apply_seq_batch_impl(state, ops):
     carry, applied = jax.vmap(per_doc)(
         state.elem_id, state.nxt, state.reg, state.killed, state.val,
         state.counter, state.n, state.inexact, ops.kind, ops.ref,
-        _referent_lookup(state.elem_id, ops.ref), ops.packed, ops.value,
+        _referent_lookup(state.elem_id, ops.ref, state.n, ops.ordered),
+        ops.packed, ops.value,
         ops.preds, ops.flag, actor_mask,
         # the empty overlay, batched like the rest of the scan's carry
         jnp.zeros((rows, width), jnp.int32))
@@ -645,9 +723,10 @@ element_visibility = instrument_kernel(
 def _linearize_impl(state):
     """List-rank every node: returns (pos [N, S+3], length [N]).
 
-    pos is node-indexed (sentinels at 0..2, real slots from SLOT0=3, in
-    op-arrival order): pos[d, SLOT0 + k] is the 0-based sequence index of
-    doc d's k-th allocated slot; sentinel and unallocated entries are
+    pos is node-indexed (sentinels at 0..2, real slots from SLOT0=3, a
+    loaded row's in id order and each later insert's in the order it was
+    applied): pos[d, SLOT0 + k] is the 0-based sequence index of the
+    element in doc d's slot k; sentinel and unallocated entries are
     garbage — mask with SLOT0 <= node < SLOT0 + n.
     Pointer doubling (Wyllie's list ranking): dist[i] = hops from node i to
     END, accumulated over ceil(log2(nodes)) rounds of jumps. Then

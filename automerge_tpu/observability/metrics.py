@@ -139,8 +139,11 @@ class Metrics:
                                  # device into a wide layout (a row past
                                  # the packed window, or a wide row that
                                  # gained a writer)
-        'seq_lookup_nodes',      # rows x nodes of every dispatched class:
-                                 # what the referent lookups compared
+        'seq_lookup_nodes',      # the nodes the referent lookups read:
+                                 # rows x nodes of a class they scanned,
+                                 # rows x width x rounds of one searched
+        'seq_search_dispatches',  # dispatches whose lookup searched rows
+                                  # in id order (sequence._referent_lookup)
         # bulk reads (fleet/backend.py materialize_docs)
         'read_docs',             # handles asked of the fleet
         'read_rows',             # rows the device gather moved to the host

@@ -231,9 +231,10 @@ def test_three_sub_phases_tile_dispatch_seq():
     assert all(a['t1_ns'] == b['t0_ns'] for a, b in zip(parts, parts[1:]))
     assert whole['t0_ns'] <= parts[0]['t0_ns'] and \
         parts[-1]['t1_ns'] <= whole['t1_ns']
+    # rows of 64 slots are too short for the search to pay: a scan
     assert parts[-1]['attrs'] == {'cls': 0, 'rows': 1, 'width': 4, 'ops': 3,
                                   'multiwriter_rows': 0,
-                                  'lookup_nodes': 1 * (64 + 3)}
+                                  'lookup_nodes': 1 * (64 + 3), 'search': 0}
     assert np.asarray(fleet.seq_pools.state(0).n)[0] == 3
     # a row that needs 70 slots moves up a size class, and reads the same
     assert fleet._place_seq_rows([0], [70]) == [(1, 0)]

@@ -323,8 +323,14 @@ def test_a_layout_past_its_bits_leaves_the_row_to_the_mirror():
                                     mirror=False)
     (row,) = fleet.slot_seq[handles[0]['state']._impl.slot].values()
     assert fleet.seq_wide[row]['lost']
+    assert fleet.seq_ordered[row] == -1      # its ids left Lamport order
     assert fleet.seq_row_inexact(row)
     assert_matches(handles[0], doc.changes)
+    again = DocFleet(doc_capacity=2)
+    loaded = loader.load_docs([host.save(host_of(doc.changes))], again)
+    row = _row_of(again, loaded[0])
+    assert again.seq_wide[row]['lost'] and again.seq_ordered[row] == -1
+    assert_matches(loaded[0], doc.changes)
 
 
 def test_the_parser_widens_sequence_ids_and_refuses_map_ones():
@@ -357,8 +363,9 @@ def test_the_parser_widens_sequence_ids_and_refuses_map_ones():
 def test_a_dispatch_notes_its_repacks_and_lookup_nodes():
     """The first dispatch past the window repacks its row under
     `seq.place` (span `seq.repack`, attribute `rows`); every
-    `seq.enqueue` carries the rows x nodes its lookup compares, which
-    `seq_lookup_nodes` sums."""
+    `seq.enqueue` carries the nodes its lookup reads, which
+    `seq_lookup_nodes` sums: rows x nodes where it scans, as it does rows
+    of 64 slots (search_pays)."""
     doc = Text()
     doc.typing(A, 2, '_head', 'ab')
     fleet = DocFleet(doc_capacity=2)
@@ -378,6 +385,7 @@ def test_a_dispatch_notes_its_repacks_and_lookup_nodes():
     assert repack['parent'] == place['id']
     assert repack['attrs'] == {'rows': 1}
     (enqueue,) = [s for s in recorded if s['name'] == 'seq.enqueue']
+    assert enqueue['attrs']['search'] == 0
     assert enqueue['attrs']['lookup_nodes'] == \
         enqueue['attrs']['rows'] * (64 + 3)
     assert fleet.metrics.seq_lookup_nodes - before == \
@@ -400,3 +408,205 @@ def test_a_sequence_make_past_what_a_wide_row_packs_promotes():
     assert fleet.metrics.promotions == 1
     assert fleet_backend.get_patch(handle)['diffs'] == \
         host.get_patch(host_of(doc.changes))['diffs']
+
+
+# ---- rows in id order: the lookup searches them -----------------------------
+
+@pytest.fixture
+def searching(monkeypatch):
+    """The search at every row length: by default it pays from rows of
+    2^19 slots up (sequence.search_pays), which a test cannot afford."""
+    from automerge_tpu.fleet import sequence
+    monkeypatch.setattr(sequence, 'SEARCH_NODES_PER_ROUND', 0)
+
+
+def test_the_search_pays_on_long_rows_only():
+    """The chip's costs set where the lookup searches: not the 262,144-slot
+    class of the text cells, whose scan took 2.3 ms a dispatch where the
+    search's 19 rounds took 2.7, but the 2^19-slot class up, and the 2^25
+    one of long documents, where the scan took 21.6 ms and the search
+    0.07."""
+    from automerge_tpu.fleet.sequence import SLOT0, search_pays
+    assert not search_pays((1 << 18) + SLOT0)
+    assert search_pays((1 << 19) + SLOT0)
+    assert search_pays((1 << 25) + SLOT0)
+
+
+def _row_of(fleet, handle):
+    (row,) = fleet.slot_seq[handle['state']._impl.slot].values()
+    return row
+
+
+def _device_ids(fleet, row):
+    """The ids of a row's allocated slots, as the device holds them."""
+    from automerge_tpu.fleet.sequence import SLOT0
+    cls, idx = fleet.seq_place[row]
+    st = fleet.seq_pools.state(cls)
+    return np.asarray(st.elem_id[idx])[SLOT0:SLOT0 + int(st.n[idx])]
+
+
+def _searched(handles, changes):
+    """apply_changes_docs(handles, changes), and the `search` attribute of
+    each of its `seq.enqueue` spans."""
+    spans.enable(capacity=4096)
+    try:
+        spans.clear()
+        handles, _ = apply_changes_docs(handles, changes, mirror=False)
+        recorded = spans.iter_spans()
+    finally:
+        spans.disable()
+    return handles, [s['attrs']['search'] for s in recorded
+                     if s['name'] == 'seq.enqueue']
+
+
+def test_one_writer_keeps_its_row_in_id_order_through_a_migration(searching):
+    """One writer's keystrokes at random places past the window keep the
+    row's slots in id order: every dispatch searches, the row's move from
+    the 64-slot class to the 128-slot one included, and
+    `seq_search_dispatches` counts them all."""
+    rng = np.random.default_rng(7)
+    doc = Text()
+    doc.typing(A, W, '_head', 'abcdefgh')
+    elems = [f'{W + i}@{A}' for i in range(8)]
+    fleet = DocFleet(doc_capacity=2)
+    handles, found = _searched(init_docs(1, fleet), [doc.changes])
+    ctr = W + 8
+    bufs = []
+    for _ in range(12):
+        at = elems[rng.integers(len(elems))]
+        bufs.append(doc.typing(A, ctr, at, 'uvwxy'))
+        elems += [f'{ctr + i}@{A}' for i in range(5)]
+        ctr += 5
+    handles, more = _searched(handles, [bufs])
+    row = _row_of(fleet, handles[0])
+    assert fleet.metrics.seq_migrations == 1
+    assert found + more == [1, 1]
+    assert fleet.metrics.seq_search_dispatches == 2
+    assert fleet.seq_ordered[row] == ctr - 1
+    assert (np.diff(_device_ids(fleet, row)) > 0).all()
+    assert_on_device(fleet)
+    assert_matches(handles[0], doc.changes)
+
+
+def test_rows_keep_their_order_apart(searching):
+    """One call's inserts are read row by row: a row whose counters lie
+    below another's in the same call stays in id order."""
+    high, low = Text(), Text(owner=C)
+    high.typing(A, W, '_head', 'abc')
+    low.typing(C, 2, '_head', 'xy')
+    fleet = DocFleet(doc_capacity=4)
+    handles, found = _searched(init_docs(2, fleet),
+                               [high.changes, low.changes])
+    assert [fleet.seq_ordered[_row_of(fleet, h)] for h in handles] == \
+        [W + 2, 3]
+    assert found == [1]
+
+
+def test_a_writer_joining_a_wide_row_keeps_it_in_id_order(searching):
+    """A writer that sorts before the row's own and has seen all of it
+    joins: the repack moves the owner to rank 1, and the row stays in id
+    order, so the next dispatch searches it."""
+    doc = one_text_past_the_window()
+    fleet = DocFleet(doc_capacity=2)
+    handles, _ = apply_changes_docs(init_docs(1, fleet), [doc.changes],
+                                    mirror=False)
+    row = _row_of(fleet, handles[0])
+    repacks = fleet.metrics.seq_repacks
+    handles, found = _searched(
+        handles, [[doc.typing(B, W + 30, f'{W + 2}@{A}', 'QR')]])
+    assert fleet.metrics.seq_repacks == repacks + 1
+    assert fleet.seq_wide[row]['writers'] == [B, A]
+    handles, more = _searched(
+        handles, [[doc.typing(A, W + 40, f'{W + 31}@{B}', 'st')]])
+    assert found + more == [1, 1]
+    assert fleet.seq_ordered[row] == W + 41
+    assert (np.diff(_device_ids(fleet, row)) > 0).all()
+    assert_on_device(fleet)
+    assert_matches(handles[0], doc.changes)
+
+
+@pytest.mark.parametrize('counter', ['equal', 'lower', 'equal_in_one_call'])
+def test_a_concurrent_insert_takes_its_row_out_of_id_order(counter,
+                                                           searching):
+    """A second writer's insert concurrent with the owner's, at the
+    counter of the row's greatest element or a lower one, in a later call
+    or in the owner's, takes the row out of id order. The dispatch that
+    brings it still searches (its lookups read the row as it was before);
+    the next one scans, with no program compiled for it, and the text is
+    the host's and the reference's."""
+    from automerge_tpu.fleet import sequence
+    jitted = sequence.apply_seq_batch_donated.__wrapped__
+    doc = Text()
+    doc.typing(A, W, '_head', 'abcd')
+    base = list(doc.heads)
+    fleet = DocFleet(doc_capacity=2)
+    handles, found = _searched(init_docs(1, fleet), [doc.changes])
+    row = _row_of(fleet, handles[0])
+    mine = doc.typing(A, W + 10, f'{W + 1}@{A}', 'wxyz', deps=base)
+    head_a = list(doc.heads)
+    if counter != 'equal_in_one_call':
+        handles, more = _searched(handles, [[mine]])
+        found += more
+        assert fleet.seq_ordered[row] == W + 13
+    start = W + 5 if counter == 'lower' else W + 13
+    theirs = doc.typing(B, start, f'{W + 1}@{A}', 'pqrs', deps=base)
+    handles, more = _searched(
+        handles, [[theirs] if counter != 'equal_in_one_call' else
+                  [mine, theirs]])
+    found += more
+    assert fleet.seq_ordered[row] == -1
+    doc.heads = sorted(head_a + doc.heads)
+    programs = jitted._cache_size()         # the first call's width, 4
+    handles, more = _searched(
+        handles, [[doc.typing(A, W + 20, f'{W + 11}@{A}', 'MNOP')]])
+    found += more
+    assert found == [1] * (len(found) - 1) + [0]
+    assert fleet.metrics.seq_search_dispatches == len(found) - 1
+    assert jitted._cache_size() == programs
+    assert_on_device(fleet)
+    assert materialize_docs(handles)[0]['text'] == rga_text(doc.changes)
+    assert_matches(handles[0], doc.changes)
+
+
+@pytest.mark.parametrize('start', [2, W])
+def test_a_loaded_row_lies_in_id_order_and_reads_as_before(start,
+                                                           searching):
+    """load_docs lays a row's elements out in id order where its list
+    order is another: typing at the head, a second writer, concurrent
+    inserts at one element, a delete and an overwrite. The text, the
+    patches (read off the device by the register fleet too) and save()
+    are the host's, before and after a keystroke that the lookup finds by
+    its search."""
+    s = start
+    doc = Text()
+    doc.typing(A, s, '_head', 'abc')
+    doc.typing(A, s + 10, '_head', 'xyz')
+    doc.typing(B, s + 20, f'{s + 1}@{A}', 'mn')
+    base = list(doc.heads)
+    doc.typing(A, s + 30, f'{s}@{A}', 'Q', deps=base)
+    head_a = list(doc.heads)
+    doc.typing(B, s + 30, f'{s}@{A}', 'R', deps=base)
+    doc.heads = sorted(head_a + doc.heads)
+    doc.delete(A, s + 40, f'{s + 11}@{A}')
+    doc.overwrite(B, s + 41, f'{s + 2}@{A}', 'C')
+    loaded = list(doc.changes)
+    saved = host.save(host_of(loaded))
+    for exact in (True, False):
+        fleet = DocFleet(doc_capacity=2, exact_device=exact)
+        handles = loader.load_docs([saved], fleet)
+        row = _row_of(fleet, handles[0])
+        ids = _device_ids(fleet, row)
+        assert len(ids) == 10 and (np.diff(ids) > 0).all()
+        assert fleet.seq_ordered[row] == s + 30
+        if exact:
+            engine = handles[0]['state']._impl
+            assert engine._register_patch_diffs() == \
+                host.get_patch(host_of(loaded))['diffs']
+            continue
+        assert materialize_docs(handles)[0]['text'] == 'xzaQRbmnC'
+        assert_matches(handles[0], doc.changes, text_of_rga=False)
+        handles, found = _searched(
+            handles, [[doc.typing(A, s + 50, f'{s + 21}@{B}', '!')]])
+        assert found == [1]
+        assert_on_device(fleet)
+        assert_matches(handles[0], doc.changes, text_of_rga=False)

@@ -913,6 +913,130 @@ def test_lookup_in_blocks(capacity):
         assert f'[{rows},{width},{nodes}]' not in text
 
 
+# ---- the lookup's search: rows whose allocated slots hold ascending ids --
+
+def _ascending_ids(rng, count, lo, hi):
+    """Up to `count` distinct ids of [lo, hi), ascending."""
+    return np.unique(rng.integers(lo, hi, size=count))[:count]
+
+
+@pytest.mark.parametrize('layout', ['fleet', 'wide'])
+def test_search_answers_as_the_scan(layout):
+    """On rows whose allocated slots hold ascending ids, the lookup's
+    binary search answers every ref as the block scan and a plain search
+    do: hits (a row's first and last slot among them), misses between,
+    below and past a row's ids, 0 (node 0, HEAD), -1 and INT32_MAX, on an
+    empty row, a full one and rows between. The ids are those of a loaded
+    or one-writer row in the fleet layout ((counter << 8) | actor), or of
+    a wide row, (counter << 1) | rank with counters past the 2^23 window.
+    A row out of id order whose refs are all 0 reads node 0 either way.
+    Both branches are one program."""
+    import jax
+    from automerge_tpu.fleet.sequence import (
+        INT32_MAX, SLOT0, _referent_lookup, _referent_scan)
+    from automerge_tpu.fleet.tensor_doc import CTR_LIMIT
+    rows, width, capacity = 5, 24, 300
+    nodes = capacity + SLOT0
+    lo, hi = (1 << 8, CTR_LIMIT << 8) if layout == 'fleet' else \
+        (CTR_LIMIT << 1, INT32_MAX - 1)
+    lookup = jax.jit(_referent_lookup)
+    rng = np.random.default_rng(46)
+    programs = None
+    for trial in range(4):
+        elem = np.zeros((rows, nodes), np.int32)
+        ref = np.zeros((rows, width), np.int32)
+        n = np.zeros(rows, np.int32)
+        for r in range(rows):
+            want = (0, capacity)[r] if r < 2 else \
+                int(rng.integers(1, capacity))
+            ids = _ascending_ids(rng, want, lo, hi)
+            n[r] = len(ids)
+            elem[r, SLOT0:SLOT0 + n[r]] = ids
+            if r == rows - 1:               # out of order, refs all 0
+                elem[r, SLOT0:SLOT0 + n[r]] = rng.permutation(ids)
+                continue
+            pool = [0, -1, INT32_MAX, lo - 1, hi]
+            if len(ids):
+                gaps = ids[:-1][np.diff(ids) > 1] + 1
+                pool += [ids[0], ids[-1], ids[0] - 1, ids[-1] + 1]
+                pool += rng.choice(ids, 8).tolist() + gaps[:6].tolist()
+            ref[r] = rng.choice(pool, width)
+        scanned = _plain_lookup(elem, ref)
+        np.testing.assert_array_equal(
+            np.asarray(_referent_scan(elem, ref)), scanned)
+        for ordered in (True, False):
+            np.testing.assert_array_equal(
+                np.asarray(lookup(elem, ref, n, np.bool_(ordered))), scanned)
+            programs = programs or lookup._cache_size()
+    assert lookup._cache_size() == programs
+
+
+def _one_writer_edits(rng, actor, state, count):
+    """`count` random edits of one writer: inserts after a live or deleted
+    element, a new one of the same batch among them, or the head; sets and
+    deletes of elements no op has set or deleted yet. `state` is the row's
+    (next counter, [element ids], [untouched ids]), carried from batch to
+    batch."""
+    ctr, elems, untouched = state
+    ops = []
+    for _ in range(count):
+        pick = rng.random()
+        if pick < 0.6 or not untouched:
+            ref = '_head' if not elems or rng.random() < 0.1 else \
+                elems[rng.integers(len(elems))]
+            op_id = f'{ctr}@{actor}'
+            ops.append(ins(ref, op_id, chr(ord('a') + ctr % 26)))
+            elems.append(op_id)
+            untouched.append(op_id)
+        else:
+            target = untouched.pop(rng.integers(len(untouched)))
+            ops.append(_set(target, f'{ctr}@{actor}', 'X') if pick < 0.8
+                       else _del(target, f'{ctr}@{actor}'))
+        ctr += 1
+    return ops, (ctr, elems, untouched)
+
+
+@pytest.mark.parametrize('seed', range(2))
+def test_an_ordered_dispatch_applies_as_a_scanned_one(seed):
+    """Three dispatches of one writer's random edits a row, each applied to
+    a state whose lookups searched and to one whose lookups scanned: every
+    array of the two states stays equal and the text is the host's. The
+    batches' inserts name old elements, the head and elements of the same
+    batch (which the lookup leaves to the scan's overlay); a third row
+    inserts after an id it never held (a miss: the op is dropped and the
+    row flagged), and a fourth has no op at all."""
+    actors = [A1, A2, A3]
+    enc = SeqEncoder(actors)
+    rng = np.random.default_rng(seed)
+    rows = [(2, [], []) for _ in actors]
+    history = [[] for _ in actors]
+    searched = scanned = SeqState.empty(4, 64)
+    for step in range(3):
+        per_row = []
+        for r, actor in enumerate(actors):
+            ops, rows[r] = _one_writer_edits(rng, actor, rows[r], 10)
+            per_row.append(ops)
+            history[r] += ops
+        per_row[2][4] = ins(f'999@{A3}', per_row[2][4]['id'], '?')
+        batch = enc.batch(per_row + [[]], pad_to=16)
+        batch.ordered = np.bool_(True)
+        searched, applied = apply_seq_batch(searched, batch)
+        batch.ordered = np.bool_(False)
+        scanned, applied_too = apply_seq_batch(scanned, batch)
+        assert int(applied) == int(applied_too)
+        for a, b in zip(searched.tree_flatten()[0],
+                        scanned.tree_flatten()[0]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    elem, n = np.asarray(searched.elem_id), np.asarray(searched.n)
+    for r in range(3):
+        ids = elem[r, 3:3 + n[r]]
+        assert (np.diff(ids) > 0).all()
+    assert np.asarray(searched.inexact).tolist() == [False, False, True,
+                                                     False]
+    assert visible_text(searched)[:2] == [
+        host_text(history[0], actors), host_text(history[1], actors[1:])]
+
+
 @functools.lru_cache(maxsize=None)
 def _compiled_text(rows, capacity, width, lanes=4):
     """The optimized program of one dispatch at these shapes, as text."""
